@@ -17,8 +17,7 @@ Format::
 from __future__ import annotations
 
 import os
-
-from nydus_snapshotter_tpu.utils.tomlcompat import tomllib
+import tomllib
 
 DEFAULT_PATH = os.path.join(os.path.dirname(__file__), "baseline.toml")
 

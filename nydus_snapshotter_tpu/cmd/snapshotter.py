@@ -255,7 +255,7 @@ def build_stack(cfg: SnapshotterConfig):
             # tarfs boundaries come from the tar layout (fixed regions);
             # digests go through the configured arm (validated in
             # Config.validate; default hybrid — the control plane must
-            # never block on device/tunnel init unless jax is opted in),
+            # never block on device init unless jax is opted in),
             # or hashlib when acceleration is disabled outright.
             engine=(
                 ChunkDigestEngine(

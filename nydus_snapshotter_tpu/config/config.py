@@ -13,11 +13,11 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import tomllib
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
 from nydus_snapshotter_tpu import constants
-from nydus_snapshotter_tpu.utils.tomlcompat import tomllib
 
 
 class ConfigError(ValueError):

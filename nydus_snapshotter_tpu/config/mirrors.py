@@ -11,11 +11,11 @@ backend config.
 from __future__ import annotations
 
 import os
+import tomllib
 import urllib.parse
 
 from nydus_snapshotter_tpu.config.daemonconfig import MirrorConfig
 from nydus_snapshotter_tpu.utils import errdefs
-from nydus_snapshotter_tpu.utils.tomlcompat import tomllib
 
 
 def host_directory(host: str) -> str:

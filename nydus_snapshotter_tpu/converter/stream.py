@@ -1070,6 +1070,7 @@ def pack_stream(
                 fres = feng.process_many(streams)
             except fused_convert.FusedOverflow:
                 fres = None  # pathological input: per-file paths below
+                fused_convert.record_host_fallback()
             _t_chunk += _pc() - _tc
             if fres is not None:
                 for (_tag, meta, off, size), fcuts, dlist in zip(

@@ -590,6 +590,7 @@ class ChunkDigestEngine:
         try:
             res = eng.process_many(arrs)
         except fused_convert.FusedOverflow:
+            fused_convert.record_host_fallback()
             return None
         return [
             [

@@ -152,11 +152,4 @@ def gear_bitmaps(x: jax.Array, mask_s: int, mask_l: int, n: int, interpret: bool
 
 def supported(n: int) -> bool:
     """This kernel needs TPU and a window that tiles into lane substreams."""
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        return False
-    return (
-        on_tpu
-        and n % (LANES * ROWS_PER_TILE) == 0
-    )
+    return jax.default_backend() == "tpu" and n % (LANES * ROWS_PER_TILE) == 0

@@ -10,7 +10,7 @@ is a rolling 16-deep window kept as sixteen separate [8, 128] vectors.
 Two backend constraints shape the round loop, learned the hard way:
 
 - Mosaic cannot lower `dynamic_slice` on *values* — the first real-TPU
-  window (DEVICE_NUMBERS.md, 2026-07-31) failed exactly there when the
+  compile failed exactly there when the
   message window was a stacked [16, 8, 128] array indexed by
   ``(step*8 + r) % 16`` with a traced step.
 - XLA CPU (the `interpret=True` correctness path) chokes on a fully
@@ -164,8 +164,4 @@ def sha256_batch_pallas(
 def supported(m: int) -> bool:
     """Worth dispatching: TPU backend and a batch big enough to fill at
     least one 1024-chunk group."""
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        return False
-    return on_tpu and m >= GROUP
+    return jax.default_backend() == "tpu" and m >= GROUP

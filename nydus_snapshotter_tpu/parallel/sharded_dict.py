@@ -65,11 +65,6 @@ from nydus_snapshotter_tpu.analysis import runtime as _an
 from nydus_snapshotter_tpu.metrics import registry as _metrics
 from nydus_snapshotter_tpu.parallel import mesh as mesh_lib
 
-try:  # jax >= 0.4.35 exports shard_map at top level; 0.4.x before that
-    _shard_map = jax.shard_map  # under jax.experimental (same semantics)
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 # Longest probe chain the BUILD tolerates before doubling capacity. The
 # probe paths bound their loops by the table's actual max chain
 # (_table_max_depth, persisted with the table), so a deeper tolerance
@@ -269,7 +264,7 @@ def _probe_sharded(keys, values, queries, n_shards: int, mesh, depth: int = MAX_
         found = _probe_local(k, v, allq, cap, depth)
         return jnp.where(belongs, found, 0)
 
-    partial_answers = _shard_map(
+    partial_answers = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -328,7 +323,7 @@ def _probe_routed(keys, values, queries, n_shards: int, mesh, depth: int = MAX_P
         ans = jnp.where(ok, back[jnp.clip(slot, 0, n_shards * bucket_cap - 1)], 0)
         return ans, jnp.full((1,), overflow)
 
-    answers, overflowed = _shard_map(
+    answers, overflowed = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -344,6 +339,8 @@ def _probe_routed(keys, values, queries, n_shards: int, mesh, depth: int = MAX_P
 class ShardedChunkDict:
     """Device-resident dedup dictionary, one shard per mesh device."""
 
+    pallas_interpret = False  # load()/copy() build without __init__
+
     def __init__(
         self,
         digests_u32: np.ndarray,
@@ -351,6 +348,7 @@ class ShardedChunkDict:
         capacity_factor: float = DEFAULT_HEADROOM,
         probe_backend: str = "auto",
         load_factor: float = DEFAULT_LOAD_FACTOR,
+        pallas_interpret: bool = False,
     ):
         if probe_backend not in ("auto", "device", "host", "pallas"):
             raise ValueError(f"unknown probe backend {probe_backend!r}")
@@ -359,6 +357,9 @@ class ShardedChunkDict:
         self.mesh = mesh if mesh is not None else mesh_lib.make_mesh()
         self.n_shards = int(np.prod(list(self.mesh.shape.values())))
         self.probe_backend = probe_backend
+        # Pallas interpret mode is a test arm: only a caller that asks
+        # for it gets it (probe_backend="pallas" compiles for the chip)
+        self.pallas_interpret = pallas_interpret
         self.capacity_factor = capacity_factor
         self.load_factor = load_factor
         self._init_growth_state()
@@ -963,14 +964,13 @@ class ShardedChunkDict:
 
     def _lookup_pallas(self, queries_u32: np.ndarray) -> np.ndarray:
         """Single-host DMA-pipelined device probe (ops/probe_pallas): the
-        TPU-native replacement for the XLA gather (VERDICT r3 next #4) —
+        TPU-native replacement for the XLA gather —
         the table stays in HBM, each query's chain window is DMA'd into
         VMEM with pipelined copies. Queries are partitioned by owning
         shard host-side; each shard's table is probed in one kernel
-        launch. Falls back to interpret mode off-TPU (correctness path)."""
+        launch."""
         from nydus_snapshotter_tpu.ops import probe_pallas
 
-        interpret = not probe_pallas.supported()
         m = len(queries_u32)
         host_keys, host_values, _cap, depth = self._tables
         shard_of = queries_u32[:, 0] % np.uint32(self.n_shards)
@@ -984,7 +984,7 @@ class ShardedChunkDict:
                 host_values[s],
                 queries_u32[idx],
                 depth,
-                interpret=interpret,
+                interpret=self.pallas_interpret,
             )
             out[idx] = ans.astype(np.int64)
         return out - 1

@@ -78,12 +78,12 @@ phase references all raise :class:`ScenarioSpecError` naming the table.
 from __future__ import annotations
 
 import os
+import tomllib
 from dataclasses import dataclass, field
 from typing import Optional
 
 from nydus_snapshotter_tpu import failpoint
 from nydus_snapshotter_tpu.failpoint.spec import SpecError, parse_action
-from nydus_snapshotter_tpu.utils.tomlcompat import tomllib
 
 
 class ScenarioSpecError(ValueError):

@@ -19,6 +19,7 @@ optimizer-server) call :func:`ensure_built` on first use. Discipline:
 
 from __future__ import annotations
 
+import glob
 import os
 import shutil
 import subprocess
@@ -70,6 +71,22 @@ def failure_reason(target: str) -> str:
         return ""
     _stamp, _nl, stderr = memo.partition("\n")
     return stderr.strip()
+
+
+def rebuild_from_sources(stderr) -> None:
+    """Rebuild native/bin's default targets from the committed sources,
+    trusting no artifact (``make -B``) and no failure memo found on disk.
+    ``make``'s output goes to ``stderr``; raises CalledProcessError when
+    the build fails."""
+    for memo in glob.glob(_marker_path("*")):
+        os.unlink(memo)
+    subprocess.run(
+        ["make", "-B", "-C", _NATIVE_DIR],
+        stdout=stderr,
+        stderr=stderr,
+        check=True,
+        timeout=600,
+    )
 
 
 def ensure_built(target: str, src_subdir: str) -> bool:

@@ -1,0 +1,143 @@
+"""The main path's kernels, held to the TPU v5e compiler at real widths.
+
+The chip's compiler is installed in the sandbox and compiles for a chip
+that is described, not attached — so every PR learns here, at no chip
+time, what Mosaic/XLA would refuse there (a misaligned slice, too much
+VMEM, a program over HBM). Nothing runs; a compile that passes is not a
+chip run (chip_smoke.py is).
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described inside a module-scoped fixture, never at import, in a skipif
+or a parametrize argument; it compiles in the test's own process (the
+worker that loaded libtpu holds its lock); one file, so one xdist worker
+owns all of it; the persistent compile cache is off around the compiles
+(an entry written for an unattached chip cannot be read back).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from nydus_snapshotter_tpu.ops import (
+    cdc,
+    fused_convert,
+    gear_pallas,
+    probe_pallas,
+    sha256_pallas,
+)
+
+V5E_HBM_BYTES = 16 * 10**9
+CHUNK = 0x10000
+MIB = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    """shape(dims, dtype) -> ShapeDtypeStruct on one described v5e chip,
+    with the persistent compile cache off for this module's compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
+
+
+def test_gear_bitmaps_kernel(shape):
+    p = cdc.CDCParams(CHUNK)
+    win = fused_convert.WINDOW
+    compiled = gear_pallas.gear_bitmaps.lower(
+        shape((16, win + gear_pallas.TAIL), jnp.uint8), p.mask_small, p.mask_large, win
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_pass1_with_pallas_gear_at_512_mib(shape, monkeypatch):
+    """A 512 MiB layer: its buffer pads to 640 MiB. The branch is chosen
+    at trace time from supported(), which asks JAX's default backend —
+    the CPU here — so the test steers it."""
+    monkeypatch.setattr(gear_pallas, "supported", lambda n: True)
+    eng = fused_convert.FusedDeviceEngine(chunk_size=CHUNK)
+    n = 512 * MIB
+    npad = 640 * MIB
+    compiled = fused_convert._pass1.lower(
+        shape((npad,), jnp.uint8),
+        shape((), jnp.int32),
+        eng.params.mask_small,
+        eng.params.mask_large,
+        fused_convert._wcap_for(n, eng.params.bits + 2),
+        fused_convert._wcap_for(n, eng.params.bits - 2),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("digester,top_classes", [("sha256", 3), ("blake3", 1)])
+def test_pass2_gather_digest(shape, monkeypatch, digester, top_classes):
+    """Few rows, real capacities: the top classes of a 64 KiB-chunk
+    layer's plan. The digest rounds' form is chosen at trace time from
+    JAX's default backend — the CPU here — so the test steers it to the
+    unrolled form the chip compiles. That form costs the compiler
+    seconds per class and a real layer brings twelve (sha256) or nine
+    (blake3), so only the top ones are held here."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = fused_convert.FusedDeviceEngine(chunk_size=CHUNK, digester=digester)
+    top = eng._blocks_of(eng.params.max_size)
+    caps = tuple(sorted({top} | {top >> k for k in range(1, top_classes)}))
+    rows = tuple(shape((8,), jnp.int32) for _ in caps)
+    compiled = fused_convert._pass2.lower(
+        shape((64 * MIB,), jnp.uint8), rows, rows, caps, digester=digester
+    ).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_pass2_with_pallas_probe(shape):
+    cap, depth = 1 << 16, 8
+    cp = probe_pallas.padded_slots(cap, depth)
+    rows = (shape((16,), jnp.int32),)
+    compiled = fused_convert._pass2.lower(
+        shape((16 * MIB,), jnp.uint8), rows, rows, (64,),
+        shape((8, cp), jnp.int32), shape((cap,), jnp.int32), cap, depth,
+        pallas_probe=True,
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_sha256_pallas_kernel(shape):
+    compiled = sha256_pallas.sha256_batch_pallas.lower(
+        shape((1024, 1025, 16), jnp.uint32), shape((1024,), jnp.int32)
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("n_queries", [512, 4096])
+def test_probe_kernel_at_1m_entry_table(shape, n_queries):
+    """A 1M-entry dict builds a 2M-slot table; one launch and the
+    segment-mapped form both lower."""
+    cap, depth = 1 << 21, 16
+    cp = probe_pallas.padded_slots(cap, depth)
+    compiled = probe_pallas.probe_padded.lower(
+        shape((8, cp), jnp.int32), shape((cap,), jnp.int32),
+        shape((n_queries, 8), jnp.uint32), table_cap=cap, depth=depth,
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert _device_bytes(compiled) < V5E_HBM_BYTES

@@ -89,7 +89,7 @@ class TestK8sManifests:
     def test_grpc_socket_dir_is_host_mounted(self):
         # config.toml's UDS address must live on a hostPath mount or host
         # containerd can never dial the snapshotter
-        from nydus_snapshotter_tpu.utils.tomlcompat import tomllib
+        import tomllib
 
         with open(os.path.join(MISC, "config.toml"), "rb") as f:
             cfg = tomllib.load(f)

@@ -9,14 +9,15 @@ import os
 import subprocess
 import sys
 
+from nydus_snapshotter_tpu.utils import jax_cache
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_dryrun_multichip_8():
-    env = dict(os.environ)
+    env = jax_cache.child_env()
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/ntpu_jax_cache")
     out = subprocess.run(
         [sys.executable, "-c", "import __graft_entry__ as g; g.dryrun_multichip(8)"],
         capture_output=True,
@@ -31,10 +32,9 @@ def test_dryrun_multichip_8():
 
 
 def test_entry_compiles_single_device():
-    env = dict(os.environ)
+    env = jax_cache.child_env()
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/ntpu_jax_cache")
     child = (
         "import jax; jax.config.update('jax_platforms', 'cpu');\n"
         "import __graft_entry__ as g\n"

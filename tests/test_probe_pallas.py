@@ -64,7 +64,7 @@ class TestProbePallas:
         cap = keys.shape[1]
         # synthesize queries landing in the last window rows
         occupied = np.nonzero(values[0] != 0)[0]
-        tail = occupied[occupied >= cap - probe_pallas.window_rows(depth)]
+        tail = occupied[occupied >= cap - probe_pallas.window_slots(depth)]
         if len(tail) == 0:
             pytest.skip("no occupied slot near the table tail for this seed")
         q = keys[0][tail]
@@ -90,7 +90,9 @@ class TestProbePallas:
         with the native host probe."""
         rng = np.random.default_rng(21)
         digests = rng.integers(0, 2**32, (30_000, 8), dtype=np.uint32)
-        d_pal = ShardedChunkDict(digests, probe_backend="pallas")
+        d_pal = ShardedChunkDict(
+            digests, probe_backend="pallas", pallas_interpret=True
+        )
         d_host = ShardedChunkDict(digests, probe_backend="host")
         q = _queries(digests, 2048, seed=22)
         a = d_pal.lookup_u32(q)

@@ -2,7 +2,7 @@
 artifacts as a cross-revision regression table.
 
 Every profile run this repo gates on banks its report at the repo root
-(``BENCH_r05.json``, ``MULTICORE_r05.json``, ``PROVENANCE_r01.json``,
+(``BENCH_r01.json``, ``MULTICORE_r05.json``, ``PROVENANCE_r01.json``,
 ...). Each family's revisions are a longitudinal record of the same
 workload on the same class of box — this tool joins consecutive
 revisions per family, flattens the numeric leaves, and prints the

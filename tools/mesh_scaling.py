@@ -122,6 +122,7 @@ def _run(n: int, per_dev_kib: int, reps: int) -> dict:
     env["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={n}"
     ).strip()
+    env["JAX_PLATFORMS"] = "cpu"  # virtual devices: the child needs no chip
     out = subprocess.run(
         [
             sys.executable,
