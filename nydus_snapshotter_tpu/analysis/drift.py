@@ -464,7 +464,7 @@ def _callee_closure(model: PackageModel, start_key: str) -> set[str]:
 
 def find_trace_carry_drift(model: PackageModel) -> list[Finding]:
     findings: list[Finding] = []
-    opens = {"span", "start_span", "traced"}
+    opens = {"span", "start_span", "traced", "stage", "batch_span", "Stages"}
     carries = {"capture", "with_context", "remote_context"}
     for key, fi in sorted(model.functions.items()):
         for ref, kind, lineno in fi.spawns:

@@ -56,6 +56,9 @@ TRACE_ATTRS = {
     "span",
     "start_span",
     "traced",
+    "stage",
+    "batch_span",
+    "Stages",
     "capture",
     "with_context",
     "remote_context",

@@ -52,25 +52,36 @@ def _read_prefetch(args) -> str:
 
 
 def cmd_pack(args) -> int:
+    from nydus_snapshotter_tpu import trace
     from nydus_snapshotter_tpu.converter.convert import Pack
     from nydus_snapshotter_tpu.converter.zran import pack_gzip_layer
 
     opt = _pack_option(args)
-    with open(args.input, "rb") as f:
-        src = f.read()
-    if args.oci_ref:
-        from nydus_snapshotter_tpu.converter.convert import frame_bootstrap_only
+    # The verb's root span, opened here so that the file read is inside;
+    # pack_stream's stages follow pack:read under it (docs/observability.md).
+    # Spans leave through the trace ring, never through the result line.
+    with trace.batch_span("convert.pack"):
+        with trace.span("pack:read") as sp, open(args.input, "rb") as f:
+            src = f.read()
+            sp.annotate(bytes=len(src))
+        if args.oci_ref:
+            from nydus_snapshotter_tpu.converter.convert import frame_bootstrap_only
 
-        bootstrap = pack_gzip_layer(src, opt)
-        # Framed like every other layer stream so the output feeds
-        # straight into `merge`.
-        with open(args.out, "wb") as out:
-            out.write(frame_bootstrap_only(bootstrap.to_bytes()))
-        print(json.dumps({"blob_id": bootstrap.blobs[0].blob_id,
-                          "chunks": len(bootstrap.chunks)}))
-        return 0
-    with open(args.out, "wb") as out:
-        res = Pack(out, src, opt)
+            bootstrap = pack_gzip_layer(src, opt)
+            # Framed like every other layer stream so the output feeds
+            # straight into `merge`.
+            with open(args.out, "wb") as out:
+                out.write(frame_bootstrap_only(bootstrap.to_bytes()))
+            print(json.dumps({"blob_id": bootstrap.blobs[0].blob_id,
+                              "chunks": len(bootstrap.chunks)}))
+            return 0
+        # replacing a blob of the same name frees its pages here: a stage
+        with trace.span("pack:open_out") as sp:
+            if os.path.exists(args.out):
+                sp.annotate(replaced_bytes=os.path.getsize(args.out))
+            out = open(args.out, "wb")
+        with out:
+            res = Pack(out, src, opt)
     print(json.dumps({
         "blob_id": res.blob_id,
         "blob_size": res.blob_size,
@@ -80,25 +91,29 @@ def cmd_pack(args) -> int:
 
 
 def cmd_merge(args) -> int:
+    from nydus_snapshotter_tpu import trace
     from nydus_snapshotter_tpu.converter.convert import Merge
     from nydus_snapshotter_tpu.converter.types import MergeOption
 
-    layers = []
-    for path in args.layers:
-        with open(path, "rb") as f:
-            layers.append(f.read())
-    res = Merge(
-        layers,
-        MergeOption(
-            fs_version=args.fs_version,
-            chunk_dict_path=args.chunk_dict or "",
-            prefetch_patterns=_read_prefetch(args),
-            bootstrap_format=getattr(args, "bootstrap_format", "native"),
-            digester=getattr(args, "digester", "sha256"),
-        ),
-    )
-    with open(args.out, "wb") as f:
-        f.write(res.bootstrap)
+    with trace.batch_span("convert.merge"):
+        layers = []
+        with trace.span("merge:read", layers=len(args.layers)) as sp:
+            for path in args.layers:
+                with open(path, "rb") as f:
+                    layers.append(f.read())
+            sp.annotate(bytes_read=sum(len(b) for b in layers))
+        res = Merge(
+            layers,
+            MergeOption(
+                fs_version=args.fs_version,
+                chunk_dict_path=args.chunk_dict or "",
+                prefetch_patterns=_read_prefetch(args),
+                bootstrap_format=getattr(args, "bootstrap_format", "native"),
+                digester=getattr(args, "digester", "sha256"),
+            ),
+        )
+        with trace.span("merge:emit", bytes=len(res.bootstrap)), open(args.out, "wb") as f:
+            f.write(res.bootstrap)
     print(json.dumps({"blob_digests": res.blob_digests}))
     return 0
 
@@ -441,9 +456,13 @@ def _require_device_backend() -> None:
     never carries on on JAX's CPU backend unless CPU was asked for."""
     import jax
 
+    from nydus_snapshotter_tpu import trace
     from nydus_snapshotter_tpu.utils import jax_cache
 
     jax_cache.enable()
+    # JAX is loaded here anyway: from now on the convert spans also show on
+    # the host plane of a profiler session (trace itself never imports JAX)
+    trace.install_profiler_bridge(jax.profiler.TraceAnnotation)
     asked = (jax.config.jax_platforms or "").split(",")
     if jax.default_backend() == "cpu" and "cpu" not in asked:
         raise RuntimeError(

@@ -26,7 +26,7 @@ from typing import BinaryIO, Callable, Optional
 
 from nydus_snapshotter_tpu.utils.zstdcompat import zstandard
 
-from nydus_snapshotter_tpu import constants
+from nydus_snapshotter_tpu import constants, trace
 from nydus_snapshotter_tpu.converter import crypto
 from nydus_snapshotter_tpu.converter.types import ConvertError, MergeOption, PackOption, UnpackOption
 from nydus_snapshotter_tpu.models import fstree, layout, nydus_tar, toc
@@ -547,9 +547,20 @@ def Merge(
     whose blob-digest list comes from merge-output.json,
     tool/builder.go:278-294). ``chunk_dict`` passes an already-loaded dict
     object (batch conversion); ``opt.chunk_dict_path`` is the file fallback.
+
+    Traced as consecutive leaf spans under a ``convert.merge`` root
+    (docs/observability.md): ``merge:parse`` (dictionary, parent and each
+    layer's bootstrap), ``merge:overlay`` (overlay, dedup re-pointing,
+    tables), ``merge:emit`` (serialization).
     """
+    with trace.batch_span("convert.merge"), trace.Stages() as stages:
+        return _merge(layers, opt, chunk_dict, stages)
+
+
+def _merge(layers, opt, chunk_dict, stages) -> MergeResult:
     if not layers:
         raise ConvertError("merge needs at least one layer")
+    stages.next("merge:parse", layers=len(layers))
     if chunk_dict is None and opt.chunk_dict_path:
         from nydus_snapshotter_tpu.parallel.dict_service import open_chunk_dict
 
@@ -585,6 +596,11 @@ def Merge(
         boots.append(
             layer if isinstance(layer, Bootstrap) else _layer_bootstrap(layer)
         )
+    stages.annotate(
+        inodes=sum(len(b.inodes) for b in boots),
+        chunks=sum(len(b.chunks) for b in boots),
+    )
+    stages.next("merge:overlay")
     chunk_size = boots[-1].chunk_size
     version = opt.fs_version or boots[-1].version
     lower: list[_Node] = []
@@ -672,6 +688,8 @@ def Merge(
         if opt.prefetch_patterns
         else [],
     )
+    stages.annotate(inodes=len(inodes), chunks=len(chunk_records))
+    stages.next("merge:emit")
     if opt.bootstrap_format in ("rafs-v5", "rafs-v6"):
         # Emit the image bootstrap in the reference toolchain's own
         # layout so its ecosystem can mount what this framework built.
@@ -715,6 +733,7 @@ def Merge(
             info.mode = 0o444
             tf.addfile(info, io.BytesIO(boot_bytes))
         boot_bytes = out.getvalue()
+    stages.annotate(bootstrap_bytes=len(boot_bytes))
     return MergeResult(
         bootstrap=boot_bytes,
         blob_digests=[b.blob_id for b in blob_table],

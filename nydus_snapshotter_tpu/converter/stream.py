@@ -32,7 +32,7 @@ from typing import BinaryIO, Optional
 
 import numpy as np
 
-from nydus_snapshotter_tpu import constants
+from nydus_snapshotter_tpu import constants, trace
 from nydus_snapshotter_tpu.converter import crypto
 from nydus_snapshotter_tpu.converter.types import ConvertError, PackOption
 from nydus_snapshotter_tpu.models import fstree, layout, nydus_tar, toc
@@ -716,11 +716,19 @@ def pack_stream(
     reuse one growing dict without re-parsing a bootstrap per layer;
     ``opt.chunk_dict_path`` is the file-based fallback.
 
-    ``stats``: optional dict that accumulates per-stage wall seconds
-    (in-memory fast-path semantics): ``scan`` tar walk + metadata,
-    ``chunk_digest`` CDC + chunk SHA-256, ``dedup`` dedup/bookkeeping,
-    ``assemble`` compression + blob append + blob digest,
-    ``bootstrap`` inode/chunk-table serialization.
+    The pack's wall is a flat partition into consecutive leaf spans under
+    a ``convert.pack`` root (docs/observability.md): ``pack:dict_load``,
+    ``pack:scan``, then the lane's (``pack:lane.*`` from the fused device
+    engine, or one ``pack:chunk_digest`` / ``pack:fused_pack``),
+    ``pack:dedup``, ``pack:compress_write``, ``pack:bootstrap``.
+
+    ``stats``: optional dict that accumulates per-stage wall seconds, the
+    sums of those spans' own times (``_STATS_SPANS``): ``scan`` tar walk +
+    metadata, ``chunk_digest`` CDC + chunk digests (on the per-file lanes
+    the ordered dedup walk interleaves with it and is inside),
+    ``fused_pack`` the whole-layer native pass, ``dedup``
+    dedup/bookkeeping, ``assemble`` compression + blob append + blob
+    digest, ``bootstrap`` inode/chunk-table serialization, ``dict_load``.
 
     ``budget``: optional :class:`parallel.pipeline.MemoryBudget` bounding
     this conversion's speculative-compression bytes in flight; batch
@@ -737,12 +745,35 @@ def pack_stream(
     routes through the Python section writer (the codec-stage interface
     a device-offloaded codec would implement too).
     """
-    import io
-    from time import perf_counter as _pc
+    stages = trace.Stages()
+    try:
+        with trace.batch_span("convert.pack"), stages:
+            return _pack_stream(
+                dest, src_tar, opt, chunk_dict, stats, budget, codec, stages
+            )
+    finally:
+        if stats is not None:
+            for key, names in _STATS_SPANS.items():
+                stats[key] = stats.get(key, 0.0) + sum(
+                    v for k, v in stages.seconds.items() if k.startswith(names)
+                )
 
-    _t_chunk = 0.0
-    _t_spec = 0.0  # speculative compression (counts toward 'assemble')
-    _t_fused = 0.0  # whole-layer fused pass (chunk+dedup+assemble in one)
+
+# pack_stream's ``stats`` keys <- the leaf spans (name prefixes) they sum
+_STATS_SPANS = {
+    "dict_load": ("pack:dict_load",),
+    "scan": ("pack:scan",),
+    "chunk_digest": ("pack:chunk_digest", "pack:lane."),
+    "fused_pack": ("pack:fused_pack",),
+    "dedup": ("pack:dedup",),
+    "assemble": ("pack:compress_write",),
+    "bootstrap": ("pack:bootstrap",),
+}
+
+
+def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
+    """pack_stream's body; ``stages`` (trace.Stages) runs its leaf spans."""
+    import io
 
     opt.validate()
     # In-memory layers take the zero-copy path: random-access tar parse,
@@ -759,7 +790,14 @@ def pack_stream(
         # other shape is the file-based dict as before.
         from nydus_snapshotter_tpu.parallel.dict_service import open_chunk_dict
 
+        stages.next("pack:dict_load")
         chunk_dict = open_chunk_dict(opt.chunk_dict_path)
+        stages.annotate(
+            dict_chunks=len(chunk_dict), dict_blobs=len(chunk_dict.blob_ids())
+        )
+    # everything up to the chunk stage's own call is the scan: set-up, the
+    # member walk, the plan's extents
+    stages.next("pack:scan")
     from nydus_snapshotter_tpu.converter.convert import _make_compressor
 
     if codec is None:
@@ -925,9 +963,10 @@ def pack_stream(
         for chunk, digest in chunker.finish():
             _add_chunk(meta, chunk, digest)
 
-    _t0 = _pc()
+    n_members = 0
     members = _fast_tar_members(raw) if raw is not None else None
     if members is not None:
+        n_members = len(members)
         for info, data_off in members:
             _walk_member(info, data_off, None)  # tf unused: data via raw
     else:
@@ -942,6 +981,7 @@ def pack_stream(
         with tf:
             try:
                 for info in tf:
+                    n_members += 1
                     _walk_member(
                         info,
                         info.offset_data if raw is not None else None,
@@ -949,7 +989,11 @@ def pack_stream(
                     )
             except tarfile.TarError as e:
                 raise ConvertError(f"bad layer tar: {e}") from e
-    _t1 = _pc()
+    stages.annotate(
+        members=n_members,
+        files_planned=len(plan),
+        bytes_planned=sum(size for _t, _m, _o, size in plan),
+    )
     if plan:
         from nydus_snapshotter_tpu.ops import native_cdc
 
@@ -989,7 +1033,7 @@ def pack_stream(
             ext = np.asarray(
                 [(off, size) for _t, _m, off, size in plan], dtype=np.int64
             )
-            _tc = _pc()
+            stages.next("pack:fused_pack")
             fused = native_cdc.pack_files(
                 arr_all, ext, params, section._kind, section._accel, n_threads,
                 digester=opt.digester,
@@ -1023,16 +1067,15 @@ def pack_stream(
                     fused["blob"], fused["comp_extents"], fused["blob_digest"]
                 )
                 plan = []
-                _t_fused += _pc() - _tc
         if use_multi and plan:
             ext = np.asarray(
                 [(off, size) for _t, _m, off, size in plan], dtype=np.int64
             )
-            _tc = _pc()
+            stages.next("pack:chunk_digest")
             ncuts_arr, cuts_all, digs_all = native_cdc.chunk_digest_multi(
                 arr_all, ext, params, digester=opt.digester
             )
-            _t_chunk += _pc() - _tc
+            stages.next("pack:dedup")
             pos = 0
             for (tag, meta, off, size), nc in zip(plan, ncuts_arr):
                 nc = int(nc)
@@ -1065,14 +1108,15 @@ def pack_stream(
                 chunk_size=opt.chunk_size, digester=opt.digester
             )
             streams = [arr_all[off : off + size] for _t, _m, off, size in plan]
-            _tc = _pc()
+            stages.close()  # the lane runs its own stages: pack:lane.*
             try:
                 fres = feng.process_many(streams)
             except fused_convert.FusedOverflow:
                 fres = None  # pathological input: per-file paths below
                 fused_convert.record_host_fallback()
-            _t_chunk += _pc() - _tc
             if fres is not None:
+                stages.next("pack:dedup")
+                stages.seconds.update(fres.span_seconds or {})  # pack:lane.*, once a pack
                 for (_tag, meta, off, size), fcuts, dlist in zip(
                     plan, fres.cuts, fres.digests
                 ):
@@ -1088,12 +1132,15 @@ def pack_stream(
         small_items = [
             (arr_all, off, size) for tag, _m, off, size in plan if tag == "small"
         ]
+        if plan:
+            # the per-file lanes: chunking (here, or on the pipeline's
+            # workers) interleaves with the ordered dedup walk file by
+            # file, so the loop is ONE span, never one a file
+            stages.next("pack:chunk_digest")
         if small_items:
             from nydus_snapshotter_tpu.ops.chunker import host_digests_for
 
-            _tc = _pc()
             small_digests = iter(host_digests_for(opt.digester)(small_items))
-            _t_chunk += _pc() - _tc
 
         # Within-layer parallelism for multi-core hosts (the reference gets
         # it from the builder's internal thread pool): the stage-parallel
@@ -1202,13 +1249,11 @@ def pack_stream(
                 if tag == "small":  # ≤ min_size ⇒ exactly one chunk
                     _process([(meta, view)], [next(small_digests)])
                     continue
-                _tc = _pc()
                 chunks = (
                     pipe.chunks_for(i)
                     if pipe is not None
                     else shared_chunker.chunk_whole(view)
                 )
-                _t_chunk += _pc() - _tc
                 if chunks and chunks[0][1] is not None:
                     _process(
                         [(meta, c) for c, _ in chunks],
@@ -1220,12 +1265,20 @@ def pack_stream(
                 else:
                     for chunk, digest in chunks:
                         _add_chunk(meta, chunk, digest)
-    _t2 = _pc()
+    if stages.running != "pack:dedup":
+        stages.next("pack:dedup")
     _drain_all()
+    stages.annotate(
+        chunks=sum(len(m.chunks) for m in metas.values()),
+        unique=len(uncomp_offsets),
+        dict_hits=len(dict_hits),
+    )
+    stages.next("pack:compress_write", uncompressed_bytes=uoff)
     section.finish()
-    _t3 = _pc()
-
     blob_size = section.coff
+    stages.annotate(blob_bytes=blob_size)
+    stages.next("pack:bootstrap")
+
     blob_id = section.hasher.hexdigest() if blob_size else ""
     if blob_size:
         out.write(nydus_tar.make_header(toc.ENTRY_BLOB_DATA, blob_size))
@@ -1363,17 +1416,11 @@ def pack_stream(
     toc_bytes = toc.pack_toc(toc_entries)
     out.write(toc_bytes)
     out.write(nydus_tar.make_header(toc.ENTRY_BLOB_TOC, len(toc_bytes)))
-
-    if stats is not None:
-        stats["scan"] = stats.get("scan", 0.0) + (_t1 - _t0)
-        stats["chunk_digest"] = stats.get("chunk_digest", 0.0) + _t_chunk
-        # fused_pack spans chunk+dedup+assemble inside one native call
-        stats["fused_pack"] = stats.get("fused_pack", 0.0) + _t_fused
-        stats["dedup"] = stats.get("dedup", 0.0) + (
-            _t2 - _t1 - _t_chunk - _t_spec - _t_fused
-        )
-        stats["assemble"] = stats.get("assemble", 0.0) + (_t3 - _t2) + _t_spec
-        stats["bootstrap"] = stats.get("bootstrap", 0.0) + (_pc() - _t3)
+    stages.annotate(
+        inodes=len(inodes),
+        chunk_records=len(chunk_records),
+        bootstrap_bytes=len(boot_bytes),
+    )
 
     from nydus_snapshotter_tpu.converter.convert import PackResult
 

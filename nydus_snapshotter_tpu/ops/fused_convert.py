@@ -63,7 +63,9 @@ def _counters():
     padded buffer, h2d = upload, pass1_gear = gear+compaction dispatch
     and candidate D2H, host_resolve = cut resolution + bucket plan (the
     host arm between dispatches), pass2_digest = gather+digest+probe
-    dispatch."""
+    dispatch, digest_d2h = digest states (and probe) back to the host as
+    per-chunk bytes. Fed from the ``pack:lane.*`` spans' own times
+    (process_many): nothing is timed twice."""
     from nydus_snapshotter_tpu.metrics import registry as _metrics
 
     # literal Counter(...) calls: tools/analyze.py's metric drift gate
@@ -151,14 +153,17 @@ def _pass1(
 
     from nydus_snapshotter_tpu.ops import gear_pallas
 
-    if gear_pallas.supported(WINDOW):
-        bm_s, bm_l = gear_pallas.gear_bitmaps(rows, mask_s, mask_l, WINDOW)
-    else:
-        from nydus_snapshotter_tpu.ops.chunker import _hash_bitmaps_kernel
+    # named scopes: stable names for the parts of the program in a device
+    # trace (op-name metadata; not part of the compile cache's key)
+    with jax.named_scope("gear"):
+        if gear_pallas.supported(WINDOW):
+            bm_s, bm_l = gear_pallas.gear_bitmaps(rows, mask_s, mask_l, WINDOW)
+        else:
+            from nydus_snapshotter_tpu.ops.chunker import _hash_bitmaps_kernel
 
-        bm_s, bm_l = _hash_bitmaps_kernel(
-            rows, jnp.uint32(mask_s), jnp.uint32(mask_l), WINDOW
-        )
+            bm_s, bm_l = _hash_bitmaps_kernel(
+                rows, jnp.uint32(mask_s), jnp.uint32(mask_l), WINDOW
+            )
 
     nwords = npad // 32
     widx_valid = jnp.arange(nwords, dtype=jnp.int32) < (n + 31) // 32
@@ -179,8 +184,10 @@ def _pass1(
         )  # u32[wcap]
         return sel.astype(jnp.int32), got, nw
 
-    sel_s, got_s, nw_s = compact(bm_s, wcap_s)
-    sel_l, got_l, nw_l = compact(bm_l, wcap_l)
+    with jax.named_scope("compact_s"):
+        sel_s, got_s, nw_s = compact(bm_s, wcap_s)
+    with jax.named_scope("compact_l"):
+        sel_l, got_l, nw_l = compact(bm_l, wcap_l)
     return sel_s, got_s, nw_s, sel_l, got_l, nw_l
 
 
@@ -201,13 +208,16 @@ class Bucket:
     """One power-of-two block-capacity class of the pass-2 plan.
 
     offsets/sizes are pow2-padded (padding rows have size 0 and offset 0
-    and are discarded on assembly); ``count`` is the live prefix.
+    and are discarded on assembly); ``count`` is the live prefix and
+    ``blocks`` the digest blocks its chunks really hold (of the
+    ``M * cap_blocks`` the class computes).
     """
 
     cap_blocks: int
     offsets: np.ndarray  # i32[M] absolute byte offsets into the buffer
     sizes: np.ndarray  # i32[M]
     count: int
+    blocks: int = 0
 
 
 def _gather_pack_sha(buffer: jax.Array, offs: jax.Array, sizes: jax.Array, cap_blocks: int):
@@ -294,30 +304,35 @@ def _pass2(
         if digester == "blake3":
             from nydus_snapshotter_tpu.ops import blake3_jax
 
-            blocks = _gather_pack_b3(buffer, offs, sizes, cap)
-            states.append(blake3_jax._blake3_batch_jit(blocks, sizes, unroll))
+            with jax.named_scope(f"gather_c{cap}"):
+                blocks = _gather_pack_b3(buffer, offs, sizes, cap)
+            with jax.named_scope(f"blake3_c{cap}"):
+                states.append(blake3_jax._blake3_batch_jit(blocks, sizes, unroll))
         else:
-            blocks = _gather_pack_sha(buffer, offs, sizes, cap)
-            counts = (sizes + 8) // 64 + 1
-            states.append(sha256._sha256_batch_jit(blocks, counts, unroll))
+            with jax.named_scope(f"gather_c{cap}"):
+                blocks = _gather_pack_sha(buffer, offs, sizes, cap)
+            with jax.named_scope(f"sha256_c{cap}"):
+                counts = (sizes + 8) // 64 + 1
+                states.append(sha256._sha256_batch_jit(blocks, counts, unroll))
     probe = None
     if table_keys is not None:
         allq = jnp.concatenate(states, axis=0)
-        if pallas_probe:
-            # DMA-pipelined Pallas probe (ops/probe_pallas): the XLA
-            # gather formulation runs effectively element-serially on
-            # TPU — at full-batch chunk counts it would dominate the
-            # dispatch. Keys arrive in the wrap-free lane-dense layout.
-            from nydus_snapshotter_tpu.ops import probe_pallas
+        with jax.named_scope("probe"):
+            if pallas_probe:
+                # DMA-pipelined Pallas probe (ops/probe_pallas): the XLA
+                # gather formulation runs effectively element-serially on
+                # TPU — at full-batch chunk counts it would dominate the
+                # dispatch. Keys arrive in the wrap-free lane-dense layout.
+                from nydus_snapshotter_tpu.ops import probe_pallas
 
-            probe = probe_pallas.probe_padded(
-                table_keys, table_vals, allq, table_cap, depth,
-                interpret=probe_interpret,
-            )
-        else:
-            from nydus_snapshotter_tpu.parallel.sharded_dict import _probe_local
+                probe = probe_pallas.probe_padded(
+                    table_keys, table_vals, allq, table_cap, depth,
+                    interpret=probe_interpret,
+                )
+            else:
+                from nydus_snapshotter_tpu.parallel.sharded_dict import _probe_local
 
-            probe = _probe_local(table_keys, table_vals, allq, table_cap, depth)
+                probe = _probe_local(table_keys, table_vals, allq, table_cap, depth)
     return tuple(states), probe
 
 
@@ -333,6 +348,8 @@ class FusedResult:
     cuts: list[np.ndarray]  # per-stream exclusive cut ends
     digests: list[list[bytes]]  # per-stream raw 32-B sha256 digests
     probe: np.ndarray | None  # i32 over all chunks in stream order (0=miss)
+    # seconds per ``pack:lane.*`` span of the batch (None for an empty one)
+    span_seconds: dict[str, float] | None = None
 
 
 class FusedDeviceEngine:
@@ -450,6 +467,7 @@ class FusedDeviceEngine:
         """
         max_blocks = self._blocks_of(self.params.max_size)
         per_class: dict[int, list[tuple[int, int]]] = {}
+        real_blocks: dict[int, int] = {}
         order: list[tuple[int, int]] = []
         for (f_off, _f_len), f_cuts in zip(table, cuts):
             prev = 0
@@ -458,6 +476,7 @@ class FusedDeviceEngine:
                 nb = self._blocks_of(size)
                 cap = min(_pow2_ceil(nb), max_blocks)
                 rows = per_class.setdefault(cap, [])
+                real_blocks[cap] = real_blocks.get(cap, 0) + nb
                 order.append((cap, len(rows)))
                 rows.append((f_off + prev, size))
                 prev = int(cut)
@@ -469,13 +488,15 @@ class FusedDeviceEngine:
             sizes = np.zeros(m, dtype=np.int32)
             offs[: len(rows)] = [r[0] for r in rows]
             sizes[: len(rows)] = [r[1] for r in rows]
-            buckets.append(Bucket(cap, offs, sizes, len(rows)))
+            buckets.append(Bucket(cap, offs, sizes, len(rows), real_blocks[cap]))
         return buckets, order
 
     # -- execution -----------------------------------------------------------
 
-    def candidates(self, buffer_dev: jax.Array, n: int) -> tuple[np.ndarray, np.ndarray]:
-        """Pass 1 on an already-device-resident buffer."""
+    def candidate_words(self, buffer_dev: jax.Array, n: int):
+        """Pass 1 on an already-device-resident buffer, up to its first
+        sync -> (sel_s, got_s, nw_s, sel_l, got_l, nw_l, wcap_s, wcap_l),
+        the word lists still on the device, the counts on the host."""
         p = self.params
         wcap_s = _wcap_for(n, p.bits + 2)
         wcap_l = _wcap_for(n, p.bits - 2)
@@ -487,18 +508,27 @@ class FusedDeviceEngine:
             raise FusedOverflow(
                 f"candidate words {nw_s}/{nw_l} exceed caps {wcap_s}/{wcap_l}"
             )
-        def host_pos(sel, got, nw):
-            # expand word-index + bitmap word to int64 byte positions
-            sel = np.asarray(jax.device_get(sel))[:nw].astype(np.int64)
-            got = np.asarray(jax.device_get(got))[:nw]
-            bits = np.unpackbits(
-                got.view(np.uint8).reshape(-1, 4), axis=1, bitorder="little"
-            )  # [nw, 32]
-            widx, bit = np.nonzero(bits)
-            pos = sel[widx] * 32 + bit
-            return pos[pos < n]
+        return sel_s, got_s, nw_s, sel_l, got_l, nw_l, wcap_s, wcap_l
 
-        return host_pos(sel_s, got_s, nw_s), host_pos(sel_l, got_l, nw_l)
+    @staticmethod
+    def candidate_positions(sel, got, nw: int, n: int) -> np.ndarray:
+        """Candidate D2H: word indices + bitmap words -> int64 byte positions."""
+        sel = np.asarray(jax.device_get(sel))[:nw].astype(np.int64)
+        got = np.asarray(jax.device_get(got))[:nw]
+        bits = np.unpackbits(
+            got.view(np.uint8).reshape(-1, 4), axis=1, bitorder="little"
+        )  # [nw, 32]
+        widx, bit = np.nonzero(bits)
+        pos = sel[widx] * 32 + bit
+        return pos[pos < n]
+
+    def candidates(self, buffer_dev: jax.Array, n: int) -> tuple[np.ndarray, np.ndarray]:
+        """Pass 1 + candidate D2H on an already-device-resident buffer."""
+        sel_s, got_s, nw_s, sel_l, got_l, nw_l, _, _ = self.candidate_words(buffer_dev, n)
+        return (
+            self.candidate_positions(sel_s, got_s, nw_s, n),
+            self.candidate_positions(sel_l, got_l, nw_l, n),
+        )
 
     def digest_probe(
         self,
@@ -595,9 +625,7 @@ class FusedDeviceEngine:
         probe_kernel: str = "auto",
         dict_epoch: int | None = None,
     ) -> FusedResult:
-        from time import perf_counter as _pc
-
-        from nydus_snapshotter_tpu import failpoint
+        from nydus_snapshotter_tpu import failpoint, trace
 
         # Device batch boundary: chaos-testable (an injected error
         # propagates — callers redo a batch on the host lanes only for
@@ -605,66 +633,90 @@ class FusedDeviceEngine:
         # scheduling around the two dispatches is visible next to the
         # pipeline's stage counters.
         failpoint.hit("fused.dispatch")
-        arrs = [
-            np.frombuffer(s, dtype=np.uint8) if isinstance(s, (bytes, bytearray)) else s
-            for s in streams
-        ]
-        n = sum(a.size for a in arrs)
-        if n == 0:
-            return FusedResult(
-                cuts=[np.asarray([], dtype=np.int64) for _ in arrs],
-                digests=[[] for _ in arrs],
-                probe=np.zeros(0, np.int32) if chunk_dict is not None else None,
+        # One span a stage, consecutive (trace.Stages): a span's enter/exit
+        # is the only clock read at its boundary, and the stage counters
+        # and the caller's stats are fed from the spans' own seconds.
+        with trace.Stages() as lane:
+            lane.next("pack:lane.layout")
+            arrs = [
+                np.frombuffer(s, dtype=np.uint8) if isinstance(s, (bytes, bytearray)) else s
+                for s in streams
+            ]
+            n = sum(a.size for a in arrs)
+            if n == 0:
+                return FusedResult(
+                    cuts=[np.asarray([], dtype=np.int64) for _ in arrs],
+                    digests=[[] for _ in arrs],
+                    probe=np.zeros(0, np.int32) if chunk_dict is not None else None,
+                )
+            buf, table = self.layout(arrs)
+            lane.annotate(bytes=n, padded_bytes=int(buf.size))
+            # committed to the default device; blocked on so the upload is
+            # its own stage (pass 1 needs the whole buffer before it starts)
+            lane.next("pack:lane.h2d", bytes=int(buf.size))
+            buffer_dev = jax.block_until_ready(jnp.asarray(buf))
+            lane.next("pack:lane.pass1")
+            sel_s, got_s, nw_s, sel_l, got_l, nw_l, wcap_s, wcap_l = self.candidate_words(
+                buffer_dev, n
             )
-        _t0 = _pc()
-        buf, table = self.layout(arrs)
-        _t1 = _pc()
-        # committed to the default device; blocked on so the upload is
-        # its own stage (pass 1 needs the whole buffer before it starts)
-        buffer_dev = jax.block_until_ready(jnp.asarray(buf))
-        _t2 = _pc()
-        cand_s, cand_l = self.candidates(buffer_dev, n)
-        _t3 = _pc()
-        cuts = self.resolve(cand_s, cand_l, table)
-        buckets, order = self.plan_buckets(table, cuts)
-        _t4 = _pc()
-        states, probe = self.digest_probe(
-            buffer_dev, buckets, chunk_dict, depth, probe_kernel, dict_epoch
-        )
-        jax.block_until_ready(states)
+            lane.annotate(wcap_s=wcap_s, wcap_l=wcap_l, words_s=nw_s, words_l=nw_l)
+            lane.next("pack:lane.cand_d2h")
+            cand_s = self.candidate_positions(sel_s, got_s, nw_s, n)
+            cand_l = self.candidate_positions(sel_l, got_l, nw_l, n)
+            lane.annotate(candidates_s=len(cand_s), candidates_l=len(cand_l))
+            lane.next("pack:lane.resolve", files=len(table))
+            cuts = self.resolve(cand_s, cand_l, table)
+            lane.annotate(chunks=sum(len(c) for c in cuts))
+            lane.next("pack:lane.plan")
+            buckets, order = self.plan_buckets(table, cuts)
+            lane.annotate(
+                classes=[[b.cap_blocks, b.count, len(b.offsets)] for b in buckets],
+                blocks_real=sum(b.blocks for b in buckets),
+                blocks_padded=sum(len(b.offsets) * b.cap_blocks for b in buckets),
+            )
+            # a first call of a new plan compiles here: programs_after tells
+            lane.next("pack:lane.pass2", programs_before=_pass2._cache_size())
+            states, probe = self.digest_probe(
+                buffer_dev, buckets, chunk_dict, depth, probe_kernel, dict_epoch
+            )
+            jax.block_until_ready(states)
+            lane.annotate(programs_after=_pass2._cache_size())
+            lane.next("pack:lane.digest_d2h", chunks=len(order))
+            by_cap = {
+                b.cap_blocks: np.asarray(jax.device_get(s))
+                for b, s in zip(buckets, states)
+            }
+            flat_digests = [
+                self._digest_bytes(by_cap[cap][row]) for cap, row in order
+            ]
+            probe_np = None
+            if probe is not None:
+                # probe ran over the concatenation of bucket rows (incl.
+                # padding); remap to stream order via each bucket's row base
+                probe_all = np.asarray(jax.device_get(probe))
+                base = {}
+                acc = 0
+                for b in buckets:
+                    base[b.cap_blocks] = acc
+                    acc += len(b.offsets)
+                probe_np = np.asarray(
+                    [probe_all[base[cap] + row] for cap, row in order], dtype=np.int32
+                )
+            out_digests: list[list[bytes]] = []
+            pos = 0
+            for f_cuts in cuts:
+                out_digests.append(flat_digests[pos : pos + len(f_cuts)])
+                pos += len(f_cuts)
+        took = lane.seconds
         _record_dispatch(
             n,
             {
-                "layout": _t1 - _t0,
-                "h2d": _t2 - _t1,
-                "pass1_gear": _t3 - _t2,
-                "host_resolve": _t4 - _t3,
-                "pass2_digest": _pc() - _t4,
+                "layout": took["pack:lane.layout"],
+                "h2d": took["pack:lane.h2d"],
+                "pass1_gear": took["pack:lane.pass1"] + took["pack:lane.cand_d2h"],
+                "host_resolve": took["pack:lane.resolve"] + took["pack:lane.plan"],
+                "pass2_digest": took["pack:lane.pass2"],
+                "digest_d2h": took["pack:lane.digest_d2h"],
             },
         )
-        by_cap = {
-            b.cap_blocks: np.asarray(jax.device_get(s))
-            for b, s in zip(buckets, states)
-        }
-        flat_digests = [
-            self._digest_bytes(by_cap[cap][row]) for cap, row in order
-        ]
-        probe_np = None
-        if probe is not None:
-            # probe ran over the concatenation of bucket rows (incl.
-            # padding); remap to stream order via each bucket's row base
-            probe_all = np.asarray(jax.device_get(probe))
-            base = {}
-            acc = 0
-            for b in buckets:
-                base[b.cap_blocks] = acc
-                acc += len(b.offsets)
-            probe_np = np.asarray(
-                [probe_all[base[cap] + row] for cap, row in order], dtype=np.int32
-            )
-        out_digests: list[list[bytes]] = []
-        pos = 0
-        for f_cuts in cuts:
-            out_digests.append(flat_digests[pos : pos + len(f_cuts)])
-            pos += len(f_cuts)
-        return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np)
+        return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np, span_seconds=took)

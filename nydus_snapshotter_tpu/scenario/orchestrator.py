@@ -548,12 +548,17 @@ class ScenarioRunner:
             compressor="zstd" if adaptive else "lz4_block",
         )
 
+        # pack opens trace spans (convert.pack and its stages): carry the
+        # phase's context onto the pool so they hang off it
+        phase_ctx = trace.capture()
+
         def convert_one(cid: str) -> dict:
             tar = self._corpus_tar(cid)
             codec = (
                 AdaptiveCodec(CodecConfig(adaptive=True)) if adaptive else None
             )
-            blob, res = pack_layer(tar, opt, codec=codec)
+            with trace.with_context(phase_ctx):
+                blob, res = pack_layer(tar, opt, codec=codec)
             return {
                 "cid": cid,
                 "tar_len": len(tar),

@@ -27,6 +27,13 @@ recorder that logs the full reconstructed tree of any root op over
 ``slow_op_threshold_ms``, and over-p95 ``trace_exemplars`` on the metrics
 summaries.
 
+The convert verbs (``cmd.convert pack|merge``) record their wall as a
+flat partition of consecutive leaf spans (:class:`Stages`) under a
+:func:`batch_span` root, feed their stage counters from the leaves' own
+seconds (:func:`stage`), and — once a process that has JAX loaded calls
+:func:`install_profiler_bridge` — show on the JAX profiler's host plane
+(docs/observability.md). This module itself never imports JAX.
+
 Zero-overhead contract (gated by ``tools/trace_profile.py``): with
 tracing disabled, :func:`span` is one global load, one branch and a
 no-op context manager — no ids, no clock reads, no allocation beyond the
@@ -65,9 +72,11 @@ from nydus_snapshotter_tpu.trace.ring import SPANS_DROPPED, LazyCounter, SpanRin
 __all__ = [
     "Span",
     "SpanContext",
+    "Stages",
     "TraceRuntimeConfig",
     "annotate",
     "annotate_failpoint",
+    "batch_span",
     "capture",
     "chrome_trace",
     "chrome_trace_bytes",
@@ -76,12 +85,14 @@ __all__ = [
     "dump_text",
     "enabled",
     "exemplars",
+    "install_profiler_bridge",
     "remote_context",
     "reset",
     "resolve_trace_config",
     "slow_ops",
     "snapshot_spans",
     "span",
+    "stage",
     "start_span",
     "traced",
     "with_context",
@@ -185,7 +196,13 @@ class Span:
 
     Ids are ints — ``(pid | boot-time) << 32 | counter`` — formatted to
     strings only at the export boundary (Chrome args, exemplars), where a
-    raw 64-bit int would lose precision in JavaScript JSON consumers."""
+    raw 64-bit int would lose precision in JavaScript JSON consumers.
+
+    ``t0`` is the start on ``time.perf_counter()`` — the clock a caller's
+    own records and the JAX profiler's host events share — and ``t1`` the
+    end on it; ``start`` is the same instant as epoch seconds. ``batch``
+    marks a root that runs for seconds by nature (a convert verb): the
+    slow-op recorder leaves it alone."""
 
     __slots__ = (
         "name",
@@ -196,9 +213,11 @@ class Span:
         "duration_ms",
         "attrs",
         "thread",
+        "t0",
+        "batch",
         "_tracer",
-        "_t0",
         "_token",
+        "_ann",
     )
 
     sampled = True  # a live span in the context ⇒ the trace is sampled
@@ -212,21 +231,39 @@ class Span:
         self.duration_ms = 0.0
         self.attrs = attrs
         self.thread = ""
+        self.t0 = 0.0  # perf_counter seconds
+        self.batch = False
         self._tracer = tracer
+        self._ann = None
 
     @property
     def span(self) -> "Span":
         return self
 
+    @property
+    def seconds(self) -> float:
+        return self.duration_ms / 1000.0
+
+    @property
+    def t1(self) -> float:
+        return self.t0 + self.seconds
+
     def __enter__(self) -> "Span":
         self.thread = _thread_name()
-        self._t0 = t0 = perf_counter()
+        self.t0 = t0 = perf_counter()
         self.start = _EPOCH_OFFSET + t0
         self._token = _current.set(self)
+        bridge = _profiler_annotation
+        if bridge is not None:
+            self._ann = ann = bridge(self.name)
+            ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self.duration_ms = (perf_counter() - self._t0) * 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self.duration_ms = (perf_counter() - self.t0) * 1000.0
         if exc is not None:
             self.attrs["error"] = f"{exc_type.__name__}: {exc}"
         _current.reset(self._token)
@@ -261,6 +298,10 @@ _UNSAMPLED_CTX = SpanContext(0, 0, False, None)
 # monotonic clock read per span edge instead of time()+perf_counter().
 _EPOCH_OFFSET = time.time() - perf_counter()
 
+# The profiler bridge (install_profiler_bridge): name -> context manager,
+# None until a process that has JAX loaded installs one.
+_profiler_annotation = None
+
 _tls = threading.local()
 
 
@@ -293,6 +334,32 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+
+
+class _Stopwatch:
+    """What :func:`stage` hands out when nothing records: the reading
+    surface of a span (``name``, ``t0``, ``seconds``), two clock reads, no
+    ids, no ring."""
+
+    __slots__ = ("name", "t0", "seconds")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.t0 = self.seconds = 0.0
+
+    def __enter__(self) -> "_Stopwatch":
+        self.t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = perf_counter() - self.t0
+        return False
+
+    def annotate(self, **attrs) -> None:
+        pass
+
+    def end(self, error: Optional[BaseException] = None) -> None:
+        self.__exit__(None, None, None)
 
 
 class _UnsampledRoot:
@@ -356,7 +423,7 @@ class Tracer:
         self.ring.push(sp)
         if not sp.parent_id:
             self.exemplar_store.record(sp)
-            if 0 < self.cfg.slow_op_threshold_ms <= sp.duration_ms:
+            if 0 < self.cfg.slow_op_threshold_ms <= sp.duration_ms and not sp.batch:
                 SLOW_OPS.inc()
                 self.recorder.record(sp, self.ring)
 
@@ -416,6 +483,85 @@ def start_span(name: str, /, **attrs):
     s = span(name, **attrs)
     s.__enter__()
     return s
+
+
+def batch_span(name: str, /, **attrs):
+    """A span around a batch verb (``convert.pack``, ``convert.merge``): a
+    GiB of work runs for seconds by nature, so as a root it never trips
+    the slow-op recorder. Re-entrant by name — inside a span of the same
+    name it is a no-op — so the CLI verb and the library entry beneath it
+    share one root wherever the caller came in."""
+    ctx = _current.get()
+    if ctx is not None and ctx.span is not None and ctx.span.name == name:
+        return _NOOP
+    s = span(name, **attrs)
+    if isinstance(s, Span):
+        s.batch = True
+    return s
+
+
+def stage(name: str, /, **attrs):
+    """A span whose own time the caller reads (``.seconds``) to feed a
+    counter or a stats dict: timed even when the tracer is off or the
+    trace sampled out (then a bare stopwatch, nothing recorded), so the
+    span's enter/exit is the only pair of clock reads at that boundary."""
+    s = span(name, **attrs)
+    return s if isinstance(s, Span) else _Stopwatch(name)
+
+
+class Stages:
+    """A flat partition of one operation's wall into consecutive leaf
+    spans: ``next(name)`` closes the running stage and opens the next, so
+    nothing lies between two stages; ``seconds`` holds the sum per name.
+    Use as a context manager: leaving it (an error too) closes the
+    running stage."""
+
+    __slots__ = ("seconds", "_cur")
+
+    def __init__(self):
+        self.seconds: dict[str, float] = {}
+        self._cur = None
+
+    def next(self, name: str, /, **attrs):
+        self.close()
+        self._cur = cur = stage(name, **attrs)
+        cur.__enter__()
+        return cur
+
+    @property
+    def running(self) -> Optional[str]:
+        return self._cur.name if self._cur is not None else None
+
+    def annotate(self, **attrs) -> None:
+        """Counts known only at the running stage's end, onto it."""
+        if self._cur is not None:
+            self._cur.annotate(**attrs)
+
+    def close(self) -> None:
+        cur = self._cur
+        if cur is None:
+            return
+        self._cur = None
+        cur.end()
+        self.seconds[cur.name] = self.seconds.get(cur.name, 0.0) + cur.seconds
+
+    def __enter__(self) -> "Stages":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.close()
+        return False
+
+
+def install_profiler_bridge(annotation) -> None:
+    """From now on every span also enters/exits ``annotation(name)`` —
+    ``jax.profiler.TraceAnnotation``, handed in by a process that has JAX
+    loaded anyway (``cmd.convert`` with a device backend): the span then
+    shows on the host plane of a running profiler session, on the same
+    clock as ``Span.t0``. With no session an annotation is a flag test.
+    This module never imports JAX itself; ``None`` removes the bridge."""
+    global _profiler_annotation
+    _profiler_annotation = annotation
 
 
 def traced(name: str):

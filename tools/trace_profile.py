@@ -21,7 +21,11 @@ a latency-simulating filesystem facade):
   flight attributed to the root's trace id, and export as valid Chrome
   ``trace_event`` JSON.
 
-Also reports span throughput (spans/sec into the ring) and ring drops.
+Also reports span throughput (spans/sec into the ring) and ring drops,
+and the convert verbs' tracing budget: spans a ``pack`` / ``merge`` (the
+count must not follow the file count) and their cost a verb, priced at
+the measured cost of one ``trace.Stages`` boundary with a profiler bridge
+installed (gated at ``--max-verb-us``, default 1000).
 Doubles as the CI smoke driver (``trace-smoke`` job, PYTHONDEVMODE=1) and
 feeds ``bench.py``'s ``detail.trace``.
 
@@ -84,6 +88,77 @@ def disabled_cost(n: int = 200000) -> dict:
             pass
     dt = perf_counter() - t0
     return {"calls": n, "ns_per_call": round(dt / n * 1e9, 1)}
+
+
+def convert_verbs(n: int = 20000) -> dict:
+    """Spans a served ``pack`` (fused lane, XLA on the CPU here) and a
+    ``merge`` record, for a 16-file and a 400-file tar, and what they
+    cost: spans x the measured cost of one stage boundary (span exit +
+    next span enter + a no-op profiler annotation)."""
+    import contextlib
+    import io
+    import tarfile
+
+    from nydus_snapshotter_tpu.cmd import convert as cli
+
+    class _Annotation:  # what a TraceAnnotation costs with no session: nearly nothing
+        def __init__(self, name):
+            pass
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def tar_of(files: int) -> bytes:
+        buf = io.BytesIO()
+        with tarfile.open(fileobj=buf, mode="w") as tf:
+            for i in range(files):
+                data = bytes([i % 251]) * (300_000 if i < 2 else 1500)
+                info = tarfile.TarInfo(f"d/f{i}")
+                info.size = len(data)
+                tf.addfile(info, io.BytesIO(data))
+        return buf.getvalue()
+
+    work = tempfile.mkdtemp(prefix="ntpu_trace_convert.")
+    counts = {}
+    try:
+        for files in (16, 400):
+            tar, blob = os.path.join(work, f"{files}.tar"), os.path.join(work, f"{files}.nydus")
+            with open(tar, "wb") as f:
+                f.write(tar_of(files))
+            for verb, argv in (
+                ("pack", ["pack", "--in", tar, "--out", blob, "--backend", "fused", "--chunk-size", "0x10000"]),
+                ("merge", ["merge", "--out", blob + ".boot", blob]),
+            ):
+                trace.configure(enabled=True, slow_op_threshold_ms=0)
+                with contextlib.redirect_stdout(io.StringIO()):  # the verb's result line
+                    rc = cli.main(["--jax-platform", "cpu", *argv])
+                if rc != 0:
+                    raise RuntimeError(f"cmd.convert {verb} exited {rc}")
+                counts.setdefault(verb, []).append(len(trace.snapshot_spans()))
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+    trace.configure(enabled=True, ring_capacity=2048, slow_op_threshold_ms=0)
+    trace.install_profiler_bridge(_Annotation)
+    try:
+        with trace.batch_span("convert.pack"), trace.Stages() as stages:
+            t0 = perf_counter()
+            for _ in range(n):
+                stages.next("pack:stage", bytes=1)
+            dt = perf_counter() - t0
+    finally:
+        trace.install_profiler_bridge(None)
+    ns = dt / n * 1e9
+    return {
+        "spans_per_pack": counts["pack"],
+        "spans_per_merge": counts["merge"],
+        "count_follows_files": len(set(counts["pack"])) != 1 or len(set(counts["merge"])) != 1,
+        "ns_per_stage": round(ns),
+        "us_per_pack": round(max(counts["pack"]) * ns / 1e3, 1),
+        "us_per_merge": round(max(counts["merge"]) * ns / 1e3, 1),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -270,6 +345,7 @@ def profile(
         "disabled": disabled_cost(),
         "storm": storm_overhead(layers, pods, reps, mount_ms, ready_ms),
         "tree": demo_tree(),
+        "convert": convert_verbs(),
     }
     # Wall-noise-free upper bound on the enabled overhead: every span the
     # storm emits, priced at the measured per-span cost, against the best
@@ -298,6 +374,8 @@ def main() -> int:
                     help="max traced-vs-untraced storm overhead, percent")
     ap.add_argument("--max-disabled-ns", type=float, default=5000.0,
                     help="max per-call cost of span() with tracing disabled")
+    ap.add_argument("--max-verb-us", type=float, default=1000.0,
+                    help="max tracing cost of one convert verb, microseconds")
     ap.add_argument("--json", action="store_true", help="machine-readable output")
     args = ap.parse_args()
 
@@ -325,6 +403,10 @@ def main() -> int:
         print(f"throughput: {tp['spans_per_sec']} spans/s "
               f"({tp['ns_per_span']} ns/span), ring dropped {tp['ring_dropped']}")
         print(f"disabled: {report['disabled']['ns_per_call']} ns/call")
+        cv = report["convert"]
+        print(f"convert: {cv['spans_per_pack']} spans/pack {cv['spans_per_merge']} "
+              f"spans/merge (16 and 400 files), {cv['ns_per_stage']} ns/stage = "
+              f"{cv['us_per_pack']} us/pack, {cv['us_per_merge']} us/merge")
         tr = report["tree"]
         print(f"tree: {tr['spans']} spans single_tree={tr['single_tree']} "
               f"background_readahead={tr['background_readahead_attributed']} "
@@ -350,6 +432,15 @@ def main() -> int:
             f"{report['throughput']['ns_per_span']}ns)",
             file=sys.stderr,
         )
+        return 1
+    cv = report["convert"]
+    if cv["count_follows_files"] or max(cv["spans_per_pack"]) > 24 or max(cv["spans_per_merge"]) > 8:
+        print(f"FAIL: convert span budget (24 a pack, 8 a merge, whatever the "
+              f"file count): {cv}", file=sys.stderr)
+        return 1
+    if max(cv["us_per_pack"], cv["us_per_merge"]) > args.max_verb_us:
+        print(f"FAIL: tracing costs {cv['us_per_pack']} us a pack > "
+              f"{args.max_verb_us} us", file=sys.stderr)
         return 1
     if report["disabled"]["ns_per_call"] > args.max_disabled_ns:
         print(
