@@ -100,10 +100,27 @@ def _counters():
     )
 
 
-def _record_dispatch(n_bytes: int, stage_seconds: dict[str, float]) -> None:
+def _row_floor_counter():
+    """Digest classes the bucket plan lifted to ROW_FLOOR rows
+    (bucket_rows). Beside _counters(), whose four the benchmark and
+    chip_smoke.py unpack by position."""
+    from nydus_snapshotter_tpu.metrics import registry as _metrics
+
+    return _metrics.default_registry.register(
+        _metrics.Counter(
+            "ntpu_fused_convert_row_floor_classes_total",
+            "Digest classes of fused batches padded up to the row floor",
+        )
+    )
+
+
+def _record_dispatch(
+    n_bytes: int, stage_seconds: dict[str, float], row_floor_classes: int = 0
+) -> None:
     disp, by_bytes, busy, _ = _counters()
     disp.inc()
     by_bytes.inc(n_bytes)
+    _row_floor_counter().inc(row_floor_classes)
     for stage, seconds in stage_seconds.items():
         busy.labels(stage).inc(seconds)
 
@@ -116,6 +133,28 @@ def record_host_fallback() -> None:
 
 def _pow2_ceil(n: int) -> int:
     return 1 << (n - 1).bit_length() if n > 1 else 1
+
+
+# The fewest rows a digest class is dispatched with. A digest batch costs
+# its class's cap_blocks serial steps whatever its rows, and on the v5e a
+# step of the unrolled sha256 scan takes 60.5-61.3 us with ONE row (the
+# chip's compiler then fuses all 64 rounds into one kernel over [1,1]
+# arrays) and 4.6-4.8 us with 2, 4 or 8: in _pass2 a one-chunk class of
+# 65,536 blocks took 3.97 s as one row and 0.33 / 0.33 / 0.35 s padded
+# to 2 / 4 / 8 (PERF.md section 5; my chip runs, PR 27). So 2, the
+# smallest batch past the cliff: a padding row is gathered and digested
+# like any other, which is nearly free on the chip up to ~128 rows
+# (2.5-2.7 us a step from 16 to 128) but costs the CPU backend, whose
+# scan is bound by throughput, a whole row's time.
+ROW_FLOOR = 2
+
+
+def bucket_rows(live: int) -> int:
+    """Rows a digest class holding ``live`` chunks is dispatched with:
+    the next power of two, and never fewer than ROW_FLOOR. The one rule
+    for the row axis of every pass-2 batch (plan_buckets here, the
+    per-device rows of ops/mesh_pack.plan_mesh_pack)."""
+    return max(ROW_FLOOR, _pow2_ceil(live))
 
 
 # ---------------------------------------------------------------------------
@@ -207,10 +246,10 @@ def _wcap_for(n: int, density_bits: int, floor: int = 1024) -> int:
 class Bucket:
     """One power-of-two block-capacity class of the pass-2 plan.
 
-    offsets/sizes are pow2-padded (padding rows have size 0 and offset 0
-    and are discarded on assembly); ``count`` is the live prefix and
-    ``blocks`` the digest blocks its chunks really hold (of the
-    ``M * cap_blocks`` the class computes).
+    offsets/sizes are padded to ``bucket_rows(count)`` (padding rows have
+    size 0 and offset 0 and are discarded on assembly); ``count`` is the
+    live prefix and ``blocks`` the digest blocks its chunks really hold
+    (of the ``M * cap_blocks`` the class computes).
     """
 
     cap_blocks: int
@@ -364,13 +403,11 @@ class FusedDeviceEngine:
     def __init__(
         self,
         chunk_size: int = 0x100000,
-        max_bucket_rows: int = 1 << 14,
         digester: str = "sha256",
     ):
         if digester not in ("sha256", "blake3"):
             raise ValueError(f"unknown digester {digester!r}")
         self.params = cdc.CDCParams(chunk_size)
-        self.max_bucket_rows = max_bucket_rows
         self.digester = digester
 
     def _blocks_of(self, size: int) -> int:
@@ -483,7 +520,7 @@ class FusedDeviceEngine:
         buckets = []
         for cap in sorted(per_class):
             rows = per_class[cap]
-            m = _pow2_ceil(len(rows))
+            m = bucket_rows(len(rows))
             offs = np.zeros(m, dtype=np.int32)
             sizes = np.zeros(m, dtype=np.int32)
             offs[: len(rows)] = [r[0] for r in rows]
@@ -669,10 +706,15 @@ class FusedDeviceEngine:
             lane.annotate(chunks=sum(len(c) for c in cuts))
             lane.next("pack:lane.plan")
             buckets, order = self.plan_buckets(table, cuts)
+            # rows the floor added to each class, beyond its power of two
+            floored = [len(b.offsets) - _pow2_ceil(b.count) for b in buckets]
+            floored_classes = sum(1 for r in floored if r)
             lane.annotate(
                 classes=[[b.cap_blocks, b.count, len(b.offsets)] for b in buckets],
                 blocks_real=sum(b.blocks for b in buckets),
                 blocks_padded=sum(len(b.offsets) * b.cap_blocks for b in buckets),
+                row_floor_classes=floored_classes,
+                row_floor_rows=sum(floored),
             )
             # a first call of a new plan compiles here: programs_after tells
             lane.next("pack:lane.pass2", programs_before=_pass2._cache_size())
@@ -718,5 +760,6 @@ class FusedDeviceEngine:
                 "pass2_digest": took["pack:lane.pass2"],
                 "digest_d2h": took["pack:lane.digest_d2h"],
             },
+            row_floor_classes=floored_classes,
         )
         return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np, span_seconds=took)

@@ -41,11 +41,9 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from nydus_snapshotter_tpu.ops.fused_convert import bucket_rows
+
 BLOCK_BYTES = 64  # SHA-256 block: pass-2 read span = cap_blocks * 64
-
-
-def _pow2_ceil(n: int) -> int:
-    return 1 << (n - 1).bit_length() if n > 1 else 1
 
 
 @dataclass(frozen=True)
@@ -109,10 +107,10 @@ def plan_mesh_pack(
     ``n_devices`` byte-shard mesh.
 
     ``buckets``/``order`` come straight from ``plan_buckets`` (absolute
-    offsets, pow2-padded live prefixes). ``halo_bytes`` defaults to the
-    largest read span any bucket in the batch can issue; passing the
-    engine-level ``max_read_span`` keeps the plan shape independent of
-    which classes a particular corpus happened to produce.
+    offsets, live prefixes padded to ``bucket_rows``). ``halo_bytes``
+    defaults to the largest read span any bucket in the batch can issue;
+    passing the engine-level ``max_read_span`` keeps the plan shape
+    independent of which classes a particular corpus happened to produce.
     """
     if n_devices < 1:
         raise ValueError(f"n_devices must be >= 1, got {n_devices}")
@@ -138,7 +136,7 @@ def plan_mesh_pack(
             # would silently scramble shard_map's partition.
             raise ValueError("bucket rows are not offset-ordered")
         counts = np.bincount(dev, minlength=n_devices).astype(np.int64)
-        m_dev = _pow2_ceil(int(counts.max())) if live else 1
+        m_dev = bucket_rows(int(counts.max()))
         n_rows = n_devices * m_dev
         loc = np.zeros(n_rows, dtype=np.int32)
         abso = np.zeros(n_rows, dtype=np.int32)

@@ -91,19 +91,30 @@ def test_pass1_with_pallas_gear_at_512_mib(shape, monkeypatch):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
-@pytest.mark.parametrize("digester,top_classes", [("sha256", 3), ("blake3", 1)])
-def test_pass2_gather_digest(shape, monkeypatch, digester, top_classes):
-    """Few rows, real capacities: the top classes of a 64 KiB-chunk
-    layer's plan. The digest rounds' form is chosen at trace time from
-    JAX's default backend — the CPU here — so the test steers it to the
+@pytest.mark.parametrize(
+    "chunk_size,digester,caps_of,n_rows",
+    [
+        (CHUNK, "sha256", lambda top: (top >> 2, top >> 1, top), 8),
+        (CHUNK, "blake3", lambda top: (top,), 8),
+        # 65,536 blocks: the class of a 2-4 MiB chunk, with the fewest rows
+        # the plan gives a class. (The class of max-size chunks above it,
+        # 65,537 blocks, takes this sandbox two minutes, against six seconds.)
+        (16 * CHUNK, "sha256", lambda top: (top - 1,), fused_convert.ROW_FLOOR),
+    ],
+    ids=["64k-sha256", "64k-blake3", "1m-sha256-row-floor"],
+)
+def test_pass2_gather_digest(shape, monkeypatch, chunk_size, digester, caps_of, n_rows):
+    """Few rows, real capacities: the top classes of a layer's plan at
+    64 KiB chunks, and the longest power-of-two class at the CLI's
+    1 MiB. The digest rounds' form is chosen at trace time from JAX's
+    default backend — the CPU here — so the test steers it to the
     unrolled form the chip compiles. That form costs the compiler
     seconds per class and a real layer brings twelve (sha256) or nine
     (blake3), so only the top ones are held here."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    eng = fused_convert.FusedDeviceEngine(chunk_size=CHUNK, digester=digester)
-    top = eng._blocks_of(eng.params.max_size)
-    caps = tuple(sorted({top} | {top >> k for k in range(1, top_classes)}))
-    rows = tuple(shape((8,), jnp.int32) for _ in caps)
+    eng = fused_convert.FusedDeviceEngine(chunk_size=chunk_size, digester=digester)
+    caps = caps_of(eng._blocks_of(eng.params.max_size))
+    rows = tuple(shape((n_rows,), jnp.int32) for _ in caps)
     compiled = fused_convert._pass2.lower(
         shape((64 * MIB,), jnp.uint8), rows, rows, caps, digester=digester
     ).compile()

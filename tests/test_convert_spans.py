@@ -200,6 +200,33 @@ def test_stage_counters_are_the_spans_own_seconds(work, enabled):
     assert delta == pytest.approx(want, abs=1e-6)
 
 
+def test_plan_span_counts_the_row_floor_and_the_rows_dispatched(work, monkeypatch):
+    """`pack:lane.plan` says what pass 2 is given: `blocks_padded` is the
+    rows x capacity of the buckets handed to `digest_probe`, padding rows
+    of the floor included, and `row_floor_*` (and the counter) say how
+    many of them the floor added."""
+    dispatched = []
+    digest_probe = fused_convert.FusedDeviceEngine.digest_probe
+
+    def spy(self, buffer_dev, buckets, *args, **kw):
+        dispatched.append([(b.cap_blocks, b.count, len(b.offsets)) for b in buckets])
+        return digest_probe(self, buffer_dev, buckets, *args, **kw)
+
+    monkeypatch.setattr(fused_convert.FusedDeviceEngine, "digest_probe", spy)
+    floored = fused_convert._row_floor_counter()
+    before = floored.value()
+    trace.configure(enabled=True)
+    pack(work, "fused")
+    plan = {s.name: s.attrs for s in tree("convert.pack")[1]}["pack:lane.plan"]
+    (buckets,) = dispatched
+    assert [list(b) for b in buckets] == plan["classes"]
+    assert plan["blocks_padded"] == sum(cap * rows for cap, _live, rows in buckets)
+    added = [rows - fused_convert._pow2_ceil(live) for _cap, live, rows in buckets]
+    assert all(rows == fused_convert.bucket_rows(live) for _cap, live, rows in buckets)
+    assert plan["row_floor_rows"] == sum(added) > 0  # a.tar's plan has a one-row class
+    assert plan["row_floor_classes"] == sum(1 for a in added if a) == floored.value() - before
+
+
 @pytest.mark.parametrize("backend,with_dict", CASES)
 def test_stats_hold_the_spans_sums(work, backend, with_dict):
     from nydus_snapshotter_tpu.converter.stream import _STATS_SPANS

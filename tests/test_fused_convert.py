@@ -11,7 +11,7 @@ import hashlib
 import numpy as np
 import pytest
 
-from nydus_snapshotter_tpu.ops import fused_convert
+from nydus_snapshotter_tpu.ops import cdc, fused_convert, mesh_pack
 from nydus_snapshotter_tpu.ops.chunker import ChunkDigestEngine
 from nydus_snapshotter_tpu.parallel.sharded_dict import (
     _build_host_tables,
@@ -19,6 +19,8 @@ from nydus_snapshotter_tpu.parallel.sharded_dict import (
 )
 
 CHUNK = 0x10000  # 64 KiB average so small corpora produce many chunks
+SMALL = 0x1000  # 4 KiB average: the top class is 16 KiB, cheap on the CPU
+R = fused_convert.ROW_FLOOR
 
 
 def _corpus(seed: int, sizes: list[int]) -> list[bytes]:
@@ -119,6 +121,111 @@ class TestFusedDifferential:
         data = _corpus(23, [1 << 20])[0]
         with pytest.raises(fused_convert.FusedOverflow):
             eng.process_many([data])
+
+
+def _thin_top_batch(top_rows: int) -> list[bytes]:
+    """Streams, at 4 KiB chunks, whose plan's top class holds exactly
+    ``top_rows`` rows: a run of that many max-size blocks, each of one
+    byte value (the gear hash of a constant run meets neither mask, so
+    every cut inside it is the forced one), among files that CDC cuts and
+    small ones it leaves whole."""
+    max_size = cdc.CDCParams(SMALL).max_size
+    run = b"".join(
+        np.full(max_size, 1 + j, np.uint8).tobytes() for j in range(top_rows)
+    )
+    cut_a, cut_b, small = _corpus(71, [30_000, 20_000, 700])
+    return [cut_a, b"", run, small, cut_b, b"x"]
+
+
+class TestRowFloor:
+    """No digest class is dispatched with fewer than ROW_FLOOR rows
+    (fused_convert.bucket_rows): the padding rows change no cut, no
+    digest and no probe hit."""
+
+    @pytest.mark.parametrize("digester", ["sha256", "blake3"])
+    @pytest.mark.parametrize("top_rows", sorted({1, 2, 3, R + 1, 5}))
+    def test_thin_top_class_matches_oracle(self, digester, top_rows):
+        from nydus_snapshotter_tpu.utils import blake3 as pyb3
+
+        streams = _thin_top_batch(top_rows)
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL, digester=digester)
+        want = ChunkDigestEngine(
+            chunk_size=SMALL, backend="numpy", digest_backend="numpy"
+        ).process_many(streams)
+        if digester == "blake3":
+            ref, words = pyb3.blake3, "<u4"
+        else:
+            ref, words = (lambda b: hashlib.sha256(b).digest()), ">u4"
+        flat = [
+            ref(s[m.offset : m.offset + m.size])
+            for s, metas in zip(streams, want)
+            for m in metas
+        ]
+        # a dictionary of the batch's own chunks: every probe row has to
+        # name its own chunk, so a row base shifted by padding rows shows
+        keys, values = _build_host_tables(
+            np.frombuffer(b"".join(flat), dtype=words).astype(np.uint32).reshape(-1, 8), 1
+        )
+        res = eng.process_many(
+            streams, chunk_dict=(keys[0], values[0]), depth=_table_max_depth(keys, values)
+        )
+        for i, (cuts, metas) in enumerate(zip(res.cuts, want)):
+            np.testing.assert_array_equal(
+                cuts, [m.offset + m.size for m in metas], err_msg=f"stream {i}"
+            )
+        assert [d for digs in res.digests for d in digs] == flat
+        assert len(res.probe) == len(flat)
+        for d, hit in zip(flat, res.probe):
+            assert hit > 0 and flat[int(hit) - 1] == d
+
+        table, pos = [], 0
+        for s in streams:
+            table.append((pos, len(s)))
+            pos += len(s)
+        top = eng.plan_buckets(table, res.cuts)[0][-1]
+        assert top.cap_blocks == eng._blocks_of(eng.params.max_size)
+        assert top.count == top_rows
+        assert len(top.offsets) == max(R, fused_convert._pow2_ceil(top_rows))
+
+    @pytest.mark.parametrize("n_devices", [1, 2, 4])
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_no_class_under_the_floor_and_order_skips_padding(self, seed, n_devices):
+        """Host only: plan_buckets and plan_mesh_pack over drawn chunk
+        sizes, log-uniform so that the long classes are thin."""
+        rng = np.random.default_rng(300 + seed)
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL)
+        table, cuts, total = [], [], 0
+        for _ in range(int(rng.integers(1, 30))):
+            sizes = np.exp(
+                rng.uniform(0, np.log(eng.params.max_size), int(rng.integers(1, 6)))
+            ).astype(np.int64)
+            table.append((total, int(sizes.sum())))
+            cuts.append(np.cumsum(sizes))
+            total += int(sizes.sum())
+        # and one max-size chunk: the top class holds that row alone
+        table.append((total, eng.params.max_size))
+        cuts.append(np.asarray([eng.params.max_size], dtype=np.int64))
+        total += eng.params.max_size
+        buckets, order = eng.plan_buckets(table, cuts)
+        by_cap = {b.cap_blocks: b for b in buckets}
+        assert buckets[-1].count == 1 < R
+        for b in buckets:
+            assert len(b.offsets) == len(b.sizes) == fused_convert.bucket_rows(b.count) >= R
+            assert not b.sizes[b.count :].any() and not b.offsets[b.count :].any()
+        assert all(row < by_cap[cap].count for cap, row in order)
+        assert len(order) == sum(len(c) for c in cuts)
+
+        plan = mesh_pack.plan_mesh_pack(
+            buckets, order, total, n_devices, halo_bytes=eng.max_read_span()
+        )
+        sharded = {sb.cap_blocks: sb for sb in plan.buckets}
+        for sb in plan.buckets:
+            assert sb.rows_per_device == fused_convert.bucket_rows(max(sb.counts)) >= R
+            assert len(sb.sizes) == n_devices * sb.rows_per_device
+        for (cap, row), (_, old_row) in zip(plan.order, order):
+            d, i = divmod(row, sharded[cap].rows_per_device)
+            assert i < sharded[cap].counts[d]
+            assert sharded[cap].sizes[row] == by_cap[cap].sizes[old_row]
 
 
 class TestFusedPackLane:
