@@ -703,7 +703,13 @@ class FusedDeviceEngine:
             lane.annotate(candidates_s=len(cand_s), candidates_l=len(cand_l))
             lane.next("pack:lane.resolve", files=len(table))
             cuts = self.resolve(cand_s, cand_l, table)
-            lane.annotate(chunks=sum(len(c) for c in cuts))
+            # a file no longer than min_size is one chunk, no candidate judged
+            # (an empty one has no chunk)
+            min_size = self.params.min_size
+            lane.annotate(
+                chunks=sum(len(c) for c in cuts),
+                single_chunk_files=sum(1 for _off, length in table if 0 < length <= min_size),
+            )
             lane.next("pack:lane.plan")
             buckets, order = self.plan_buckets(table, cuts)
             # rows the floor added to each class, beyond its power of two

@@ -100,13 +100,18 @@ def test_pass1_with_pallas_gear_at_512_mib(shape, monkeypatch):
         # the plan gives a class. (The class of max-size chunks above it,
         # 65,537 blocks, takes this sandbox two minutes, against six seconds.)
         (16 * CHUNK, "sha256", lambda top: (top - 1,), fused_convert.ROW_FLOOR),
+        # the other regime: a layer of small files (benchmark/configs/
+        # smallfiles-64k.json, 13.8k files, 87% of one chunk each) plans its
+        # classes of 8-512 blocks at 2,048 rows each; the two largest here
+        (CHUNK, "sha256", lambda top: (256, 512), 2048),
     ],
-    ids=["64k-sha256", "64k-blake3", "1m-sha256-row-floor"],
+    ids=["64k-sha256", "64k-blake3", "1m-sha256-row-floor", "64k-sha256-smallfiles-2k-rows"],
 )
 def test_pass2_gather_digest(shape, monkeypatch, chunk_size, digester, caps_of, n_rows):
     """Few rows, real capacities: the top classes of a layer's plan at
-    64 KiB chunks, and the longest power-of-two class at the CLI's
-    1 MiB. The digest rounds' form is chosen at trace time from JAX's
+    64 KiB chunks, the longest power-of-two class at the CLI's 1 MiB, and
+    many rows at short capacities: the widest classes of a layer of small
+    files. The digest rounds' form is chosen at trace time from JAX's
     default backend — the CPU here — so the test steers it to the
     unrolled form the chip compiles. That form costs the compiler
     seconds per class and a real layer brings twelve (sha256) or nine

@@ -227,6 +227,18 @@ def test_plan_span_counts_the_row_floor_and_the_rows_dispatched(work, monkeypatc
     assert plan["row_floor_classes"] == sum(1 for a in added if a) == floored.value() - before
 
 
+@pytest.mark.parametrize("name,files,big", [("a", 20, 4), ("many", 2000, 4)])
+def test_resolve_span_counts_the_files_of_one_chunk(work, name, files, big):
+    """`pack:lane.resolve` tells the files of one chunk (no longer than the
+    chunker's min_size: no candidate judged) from the ones CDC cuts."""
+    trace.configure(enabled=True)
+    pack(work, "fused", name)
+    resolve = {s.name: s.attrs for s in tree("convert.pack")[1]}["pack:lane.resolve"]
+    assert resolve["files"] == files and resolve["single_chunk_files"] == files - big  # make_tar: 100-3,000 B
+    assert resolve["chunks"] >= resolve["single_chunk_files"] + big
+    assert len(fused_convert._counters()) == 4  # benchmark/program.py and chip_smoke.py unpack four
+
+
 @pytest.mark.parametrize("backend,with_dict", CASES)
 def test_stats_hold_the_spans_sums(work, backend, with_dict):
     from nydus_snapshotter_tpu.converter.stream import _STATS_SPANS
