@@ -380,7 +380,7 @@ def make_bytes_reader(
 
 def Pack(
     dest: BinaryIO,
-    src_tar: BinaryIO | bytes,
+    src_tar: "BinaryIO | bytes | np.ndarray",
     opt: PackOption,
     chunk_dict=None,
     stats: dict | None = None,

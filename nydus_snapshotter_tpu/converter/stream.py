@@ -700,7 +700,7 @@ def _fast_tar_members(raw: memoryview):
 
 def pack_stream(
     dest: BinaryIO,
-    src_tar: "BinaryIO | bytes",
+    src_tar: "BinaryIO | bytes | np.ndarray",
     opt: PackOption,
     chunk_dict=None,
     stats: "Optional[dict]" = None,
@@ -708,6 +708,11 @@ def pack_stream(
     codec=None,
 ):
     """Stream one OCI layer tar into a nydus blob written to ``dest``.
+
+    ``src_tar``: a file-like source, or the whole tar in memory as
+    ``bytes`` / ``bytearray`` / a 1-D uint8 array (for the fused backend
+    best the head of a ``fused_convert.zeroed_buffer`` of ``padded_length``
+    bytes, which the lane then uploads without a copy).
 
     Reference semantics (convert_unix.go:325-539): uncompressed layer tar
     in, tar-like nydus blob out; chunk-dict hits are referenced, not stored.
@@ -780,10 +785,15 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
     # whole-file views sliced straight out of the caller's buffer (the
     # bounded-memory streaming discipline below only matters for file-like
     # sources that may not fit in RAM).
+    # A uint8 array counts too (cmd_pack reads a layer into one with the
+    # fused lane's padding behind it, and the lane uploads that as it is).
     raw: Optional[memoryview] = None
-    if isinstance(src_tar, (bytes, bytearray)):
+    if isinstance(src_tar, (bytes, bytearray, np.ndarray)):
+        if isinstance(src_tar, np.ndarray) and not (
+            src_tar.dtype == np.uint8 and src_tar.ndim == 1 and src_tar.flags.c_contiguous
+        ):
+            raise ConvertError("an in-memory layer tar array must be contiguous 1-D uint8")
         raw = memoryview(src_tar)
-        src_tar = io.BytesIO(src_tar)
 
     if chunk_dict is None and opt.chunk_dict_path:
         # service://<uds>[#namespace] connects a shared-dict mirror; any
@@ -973,8 +983,11 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
         try:
             # Random access for in-memory layers (tarfile's stream mode
             # copies every data byte through its internal block buffers).
+            # io.BytesIO shares a bytes object and copies anything else,
+            # so it is built only here, where the fast walk gave up.
             tf = tarfile.open(
-                fileobj=src_tar, mode="r:" if raw is not None else "r|"
+                fileobj=io.BytesIO(src_tar) if raw is not None else src_tar,
+                mode="r:" if raw is not None else "r|",
             )
         except tarfile.TarError as e:
             raise ConvertError(f"bad layer tar: {e}") from e
@@ -997,7 +1010,11 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
     if plan:
         from nydus_snapshotter_tpu.ops import native_cdc
 
-        arr_all = np.frombuffer(raw, dtype=np.uint8)
+        # the caller's own array where it gave one: the fused lane looks
+        # for room behind it (fused_convert.lane_buffer)
+        arr_all = (
+            src_tar if isinstance(src_tar, np.ndarray) else np.frombuffer(raw, dtype=np.uint8)
+        )
         n_threads = _pack_threads()
         # Single-thread fast lane: ONE native call fuses chunk+digest for
         # EVERY planned file (small and large alike — a <= min_size file
@@ -1107,10 +1124,14 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
             feng = fused_convert.FusedDeviceEngine(
                 chunk_size=opt.chunk_size, digester=opt.digester
             )
-            streams = [arr_all[off : off + size] for _t, _m, off, size in plan]
             stages.close()  # the lane runs its own stages: pack:lane.*
             try:
-                fres = feng.process_many(streams)
+                # the tar is the lane's buffer, the plan's extents its table
+                fres = feng.process_many(
+                    fused_convert.Extents(
+                        arr_all, [(off, size) for _t, _m, off, size in plan]
+                    )
+                )
             except fused_convert.FusedOverflow:
                 fres = None  # pathological input: per-file paths below
                 fused_convert.record_host_fallback()

@@ -59,9 +59,10 @@ class FusedOverflow(RuntimeError):
 def _counters():
     """(dispatches, bytes, stage_seconds{stage}, host_fallbacks): the
     fused-convert counters next to the pipeline's
-    (ntpu_convert_pipeline_*). Stages: layout = host concat into the
-    padded buffer, h2d = upload, pass1_gear = gear+compaction dispatch
-    and candidate D2H, host_resolve = cut resolution + bucket plan (the
+    (ntpu_convert_pipeline_*). Stages: layout = the lane buffer made
+    ready (the extent table; a host copy only where the input is not
+    already one padded buffer), h2d = upload, pass1_gear =
+    gear+compaction dispatch and candidate D2H, host_resolve = cut resolution + bucket plan (the
     host arm between dispatches), pass2_digest = gather+digest+probe
     dispatch, digest_d2h = digest states (and probe) back to the host as
     per-chunk bytes. Fed from the ``pack:lane.*`` spans' own times
@@ -114,13 +115,31 @@ def _row_floor_counter():
     )
 
 
+def _layout_copied_counter():
+    """Bytes the layout stage copied on the host to build a lane buffer:
+    0 for a batch whose input already was one (lane_buffer). Its own
+    accessor for the same reason as _row_floor_counter()."""
+    from nydus_snapshotter_tpu.metrics import registry as _metrics
+
+    return _metrics.default_registry.register(
+        _metrics.Counter(
+            "ntpu_fused_convert_layout_copied_bytes_total",
+            "Bytes copied on the host into fused batches' padded buffers",
+        )
+    )
+
+
 def _record_dispatch(
-    n_bytes: int, stage_seconds: dict[str, float], row_floor_classes: int = 0
+    n_bytes: int,
+    stage_seconds: dict[str, float],
+    row_floor_classes: int = 0,
+    copied_bytes: int = 0,
 ) -> None:
     disp, by_bytes, busy, _ = _counters()
     disp.inc()
     by_bytes.inc(n_bytes)
     _row_floor_counter().inc(row_floor_classes)
+    _layout_copied_counter().inc(copied_bytes)
     for stage, seconds in stage_seconds.items():
         busy.labels(stage).inc(seconds)
 
@@ -155,6 +174,73 @@ def bucket_rows(live: int) -> int:
     for the row axis of every pass-2 batch (plan_buckets here, the
     per-device rows of ops/mesh_pack.plan_mesh_pack)."""
     return max(ROW_FLOOR, _pow2_ceil(live))
+
+
+def padded_length(total: int, max_size: int) -> int:
+    """Bytes of the lane's device buffer for ``total`` bytes of input cut
+    into chunks of at most ``max_size``: the one padding rule of the
+    lane (layout, lane_buffer, and a caller that reads a layer straight
+    into a buffer of this size)."""
+    # a window multiple + one max-chunk guard so pass-2 dynamic_slice
+    # never clamps a start (clamping would shift the slice and corrupt
+    # in-range bytes)
+    guard = max_size + 64
+    npad = -(-max(1, total + guard) // WINDOW) * WINDOW
+    # quantize to 1/8-pow2 steps: bounded compile count without the
+    # full pow2 doubling (which would push a 1.1 GiB batch to 2 GiB)
+    step = max(WINDOW, _pow2_ceil(npad) // 8)
+    npad = -(-npad // step) * step
+    # Device ints are 32-bit (no x64): pass-2 chunk offsets must
+    # address the buffer with int32. Callers split larger corpora
+    # into sub-2-GiB batches (bench packs per layer, far below this).
+    if npad >= 1 << 31:
+        raise FusedOverflow(
+            f"batch of {total} bytes pads to {npad} — beyond int32 "
+            "device addressing; split the batch"
+        )
+    return npad
+
+
+def zeroed_buffer(npad: int) -> np.ndarray:
+    """u8[npad] of zeros on a page boundary, no page of it touched yet.
+
+    The boundary is for whoever fills it through the kernel: a 523 MiB
+    file read into a destination 16 bytes past a page boundary (where
+    glibc's chunk header leaves np.zeros) took 0.61-0.68 s on the v5e
+    machine's host, into an aligned one 0.43-0.48 s, which is what
+    f.read() takes (PERF.md section 6; my chip runs, PR 30)."""
+    page = 4096
+    big = np.zeros(npad + page, dtype=np.uint8)
+    start = -big.ctypes.data % page
+    return big[start : start + npad]
+
+
+def lane_buffer(data: np.ndarray, npad: int) -> tuple[np.ndarray, int]:
+    """-> (u8[npad] that starts with ``data``, bytes copied to make it).
+
+    Where the array behind ``data`` has room for ``npad`` bytes from
+    data's first on (a layer read into the head of
+    zeroed_buffer(padded_length(...))), that stretch is the buffer as it
+    stands: nothing is copied and no page is touched twice. Else one bulk
+    copy into a fresh zeroed buffer. What follows ``data`` is never
+    judged (pass 1 drops candidate words past the valid length, pass 2
+    masks every gather by its chunk's size), so the two give the same
+    cuts and digests; zeros keep the upload a function of the input.
+    """
+    owner = data.base
+    if (
+        isinstance(owner, np.ndarray)
+        and owner.dtype == np.uint8
+        and owner.ndim == 1
+        and owner.flags.c_contiguous
+        and data.flags.c_contiguous
+    ):
+        start = data.ctypes.data - owner.ctypes.data
+        if 0 <= start and start + npad <= owner.size:
+            return owner[start : start + npad], 0
+    buf = zeroed_buffer(npad)
+    buf[: data.size] = data
+    return buf, data.size
 
 
 # ---------------------------------------------------------------------------
@@ -381,6 +467,20 @@ def _pass2(
 
 
 @dataclass(frozen=True)
+class Extents:
+    """A batch whose streams already lie in ONE buffer: ``table`` holds
+    their (offset, length) in ``data``, ascending and disjoint (an
+    in-memory layer tar and its members' data). process_many then takes
+    the buffer as the lane's and the table as it is, and builds no second
+    buffer: what lies between two files (a tar header, padding) is hashed
+    by pass 1 like any byte and its candidates fall to no file in
+    resolve(), whose seam argument does not ask what precedes a file."""
+
+    data: "bytes | bytearray | np.ndarray"  # 1-D uint8 where an array
+    table: list[tuple[int, int]]
+
+
+@dataclass(frozen=True)
 class FusedResult:
     """Per-stream chunk extents/digests + optional dict-probe hits."""
 
@@ -421,7 +521,7 @@ class FusedDeviceEngine:
 
     def max_read_span(self) -> int:
         """Largest pass-2 gather span any bucket can issue, in bytes —
-        the guard this engine's layout() pads for, and the shard halo
+        the guard padded_length() leaves for, and the shard halo
         ops/mesh_pack must append to every per-device slab so a chunk
         cut at a shard boundary still gathers without clamping."""
         if self.digester == "blake3":
@@ -439,23 +539,7 @@ class FusedDeviceEngine:
         for a in arrs:
             table.append((total, a.size))
             total += a.size
-        # pad to a window multiple + one max-chunk guard so pass-2
-        # dynamic_slice never clamps a start (clamping would shift the
-        # slice and corrupt in-range bytes)
-        guard = self.params.max_size + 64
-        npad = -(-max(1, total + guard) // WINDOW) * WINDOW
-        # quantize to 1/8-pow2 steps: bounded compile count without the
-        # full pow2 doubling (which would push a 1.1 GiB batch to 2 GiB)
-        step = max(WINDOW, _pow2_ceil(npad) // 8)
-        npad = -(-npad // step) * step
-        # Device ints are 32-bit (no x64): pass-2 chunk offsets must
-        # address the buffer with int32. Callers split larger corpora
-        # into sub-2-GiB batches (bench packs per layer, far below this).
-        if npad >= 1 << 31:
-            raise FusedOverflow(
-                f"batch of {total} bytes pads to {npad} — beyond int32 "
-                "device addressing; split the batch"
-            )
+        npad = padded_length(total, self.params.max_size)
         buf = np.zeros(npad, dtype=np.uint8)
         pos = 0
         for a in arrs:
@@ -654,14 +738,43 @@ class FusedDeviceEngine:
             return blake3_jax.digest_to_bytes(state_row)
         return sha256.digest_to_bytes(state_row)
 
+    def _lay(self, streams: "list[bytes | np.ndarray] | Extents"):
+        """The layout stage -> (u8[padded_length] lane buffer, or None for
+        a batch without a byte; its [(offset, length)] table; the valid
+        bytes; the bytes copied to build it)."""
+        if isinstance(streams, Extents):
+            data = streams.data
+            arr = np.frombuffer(data, dtype=np.uint8) if isinstance(data, (bytes, bytearray)) else data
+            table = [(int(off), int(length)) for off, length in streams.table]
+            # the device clamps a gather that leaves the buffer: refuse here
+            if any(off < 0 or length < 0 or off + length > arr.size for off, length in table):
+                raise ValueError(f"an extent lies outside its {arr.size}-byte buffer")
+            if not any(length for _off, length in table):
+                return None, table, 0, 0
+            buf, copied = lane_buffer(arr, padded_length(arr.size, self.params.max_size))
+            return buf, table, arr.size, copied
+        arrs = [
+            np.frombuffer(s, dtype=np.uint8) if isinstance(s, (bytes, bytearray)) else s
+            for s in streams
+        ]
+        n = sum(a.size for a in arrs)
+        if n == 0:
+            return None, [(0, 0)] * len(arrs), 0, 0
+        buf, table = self.layout(arrs)
+        return buf, table, n, n
+
     def process_many(
         self,
-        streams: list[bytes | np.ndarray],
+        streams: "list[bytes | np.ndarray] | Extents",
         chunk_dict: tuple[np.ndarray, np.ndarray] | None = None,
         depth: int = 8,
         probe_kernel: str = "auto",
         dict_epoch: int | None = None,
     ) -> FusedResult:
+        """``streams``: separate byte strings, which layout() copies back to
+        back into a fresh buffer, or an Extents: streams that already lie
+        in one buffer, which is then the lane's own (lane_buffer). Same
+        lane from the upload on, same result for the same streams."""
         from nydus_snapshotter_tpu import failpoint, trace
 
         # Device batch boundary: chaos-testable (an injected error
@@ -675,19 +788,14 @@ class FusedDeviceEngine:
         # and the caller's stats are fed from the spans' own seconds.
         with trace.Stages() as lane:
             lane.next("pack:lane.layout")
-            arrs = [
-                np.frombuffer(s, dtype=np.uint8) if isinstance(s, (bytes, bytearray)) else s
-                for s in streams
-            ]
-            n = sum(a.size for a in arrs)
-            if n == 0:
+            buf, table, n, copied = self._lay(streams)
+            if buf is None:
                 return FusedResult(
-                    cuts=[np.asarray([], dtype=np.int64) for _ in arrs],
-                    digests=[[] for _ in arrs],
+                    cuts=[np.asarray([], dtype=np.int64) for _ in table],
+                    digests=[[] for _ in table],
                     probe=np.zeros(0, np.int32) if chunk_dict is not None else None,
                 )
-            buf, table = self.layout(arrs)
-            lane.annotate(bytes=n, padded_bytes=int(buf.size))
+            lane.annotate(bytes=n, padded_bytes=int(buf.size), copied_bytes=copied)
             # committed to the default device; blocked on so the upload is
             # its own stage (pass 1 needs the whole buffer before it starts)
             lane.next("pack:lane.h2d", bytes=int(buf.size))
@@ -767,5 +875,6 @@ class FusedDeviceEngine:
                 "digest_d2h": took["pack:lane.digest_d2h"],
             },
             row_floor_classes=floored_classes,
+            copied_bytes=copied,
         )
         return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np, span_seconds=took)

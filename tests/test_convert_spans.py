@@ -125,6 +125,10 @@ def test_pack_leaf_names_are_the_tables(work, backend, with_dict):
     if with_dict:
         assert attrs["pack:dict_load"]["dict_chunks"] > 0 and attrs["pack:dict_load"]["dict_blobs"] == 1
     if backend == "fused":
+        # the CLI read the tar into the lane's own buffer: nothing left to copy
+        lay = attrs["pack:lane.layout"]
+        assert lay["bytes"] == attrs["pack:read"]["bytes"] and lay["copied_bytes"] == 0
+        assert lay["padded_bytes"] == attrs["pack:lane.h2d"]["bytes"] >= lay["bytes"] + 4 * CHUNK + 64
         plan = attrs["pack:lane.plan"]
         assert plan["blocks_padded"] == sum(cap * padded for cap, _rows, padded in plan["classes"])
         assert 0 < plan["blocks_real"] <= plan["blocks_padded"]
@@ -237,6 +241,64 @@ def test_resolve_span_counts_the_files_of_one_chunk(work, name, files, big):
     assert resolve["files"] == files and resolve["single_chunk_files"] == files - big  # make_tar: 100-3,000 B
     assert resolve["chunks"] >= resolve["single_chunk_files"] + big
     assert len(fused_convert._counters()) == 4  # benchmark/program.py and chip_smoke.py unpack four
+
+
+def long_name_tar() -> bytes:
+    """a.tar's shape with a GNU long name in it: the fast member walk gives
+    up on the 'L' header and tarfile walks the layer."""
+    rng = np.random.default_rng(5)
+    buf = io.BytesIO()
+    with tarfile.open(fileobj=buf, mode="w", format=tarfile.GNU_FORMAT) as tf:
+        for i, size in enumerate([300_000, 2_000, 450_000]):
+            info = tarfile.TarInfo(f"d/{'n' * 120 if i == 1 else 'f'}{i}")
+            info.size = size
+            tf.addfile(info, io.BytesIO(rng.integers(0, 256, size, dtype=np.uint8).tobytes()))
+    return buf.getvalue()
+
+
+@pytest.mark.parametrize("with_dict", [False, True])
+@pytest.mark.parametrize("source", ["cli", "bytes", "bytearray", "array", "array-with-room", "tarfile-walk"])
+def test_layout_copies_only_a_tar_with_no_room_behind_it(work, source, with_dict):
+    """`pack:lane.layout` says what the lane had to copy to get its padded
+    buffer: nothing where the tar came as the head of an array with the
+    room (the served CLI; tarfile's walk or the fast one), the whole tar
+    where it came as bytes. Same blob as the host lane either way."""
+    tar = long_name_tar() if source == "tarfile-walk" else (work / "a.tar").read_bytes()
+    extra = {"chunk_dict_path": dict_boot(work)} if with_dict else {}
+    want = io.BytesIO()
+    Pack(want, tar, PackOption(backend="hybrid", chunk_size=CHUNK, **extra))
+    copied = fused_convert._layout_copied_counter()
+    before = copied.value()
+    trace.configure(enabled=True)
+    if source == "cli":
+        with open(pack(work, "fused", with_dict=with_dict), "rb") as f:
+            got = f.read()
+    else:
+        if source in ("array-with-room", "tarfile-walk"):
+            big = fused_convert.zeroed_buffer(fused_convert.padded_length(len(tar), 4 * CHUNK))
+            big[: len(tar)] = np.frombuffer(tar, dtype=np.uint8)
+            src = big[: len(tar)]
+        else:
+            src = {"bytes": tar, "bytearray": bytearray(tar), "array": np.frombuffer(tar, dtype=np.uint8)}[source]
+        out = io.BytesIO()
+        Pack(out, src, PackOption(backend="fused", chunk_size=CHUNK, **extra))
+        got = out.getvalue()
+    assert got == want.getvalue()
+    lay = {s.name: s.attrs for s in tree("convert.pack")[1]}["pack:lane.layout"]
+    roomy = source in ("cli", "array-with-room", "tarfile-walk")
+    assert lay["copied_bytes"] == (0 if roomy else len(tar)) == copied.value() - before
+    assert lay["bytes"] == len(tar)
+    assert lay["padded_bytes"] == fused_convert.padded_length(len(tar), 4 * CHUNK)
+
+
+@pytest.mark.parametrize("reshape", [lambda t: t.view(np.uint16), lambda t: t.reshape(2, -1), lambda t: t[::2]],
+                         ids=["uint16", "2-D", "strided"])
+def test_a_tar_array_of_another_shape_is_refused(reshape):
+    from nydus_snapshotter_tpu.converter.types import ConvertError
+
+    bad = reshape(np.frombuffer(long_name_tar(), dtype=np.uint8))
+    with pytest.raises(ConvertError, match="contiguous 1-D uint8"):
+        Pack(io.BytesIO(), bad, PackOption(backend="hybrid", chunk_size=CHUNK))
 
 
 @pytest.mark.parametrize("backend,with_dict", CASES)

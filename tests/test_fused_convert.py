@@ -7,6 +7,8 @@ hardware-only: tests/test_chip_compile.py compiles it for the chip and
 chip_smoke.py runs it there)."""
 
 import hashlib
+import io
+import tarfile
 
 import numpy as np
 import pytest
@@ -226,6 +228,147 @@ class TestRowFloor:
             d, i = divmod(row, sharded[cap].rows_per_device)
             assert i < sharded[cap].counts[d]
             assert sharded[cap].sizes[row] == by_cap[cap].sizes[old_row]
+
+
+def _edge_tar() -> tuple[bytes, list[tuple[int, int]]]:
+    """-> (a layer tar cut off after its last data block, the (data
+    offset, size) of every regular member in tar order), at 4 KiB chunks:
+    an empty file, one of min_size bytes (one chunk, no candidate
+    judged), one chunk of exactly max_size, files that CDC cuts, two of whole
+    512-byte blocks with nothing but the second's header between them,
+    and a last one whose data ends with the buffer."""
+    p = cdc.CDCParams(SMALL)
+    cut_a, cut_b, seam_a, seam_b, last = _corpus(91, [30_001, 70_003, 40 * 512, 59 * 512, 24 * 512])
+    run = bytes([9]) * p.max_size  # one forced cut, at max_size (_thin_top_batch)
+    files = [b"", cut_a, b"m" * p.min_size, run, b"x", cut_b, seam_a, seam_b, last]
+    buf = io.BytesIO()
+    with tarfile.open(fileobj=buf, mode="w", format=tarfile.GNU_FORMAT) as tf:
+        for i, data in enumerate(files):
+            info = tarfile.TarInfo(f"layer/f{i}")
+            info.size = len(data)
+            tf.addfile(info, io.BytesIO(data))
+    tar = buf.getvalue()
+    with tarfile.open(fileobj=io.BytesIO(tar)) as tf:
+        extents = [(m.offset_data, m.size) for m in tf]
+    assert [tar[off : off + size] for off, size in extents] == files
+    assert extents[7][0] == extents[6][0] + extents[6][1] + 512
+    end = extents[-1][0] + extents[-1][1]
+    assert end % 512 == 0  # no padding after the last file's data
+    return tar[:end], extents
+
+
+class TestExtentsEntry:
+    """process_many(Extents(tar, extents)) is process_many(the members' slices):
+    the tar is the lane's buffer and the plan's extents are its table,
+    whether lane_buffer finds room behind the tar (no copy) or not (one
+    bulk copy), and whatever the room holds."""
+
+    def test_the_seam_between_two_files_would_show(self):
+        """The data is worth its name: were two files with a header
+        between them resolved as one extent, the second's cuts would move."""
+        tar, extents = _edge_tar()
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL)
+        (a_off, a_len), (b_off, b_len) = extents[6], extents[7]
+        apart = eng.process_many(fused_convert.Extents(tar, [extents[6], extents[7]])).cuts
+        merged = eng.process_many(
+            fused_convert.Extents(tar, [(a_off, b_off + b_len - a_off)])
+        ).cuts[0]
+        leaked = merged[merged > b_off - a_off] - (b_off - a_off)
+        assert list(apart[1]) != list(leaked)
+
+    @pytest.mark.parametrize("room", ["slack", "slack-dirty", "bytes"])
+    @pytest.mark.parametrize("with_dict", [False, True])
+    @pytest.mark.parametrize("digester", ["sha256", "blake3"])
+    def test_extents_match_process_many(self, digester, with_dict, room):
+        tar, extents = _edge_tar()
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL, digester=digester)
+        streams = [tar[off : off + size] for off, size in extents]
+        chunk_dict, depth = None, 8
+        if with_dict:
+            # a dictionary of every other chunk of the batch: hits and misses
+            flat = [d for digs in eng.process_many(streams).digests for d in digs][::2]
+            words = "<u4" if digester == "blake3" else ">u4"
+            keys, values = _build_host_tables(
+                np.frombuffer(b"".join(flat), dtype=words).astype(np.uint32).reshape(-1, 8), 1
+            )
+            chunk_dict, depth = (keys[0], values[0]), _table_max_depth(keys, values)
+        want = eng.process_many(streams, chunk_dict=chunk_dict, depth=depth)
+
+        npad = fused_convert.padded_length(len(tar), eng.params.max_size)
+        if room == "bytes":
+            data, copied_want = tar, len(tar)
+        else:
+            big = fused_convert.zeroed_buffer(npad)
+            if room == "slack-dirty":  # what follows the tar is never judged
+                big[:] = np.random.default_rng(93).integers(0, 256, npad, dtype=np.uint8)
+            big[: len(tar)] = np.frombuffer(tar, dtype=np.uint8)
+            data, copied_want = big[: len(tar)], 0
+        copied = fused_convert._layout_copied_counter()
+        before = copied.value()
+        got = eng.process_many(
+            fused_convert.Extents(data, extents), chunk_dict=chunk_dict, depth=depth
+        )
+        assert copied.value() - before == copied_want
+
+        assert len(got.cuts) == len(extents)
+        for i, (g, w) in enumerate(zip(got.cuts, want.cuts)):
+            np.testing.assert_array_equal(g, w, err_msg=f"file {i}")
+        assert got.digests == want.digests
+        assert [len(c) for c in got.cuts][:5] == [0, len(want.cuts[1]), 1, 1, 1]
+        assert sum(len(c) for c in got.cuts) > len(extents) + 20  # CDC really cut
+        if with_dict:
+            np.testing.assert_array_equal(got.probe, want.probe)
+            assert (got.probe > 0).any() and (got.probe == 0).any()
+        else:
+            assert got.probe is None and want.probe is None
+
+    @pytest.mark.parametrize("extent", [(-1, 4), (0, -4), (9_000, 2_000)])
+    def test_an_extent_outside_the_buffer_is_refused(self, extent):
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL)
+        with pytest.raises(ValueError, match="outside its 10000-byte buffer"):
+            eng.process_many(fused_convert.Extents(bytes(10_000), [(0, 100), extent]))
+
+    def test_lane_buffer_takes_the_room_behind_the_data_or_copies_once(self):
+        W = fused_convert.WINDOW
+        big = fused_convert.zeroed_buffer(3 * W)
+        assert big.ctypes.data % 4096 == 0 and big.size == 3 * W and not big.any()
+        big[:1008] = 7
+        for data in (big[:1000], big[8:1008]):  # room from its first byte on
+            buf, copied = fused_convert.lane_buffer(data, 2 * W)
+            assert copied == 0 and buf.size == 2 * W and buf.ctypes.data == data.ctypes.data
+        for data in (
+            big[W + 5000 : W + 6000],  # too little room behind it
+            big[:2000:2],  # not the bytes as they lie
+            big[:1000].copy(),  # owns its bytes, nothing behind them
+            np.frombuffer(bytes(1000), dtype=np.uint8),
+        ):
+            buf, copied = fused_convert.lane_buffer(data, 2 * W)
+            assert copied == data.size and buf.size == 2 * W and not np.shares_memory(buf, data)
+            assert bytes(buf[: data.size]) == bytes(data) and not buf[data.size :].any()
+            assert buf.ctypes.data % 4096 == 0
+
+    @pytest.mark.parametrize("total", [0, 1, 100_000, (1 << 22) - 16_448, (1 << 22) - 16_447, 600 << 20])
+    def test_padded_length_is_layouts_rule(self, total):
+        max_size = cdc.CDCParams(SMALL).max_size
+        npad = fused_convert.padded_length(total, max_size)
+        assert npad % fused_convert.WINDOW == 0 and npad >= total + max_size + 64
+        assert npad < 2 * (total + max_size + 64) + fused_convert.WINDOW
+        if total <= 1 << 22:
+            eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL)
+            assert eng.layout([np.zeros(total, np.uint8)])[0].size == npad
+
+    @pytest.mark.parametrize("chunk_size", [SMALL, 0x100000])
+    def test_padded_length_refuses_what_int32_cannot_address(self, chunk_size):
+        max_size = cdc.CDCParams(chunk_size).max_size
+        step = 1 << 28  # the 1/8-power-of-two step under 2 GiB
+        last_ok = (1 << 31) - step - max_size - 64
+        assert fused_convert.padded_length(last_ok, max_size) == (1 << 31) - step
+        with pytest.raises(fused_convert.FusedOverflow):
+            fused_convert.padded_length(last_ok + 1, max_size)
+        with pytest.raises(fused_convert.FusedOverflow):
+            fused_convert.FusedDeviceEngine(chunk_size=chunk_size).process_many(
+                fused_convert.Extents(np.zeros(last_ok + 1, dtype=np.uint8), [(0, 1)])
+            )
 
 
 class TestFusedPackLane:
