@@ -51,39 +51,10 @@ def _read_prefetch(args) -> str:
     return ""
 
 
-def _read_layer(f, opt):
-    """The whole layer tar of ``f``. For the fused lane it is read into the
-    head of a zeroed buffer of the lane's padded length, so that the lane
-    uploads the buffer as it stands (fused_convert.lane_buffer; the
-    untouched tail costs no page), and a view of the tar's own bytes is
-    returned. Any other pack gets the plain ``bytes``."""
-    size = os.fstat(f.fileno()).st_size  # 0 for a pipe
-    if not (size and opt.backend == "fused" and opt.chunking == "cdc" and not opt.oci_ref):
-        return f.read()
-    from nydus_snapshotter_tpu.ops import cdc, fused_convert
-
-    opt.validate()  # the chunk size, before the padding rule takes it
-    try:
-        npad = fused_convert.padded_length(size, cdc.CDCParams(opt.chunk_size).max_size)
-    except fused_convert.FusedOverflow:
-        return f.read()  # no lane buffer holds it: the lane will say so
-    buf = fused_convert.zeroed_buffer(npad)
-    view = memoryview(buf)[:size]
-    got = 0
-    while got < size:
-        k = f.readinto(view[got:])
-        if not k:
-            break
-        got += k
-    rest = f.read()
-    if got == size and not rest:
-        return buf[:size]
-    return bytes(view[:got]) + rest  # the file changed under the read
-
-
 def cmd_pack(args) -> int:
     from nydus_snapshotter_tpu import trace
     from nydus_snapshotter_tpu.converter.convert import Pack
+    from nydus_snapshotter_tpu.converter.stream import read_layer
     from nydus_snapshotter_tpu.converter.zran import pack_gzip_layer
 
     opt = _pack_option(args)
@@ -92,7 +63,7 @@ def cmd_pack(args) -> int:
     # Spans leave through the trace ring, never through the result line.
     with trace.batch_span("convert.pack"):
         with trace.span("pack:read") as sp, open(args.input, "rb") as f:
-            src = _read_layer(f, opt)
+            src = read_layer(f, opt)
             sp.annotate(bytes=len(src))
         if args.oci_ref:
             from nydus_snapshotter_tpu.converter.convert import frame_bootstrap_only

@@ -57,7 +57,7 @@ class DaemonConfig:
     # TPU sidecar (conversion data plane) settings
     accel_enable: bool = True
     accel_chunk_size: int = constants.CHUNK_SIZE_DEFAULT
-    accel_backend: str = "hybrid"  # calibrated crossover, like PackOption
+    accel_backend: str = "hybrid"  # the host lane, like PackOption's default
 
 
 @dataclass

@@ -18,22 +18,33 @@ metadata (inodes + chunk records) accumulates, O(files + chunks).
 
 ``converter.convert.Pack`` delegates here — this is the only Pack
 implementation, so in-memory and streaming callers share one code path.
+
+One pack is open → scan → lane → assemble → emit (``_pack_stream``). The
+scan plans the in-memory members' extents, ``choose_lane`` picks what cuts
+and digests them (a lane: a generator of one ``(cuts, digests)`` a planned
+file), one loop slices the files by those cuts into the ordered dedup
+(``_Assembler``), and ``emit_bootstrap`` builds the tables and the TOC.
 """
 
 from __future__ import annotations
 
 import hashlib
+import io
+import itertools
 import math
 import os
 import stat
 import tarfile
+from contextlib import closing, nullcontext
 from dataclasses import dataclass, field
 from typing import BinaryIO, Optional
 
 import numpy as np
 
 from nydus_snapshotter_tpu import constants, trace
-from nydus_snapshotter_tpu.converter import crypto
+from nydus_snapshotter_tpu.converter import codec as codec_mod, crypto
+from nydus_snapshotter_tpu.converter.convert import PackResult, ThreadSafeCompressor
+from nydus_snapshotter_tpu.converter.convert import _make_compressor, match_prefetch_paths
 from nydus_snapshotter_tpu.converter.types import ConvertError, PackOption
 from nydus_snapshotter_tpu.models import fstree, layout, nydus_tar, toc
 from nydus_snapshotter_tpu.models.bootstrap import (
@@ -47,7 +58,8 @@ from nydus_snapshotter_tpu.models.bootstrap import (
     Inode,
     parse_chunk_dict_arg,
 )
-from nydus_snapshotter_tpu.ops import cdc
+from nydus_snapshotter_tpu.ops import cdc, native_cdc, sha256
+from nydus_snapshotter_tpu.ops.chunker import ChunkDigestEngine, _pow2_ceil, host_digests_for
 
 SEGMENT_BYTES = 4 << 20  # tar read granularity
 DIGEST_BATCH_BYTES = 32 << 20  # chunk bytes per digest batch
@@ -80,8 +92,6 @@ class IncrementalChunker:
     """
 
     def __init__(self, opt: PackOption, engine=None):
-        from nydus_snapshotter_tpu.ops.chunker import ChunkDigestEngine
-
         # One backend-selection policy: boundaries go through the engine
         # (jax = device two-phase candidates, hybrid = native, numpy = host).
         # Callers packing many files pass one shared engine instance.
@@ -93,9 +103,8 @@ class IncrementalChunker:
             digester=opt.digester,
             **kwargs,
         )
-        self.lookahead = (
-            self._engine.params.max_size if self._engine.params else opt.chunk_size
-        )
+        self.params = self._engine.params  # None for fixed chunking
+        self.lookahead = self.params.max_size if self.params else opt.chunk_size
         # Fused single-pass chunk+digest (native SIMD bitmaps + SHA-NI):
         # when the engine's fused arm is available, each drain yields
         # (chunk, digest) pairs directly — no separate digest sweep, no
@@ -104,9 +113,6 @@ class IncrementalChunker:
         self.fused = self._engine._fused_available()
         self._buf = bytearray()
 
-    def _boundaries(self, data: "bytes | bytearray | np.ndarray") -> np.ndarray:
-        return self._engine.boundaries(data)
-
     def feed(self, seg: bytes) -> list[tuple[bytes, Optional[bytes]]]:
         self._buf += seg
         if len(self._buf) < 2 * self.lookahead:
@@ -114,9 +120,7 @@ class IncrementalChunker:
         return self._drain(final=False)
 
     def finish(self) -> list[tuple[bytes, Optional[bytes]]]:
-        out = self._drain(final=True)
-        self._buf = bytearray()
-        return out
+        return self._drain(final=True)
 
     def _drain(self, final: bool) -> list[tuple[bytes, Optional[bytes]]]:
         buf = self._buf
@@ -126,13 +130,11 @@ class IncrementalChunker:
         # frombuffer view — no copy; boundaries (and fused digests) are
         # computed before any mutation of the buffer.
         if self.fused:
-            from nydus_snapshotter_tpu.ops import native_cdc
-
             cuts, digests = native_cdc.chunk_digest_native(
-                buf, self._engine.params, digester=self._engine.digester
+                buf, self.params, digester=self._engine.digester
             )
         else:
-            cuts, digests = self._boundaries(buf), None
+            cuts, digests = self._engine.boundaries(buf), None
         out: list[tuple[bytes, Optional[bytes]]] = []
         s = 0
         for i, c in enumerate(cuts):
@@ -149,40 +151,26 @@ class IncrementalChunker:
         self._buf = bytearray(buf[s:]) if not final else bytearray()
         return out
 
-    def chunk_whole(
-        self, view: memoryview
-    ) -> list[tuple[memoryview, Optional[bytes]]]:
-        """Single-pass chunk(+digest) of a complete in-memory file.
+    def cut_whole(self, arr: np.ndarray) -> "tuple[list[int], Optional[list[bytes]]]":
+        """Cuts (exclusive ends) of a complete in-memory file, with its
+        chunks' digests where the engine's fused arm gives them.
 
-        The in-memory fast path: no bytearray accumulation, no per-chunk
-        bytes() materialization — chunks are zero-copy views into the
-        caller's tar buffer (the reference avoids these copies by piping
-        the raw stream straight into the builder process,
+        The in-memory fast path: no bytearray accumulation, no byte of the
+        caller's tar buffer copied (the reference avoids these copies by
+        piping the raw stream straight into the builder process,
         pkg/converter/convert_unix.go:443-539).
         """
-        if len(view) == 0:
-            return []
-        arr = np.frombuffer(view, dtype=np.uint8)
-        if self.fused:
-            from nydus_snapshotter_tpu.ops import native_cdc
+        if not self.fused:
+            return self._engine.boundaries(arr).tolist(), None
+        cuts, flat = native_cdc.chunk_digest_native(
+            arr, self.params, digester=self._engine.digester
+        )
+        return cuts.tolist(), _split_digests(flat, 0, len(cuts))
 
-            cuts, digests = native_cdc.chunk_digest_native(
-                arr, self._engine.params, digester=self._engine.digester
-            )
-        else:
-            cuts, digests = self._boundaries(arr), None
-        out: list[tuple[memoryview, Optional[bytes]]] = []
-        s = 0
-        for i, c in enumerate(cuts):
-            c = int(c)
-            out.append(
-                (
-                    view[s:c],
-                    digests[32 * i : 32 * (i + 1)] if digests is not None else None,
-                )
-            )
-            s = c
-        return out
+
+def _split_digests(flat: bytes, start: int, n: int) -> list[bytes]:
+    """Chunks ``start .. start + n`` of a native arm's back-to-back digests."""
+    return [flat[32 * k : 32 * (k + 1)] for k in range(start, start + n)]
 
 
 class _HostDigester:
@@ -198,8 +186,6 @@ class _HostDigester:
         self.digester = digester
 
     def submit(self, datas: list[bytes]):
-        from nydus_snapshotter_tpu.ops.chunker import host_digests_for
-
         # One shared buffer so the same-source-array grouping makes a
         # single native call for the whole batch.
         buf = np.frombuffer(b"".join(datas), dtype=np.uint8)
@@ -226,15 +212,10 @@ class _DeviceDigester:
         # (a max-size chunk is one block over a power of two; rounding up
         # would double the scan — same reasoning as
         # ops/chunker._digests_bucketed).
-        from nydus_snapshotter_tpu.ops import sha256
-
         self._max_blocks = sha256.n_padded_blocks(max_chunk)
 
     def submit(self, datas: list[bytes]):
         import jax.numpy as jnp
-
-        from nydus_snapshotter_tpu.ops import sha256
-        from nydus_snapshotter_tpu.ops.chunker import _pow2_ceil
 
         max_blocks = self._max_blocks
         buckets: dict[int, list[int]] = {}
@@ -255,8 +236,6 @@ class _DeviceDigester:
 
     def collect(self, handle) -> list[bytes]:
         import jax
-
-        from nydus_snapshotter_tpu.ops import sha256
 
         n, parts = handle
         out: list[Optional[bytes]] = [None] * n
@@ -412,8 +391,6 @@ class _DeferredSectionWriter:
         self._side += data
 
     def finish(self) -> None:
-        from nydus_snapshotter_tpu.ops import native_cdc
-
         m = len(self._items)
         if m == 0:
             return
@@ -439,12 +416,13 @@ class _DeferredSectionWriter:
                 self.coff += len(comp)
             self.hasher._d = hasher.digest()
             return
-        blob, comp_ext, digest = res
-        self._adopt(blob, comp_ext, digest)
+        self.adopt(*res)
 
-    def _adopt(self, blob, comp_extents, digest: bytes) -> None:
-        """Adopt a native pass's assembled section (shared by finish()
-        and finish_fused())."""
+    def adopt(self, blob, comp_extents, digest: bytes) -> None:
+        """Adopt a native pass's assembled section: finish()'s own, or the
+        whole-layer pass's (ntpu_pack_files, through _Assembler.adopt: it
+        already compressed/assembled/hashed and nothing was ever add()ed,
+        so the regular finish() stays a no-op)."""
         self.extents = [
             (int(comp_extents[j, 0]), int(comp_extents[j, 1]), self._cflag)
             for j in range(comp_extents.shape[0])
@@ -453,12 +431,6 @@ class _DeferredSectionWriter:
         if blob.size:
             self.out.write(memoryview(blob))
         self.coff = int(blob.size)
-
-    def finish_fused(self, blob, comp_extents, digest: bytes) -> None:
-        """Adopt the whole-layer fused pass's output (ntpu_pack_files):
-        the native call already compressed/assembled/hashed; nothing was
-        ever add()ed, so the regular finish() stays a no-op."""
-        self._adopt(blob, comp_extents, digest)
 
 
 @dataclass
@@ -698,6 +670,421 @@ def _fast_tar_members(raw: memoryview):
     return out if saw_end else None
 
 
+class _Assembler:
+    """The ordered dedup of one pack: first wins over the blob's own chunks
+    and the dictionary's, in tar order (deterministic); a chunk seen for
+    the first time goes to the section writer."""
+
+    def __init__(self, section, chunk_dict):
+        self.section = section
+        self.chunk_dict = chunk_dict
+        self.own: dict[bytes, int] = {}  # digest -> index among the blob's unique chunks
+        self.uncomp_offsets: list[int] = []  # per unique chunk
+        self.uoff = 0
+        self.dict_hits: dict[bytes, ChunkRecord] = {}
+        self.dict_blobs_used: list[str] = []
+        # frames the stage pipeline compressed ahead, by digest (its lane
+        # sets it for its run)
+        self.comp = None
+
+    def process(self, batch: list[tuple[_Meta, bytes]], digests: list[bytes]) -> None:
+        chunk_dict, own, dict_hits, comp = self.chunk_dict, self.own, self.dict_hits, self.comp
+        for (meta, data), digest in zip(batch, digests):
+            ref = _ChunkRef(digest=digest, size=len(data))
+            if chunk_dict is not None and digest not in dict_hits and digest not in own:
+                hit = chunk_dict.get(digest)
+                if hit is not None:
+                    dict_hits[digest] = hit
+                    bid = chunk_dict.blob_id_for(hit)
+                    if bid not in self.dict_blobs_used:
+                        self.dict_blobs_used.append(bid)
+            if digest in dict_hits:
+                ref.dict_hit = dict_hits[digest]
+            else:
+                idx = own.get(digest)
+                if idx is None:
+                    idx = own[digest] = len(self.uncomp_offsets)
+                    self.uncomp_offsets.append(self.uoff)
+                    self.section.add(
+                        idx,
+                        data,
+                        self.uoff,
+                        # pop: each unique digest reaches here exactly once;
+                        # releasing the entry keeps peak RSS at one chunk,
+                        # not the whole compressed blob.
+                        precomp=comp.pop(digest, None) if comp else None,
+                    )
+                    self.uoff += len(data)
+                ref.uniq_idx = idx
+            meta.chunks.append(ref)
+
+    def adopt(self, plan, fused: dict) -> None:
+        """Take the whole-layer native pass's result whole (only into a
+        state no chunk has seeded): its dedup decisions, its section."""
+        digs = fused["digests"]
+        sizes, uniq = fused["chunk_sizes"].tolist(), fused["chunk_uniq"].tolist()
+        pos = 0
+        for (meta, _off, _size), nc in zip(plan, fused["file_nchunks"].tolist()):
+            end = pos + nc
+            meta.chunks.extend(map(_ChunkRef, _split_digests(digs, pos, nc), sizes[pos:end], uniq[pos:end]))
+            pos = end
+        usz = fused["uniq_sizes"]
+        if len(usz):
+            self.uncomp_offsets = (
+                np.concatenate([[0], np.cumsum(usz[:-1])]).astype(np.int64).tolist()
+            )
+            self.uoff = int(usz.sum())
+        self.section.adopt(fused["blob"], fused["comp_extents"], fused["blob_digest"])
+
+
+class _DigestQueue:
+    """Chunks that reach the pack without a digest, in batches of
+    DIGEST_BATCH_BYTES with one batch in flight (on the device while the
+    host reads on); digested batches go to the assembler in order."""
+
+    def __init__(self, digester, asm: _Assembler):
+        self.digester = digester
+        self.asm = asm
+        self.pending: list[tuple[_Meta, bytes]] = []
+        self.pending_bytes = 0
+        self.in_flight: Optional[tuple[object, list[tuple[_Meta, bytes]]]] = None
+
+    def add(self, meta: _Meta, data: bytes, digest: Optional[bytes] = None) -> None:
+        if digest is not None:
+            # the fused chunker already digested this chunk (cache-warm,
+            # single native pass); dedup/write it immediately, in order
+            self.asm.process([(meta, data)], [digest])
+            return
+        self.pending.append((meta, data))
+        self.pending_bytes += len(data)
+        if self.pending_bytes >= DIGEST_BATCH_BYTES:
+            self.dispatch()
+
+    def dispatch(self) -> None:
+        if self.in_flight is not None:
+            handle, batch = self.in_flight
+            self.asm.process(batch, self.digester.collect(handle))
+            self.in_flight = None
+        if self.pending:
+            self.in_flight = (self.digester.submit([d for _, d in self.pending]), self.pending)
+            self.pending = []
+            self.pending_bytes = 0
+
+    def drain(self) -> None:
+        self.dispatch()  # collects old, dispatches remainder
+        self.dispatch()  # collects remainder
+
+
+@dataclass
+class _Pack:
+    """What one pack's stages share: made at its open, handed to its lane."""
+
+    opt: PackOption
+    chunker: IncrementalChunker
+    asm: _Assembler
+    queue: _DigestQueue
+    threads: int
+    codec: object  # an active converter.codec.AdaptiveCodec, or None
+    budget: object
+    stats: Optional[dict]
+
+
+# ---------------------------------------------------------------------------
+# Lanes: what cuts and digests a pack's planned files
+# ---------------------------------------------------------------------------
+#
+# A lane is a generator function (pack, plan, arr, stages): ``plan`` the
+# scan's [(meta, offset, size)] in tar order, ``arr`` the whole tar as
+# u8[n], ``stages`` the pack's running trace.Stages, on which the lane
+# opens its own leaf spans. It yields, per planned file and in plan order,
+# (cuts, digests): the file's exclusive chunk ends and its chunks' digests,
+# or None for digests the lane leaves to the digest queue. Before its first
+# result it may raise _LaneDeclined (the batch overflows the device lane's
+# buffer, a native arm lacks its codec library).
+
+
+class _LaneDeclined(Exception):
+    """This lane cannot run this plan after all: choose_lane names the next."""
+
+
+def _extents(plan) -> list[tuple[int, int]]:
+    return [(off, size) for _meta, off, size in plan]
+
+
+def _lane_native_whole(pack: _Pack, plan, arr, stages):
+    """Every planned file through ONE native call; the assembler adopts
+    the result whole."""
+    # Chunk + digest + first-wins dedup + compress + assemble + blob hash
+    # (the reference's entire `nydus-image create` hot loop). The pass owns
+    # the WHOLE dedup/storage state or none, so no file is left to feed.
+    section = pack.asm.section
+    stages.next("pack:fused_pack")
+    fused = native_cdc.pack_files(
+        arr, np.asarray(_extents(plan), dtype=np.int64), pack.chunker.params,
+        section._kind, section._accel, pack.threads, digester=pack.opt.digester,
+    )
+    if fused is None:
+        raise _LaneDeclined("ntpu_pack_files cannot run")
+    pack.asm.adopt(plan, fused)
+    yield from itertools.repeat(((), ()), len(plan))  # every file's chunks are in
+
+
+def _lane_native_multi(pack: _Pack, plan, arr, stages):
+    """ONE native call fuses chunk+digest for EVERY planned file."""
+    # Small and large alike — a <= min_size file is exactly one CDC chunk,
+    # so the unified pass subsumes the batched small-file digest sweep. Cut
+    # points and digests are bit-identical to the per-file lane's.
+    stages.next("pack:chunk_digest")
+    ncuts, cuts, digs = native_cdc.chunk_digest_multi(
+        arr, np.asarray(_extents(plan), dtype=np.int64), pack.chunker.params,
+        digester=pack.opt.digester,
+    )
+    stages.next("pack:dedup")
+    cuts = cuts.tolist()
+    pos = 0
+    for nc in ncuts.tolist():
+        yield cuts[pos : pos + nc], _split_digests(digs, pos, nc)
+        pos += nc
+
+
+def _lane_device(pack: _Pack, plan, arr, stages):
+    """The WHOLE layer's files as one two-dispatch device batch; the engine
+    drives ``pack:lane.*`` on ``stages``."""
+    # ops/fused_convert — gear+compaction, then gather+digest, the host
+    # keeping only cut metadata: the tar is the lane's buffer, the plan's
+    # extents its table.
+    from nydus_snapshotter_tpu.ops import fused_convert
+
+    engine = fused_convert.FusedDeviceEngine(
+        chunk_size=pack.opt.chunk_size, digester=pack.opt.digester
+    )
+    try:
+        res = engine.process_many(fused_convert.Extents(arr, _extents(plan)), stages=stages)
+    except fused_convert.FusedOverflow as e:  # pathological input
+        fused_convert.record_host_fallback()
+        raise _LaneDeclined(str(e)) from e
+    stages.next("pack:dedup")
+    yield from zip(res.cuts, res.digests)
+
+
+def _file_pipeline(pack: _Pack, plan, arr, file_idxs: list[int]):
+    """The stage-parallel pipeline (parallel/pipeline.py) over the planned
+    files ``file_idxs``, or None where its configuration is off or there
+    is nothing to overlap."""
+    # Within-layer parallelism for multi-core hosts (the reference gets it
+    # from the builder's internal thread pool): workers chunk + digest
+    # files and speculatively compress each unique chunk as soon as its
+    # digest exists — compression is deterministic, so racing duplicate
+    # digests write identical bytes — and the ordered walk only dedups +
+    # assembles. Queues between stages are byte-bounded and compressed
+    # bytes in flight draw from a MemoryBudget (shared across layers in
+    # batch conversion), so convert memory stays independent of layer size
+    # and count. Blob bytes are identical to the serial path (pinned by
+    # tests/test_fast_tar.py and tests/test_pipeline_determinism.py).
+    from nydus_snapshotter_tpu.parallel import pipeline as pipeline_mod
+
+    opt, chunker, chunk_dict = pack.opt, pack.chunker, pack.asm.chunk_dict
+    pcfg = pipeline_mod.resolve_config(pack.threads) if len(file_idxs) > 1 else None
+    if pcfg is None or not pcfg.enabled:
+        return None
+    raw = memoryview(arr)
+    # Non-fused engines cut without digesting; digest in the worker (same
+    # bytes → same digests as the batched host dispatch) so dedup and
+    # speculative compression can run ahead of the ordered walk.
+    digest_fn = None if chunker.fused else host_digests_for(opt.digester)
+
+    def chunk_one(i: int):
+        _meta, off, size = plan[i]
+        cuts, digests = chunker.cut_whole(arr[off : off + size])
+        starts = [0, *cuts[:-1]]
+        if digests is None:
+            digests = digest_fn([(arr, off + s, c - s) for s, c in zip(starts, cuts)])
+        return [(raw[off + s : off + c], d) for s, c, d in zip(starts, cuts, digests)]
+
+    compress_fn = compress_eligible = None
+    if opt.compressor in ("lz4_block", "zstd") and not isinstance(
+        pack.asm.section, _DeferredSectionWriter
+    ):
+        # (Deferred sections compress inside the native pass with their
+        # own thread fan-out — speculating here would do the work twice.)
+        # Per-thread codec contexts: lz4 calls are stateless, zstd contexts
+        # are not thread-safe; both codecs are deterministic.
+        # ThreadSafeCompressor also carries the encode_many batch seam:
+        # pipeline compress workers drain up to [compression] batch_chunks
+        # queued chunks into one GIL-released native batch-encode call
+        # (byte-identical frames either way).
+        compress_fn = ThreadSafeCompressor(opt.compressor, opt.lz4_acceleration, codec=pack.codec)
+
+        def compress_eligible(digest, view):
+            if opt.batch_size and len(view) < opt.batch_size:
+                return False  # batch-packed: compressed jointly
+            if chunk_dict is not None and chunk_dict.get(digest):
+                return False  # dict hit: never stored
+            return True
+
+    return pipeline_mod.ConvertPipeline(
+        items=[(i, plan[i][2]) for i in file_idxs],
+        chunk_fn=chunk_one,
+        compress_fn=compress_fn,
+        compress_eligible=compress_eligible,
+        config=pcfg,
+        budget=pack.budget,
+        stats=pack.stats,
+    )
+
+
+def _lane_per_file(pack: _Pack, plan, arr, stages, workers: bool = False):
+    """File by file in tar order, chunking here or (``workers``) ahead on
+    the stage pipeline's threads."""
+    chunker, asm = pack.chunker, pack.asm
+    # chunking interleaves with the driver's ordered dedup walk file by
+    # file, so the loop is ONE span, never one a file
+    stages.next("pack:chunk_digest")
+    # Where the chunker's native arm digests, the files of one chunk
+    # (≤ min_size — the node_modules shape) are digested in a single native
+    # SHA sweep over the tar buffer instead of one engine call per file.
+    small_max = chunker.params.min_size if chunker.fused else 0
+    small_items = [(arr, off, size) for _m, off, size in plan if size <= small_max]
+    small_digests = iter(host_digests_for(pack.opt.digester)(small_items)) if small_items else None
+    files = [i for i, (_m, _o, size) in enumerate(plan) if size > small_max]
+    pipe = _file_pipeline(pack, plan, arr, files) if workers else None
+    if pipe is not None:
+        # Serial-path equivalence: any walk-time chunks (sparse members)
+        # sit in the pending digest batches and would be section.add'ed
+        # before the plan's chunks — drain them now so the pipelined
+        # immediate process() keeps that order.
+        pack.queue.drain()
+        if pipe.compress_fn is not None:
+            asm.comp = pipe.comp
+    try:
+        with pipe if pipe is not None else nullcontext():
+            for i, (_meta, off, size) in enumerate(plan):
+                if size <= small_max:  # exactly one chunk
+                    yield [size], [next(small_digests)]
+                elif pipe is None:
+                    yield chunker.cut_whole(arr[off : off + size])
+                else:
+                    chunks = pipe.chunks_for(i)
+                    cuts = itertools.accumulate(len(view) for view, _d in chunks)
+                    yield cuts, [d for _v, d in chunks]  # the workers digest every chunk
+    finally:
+        asm.comp = None
+
+
+def _lane_per_file_workers(pack: _Pack, plan, arr, stages):
+    """The per-file lane, the stage pipeline's threads chunking ahead of it."""
+    return _lane_per_file(pack, plan, arr, stages, workers=True)
+
+
+def _device_lane_wanted(opt: PackOption) -> bool:
+    """The device lane's entry condition: choose_lane asks it of a scanned
+    pack, read_layer of a layer it is about to read."""
+    return opt.backend == "fused" and opt.chunking == "cdc"
+
+
+def _deferred_section(opt: PackOption, codec_active: bool, native) -> bool:
+    """Whether an in-memory layer's data section is assembled in one native
+    pass (_DeferredSectionWriter): only for layouts it reproduces byte for byte."""
+    return (
+        opt.compressor in ("none", "lz4_block", "zstd")
+        # the adaptive codec owns per-chunk frame decisions — the native
+        # section arms compress at one fixed level and would bypass it
+        and not codec_active
+        and not opt.encrypt
+        and not opt.batch_size
+        and not (opt.aligned_chunk and opt.fs_version == layout.RAFS_V5)
+        and native.pack_section_available()
+    )
+
+
+def choose_lane(
+    opt: PackOption,
+    *,
+    in_memory: bool,
+    threads: int,
+    host_fused: bool,
+    has_dict: bool,
+    codec_active: bool,
+    seeded: bool,
+    native=native_cdc,
+    declined=(),
+):
+    """The first lane, in order of precedence (native whole-layer, native
+    multi, device, per-file with workers, per-file), that this pack can
+    take and that has not ``declined`` its plan; None for a source that is
+    not in memory (it plans nothing: the walk chunked each member as it
+    streamed). Judged from what the pack can observe and nothing else:
+    ``opt``, the pack ``threads`` (_pack_threads), whether the chunker's
+    native chunk+digest arm serves this ``opt`` (``host_fused``:
+    IncrementalChunker.fused) and which further arms of ``native`` loaded,
+    a dictionary, an active adaptive codec, and whether the walk already
+    ``seeded`` chunk state (sparse members are chunked as it goes)."""
+    if not in_memory:
+        return None
+    # the native arms serve one thread, and CDC on the hybrid backend only
+    # (host_fused), so a --backend fused pack takes neither of them
+    multi = threads == 1 and host_fused and native.chunk_digest_multi_available()
+    lanes = (
+        (
+            _lane_native_whole,
+            multi
+            # dict probes stay in the Python dedup lane
+            and not has_dict
+            # the pass owns the WHOLE dedup/storage state or none
+            and not seeded
+            and _deferred_section(opt, codec_active, native)
+            and native.pack_files_available(),
+        ),
+        (_lane_native_multi, multi),
+        (_lane_device, _device_lane_wanted(opt)),
+        # Host arms only: fused/native/numpy chunking is safe to call from
+        # worker threads (GIL-dropping where it matters); the jax lanes
+        # keep their own double-buffered device dispatch discipline.
+        (
+            _lane_per_file_workers,
+            threads > 1 and opt.backend in ("hybrid", "numpy") and opt.digest_backend != "jax",
+        ),
+        (_lane_per_file, True),
+    )
+    return next(lane for lane, open_ in lanes if open_ and lane not in declined)
+
+
+def read_layer(f: BinaryIO, opt: PackOption):
+    """The whole layer tar of the open file ``f``, for pack_stream. Where
+    ``opt`` leads to the device lane it is read into the head of a zeroed
+    buffer of the lane's padded length, so that the lane uploads the
+    buffer as it stands (fused_convert.lane_buffer; the untouched tail
+    costs no page), and a view of the tar's own bytes is returned. Any
+    other pack gets the plain ``bytes``."""
+    size = os.fstat(f.fileno()).st_size  # 0 for a pipe
+    if not (size and not opt.oci_ref and _device_lane_wanted(opt)):
+        return f.read()
+    from nydus_snapshotter_tpu.ops import fused_convert
+
+    opt.validate()  # the chunk size, before the padding rule takes it
+    try:
+        npad = fused_convert.padded_length(size, cdc.CDCParams(opt.chunk_size).max_size)
+    except fused_convert.FusedOverflow:
+        return f.read()  # no lane buffer holds it: the lane will say so
+    buf = fused_convert.zeroed_buffer(npad)
+    view = memoryview(buf)[:size]
+    got = 0
+    while got < size:
+        k = f.readinto(view[got:])
+        if not k:
+            break
+        got += k
+    rest = f.read()
+    if got == size and not rest:
+        return buf[:size]
+    return bytes(view[:got]) + rest  # the file changed under the read
+
+
+# ---------------------------------------------------------------------------
+# The pack
+# ---------------------------------------------------------------------------
+
+
 def pack_stream(
     dest: BinaryIO,
     src_tar: "BinaryIO | bytes | np.ndarray",
@@ -710,9 +1097,9 @@ def pack_stream(
     """Stream one OCI layer tar into a nydus blob written to ``dest``.
 
     ``src_tar``: a file-like source, or the whole tar in memory as
-    ``bytes`` / ``bytearray`` / a 1-D uint8 array (for the fused backend
-    best the head of a ``fused_convert.zeroed_buffer`` of ``padded_length``
-    bytes, which the lane then uploads without a copy).
+    ``bytes`` / ``bytearray`` / a 1-D uint8 array (best what ``read_layer``
+    gives: for the device lane the head of a ``fused_convert.zeroed_buffer``
+    of ``padded_length`` bytes, which the lane then uploads without a copy).
 
     Reference semantics (convert_unix.go:325-539): uncompressed layer tar
     in, tar-like nydus blob out; chunk-dict hits are referenced, not stored.
@@ -776,165 +1163,20 @@ _STATS_SPANS = {
 }
 
 
-def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
-    """pack_stream's body; ``stages`` (trace.Stages) runs its leaf spans."""
-    import io
-
-    opt.validate()
-    # In-memory layers take the zero-copy path: random-access tar parse,
-    # whole-file views sliced straight out of the caller's buffer (the
-    # bounded-memory streaming discipline below only matters for file-like
-    # sources that may not fit in RAM).
-    # A uint8 array counts too (cmd_pack reads a layer into one with the
-    # fused lane's padding behind it, and the lane uploads that as it is).
-    raw: Optional[memoryview] = None
-    if isinstance(src_tar, (bytes, bytearray, np.ndarray)):
-        if isinstance(src_tar, np.ndarray) and not (
-            src_tar.dtype == np.uint8 and src_tar.ndim == 1 and src_tar.flags.c_contiguous
-        ):
-            raise ConvertError("an in-memory layer tar array must be contiguous 1-D uint8")
-        raw = memoryview(src_tar)
-
-    if chunk_dict is None and opt.chunk_dict_path:
-        # service://<uds>[#namespace] connects a shared-dict mirror; any
-        # other shape is the file-based dict as before.
-        from nydus_snapshotter_tpu.parallel.dict_service import open_chunk_dict
-
-        stages.next("pack:dict_load")
-        chunk_dict = open_chunk_dict(opt.chunk_dict_path)
-        stages.annotate(
-            dict_chunks=len(chunk_dict), dict_blobs=len(chunk_dict.blob_ids())
-        )
-    # everything up to the chunk stage's own call is the scan: set-up, the
-    # member walk, the plan's extents
-    stages.next("pack:scan")
-    from nydus_snapshotter_tpu.converter.convert import _make_compressor
-
-    if codec is None:
-        from nydus_snapshotter_tpu.converter import codec as codec_mod
-
-        codec = codec_mod.resolve_codec(opt)
-
-    out = _CountingWriter(dest)
-    from nydus_snapshotter_tpu.ops import native_cdc
-
-    compress = _make_compressor(opt.compressor, opt.lz4_acceleration, codec=codec)
-    align_needed = opt.aligned_chunk and opt.fs_version == layout.RAFS_V5
-    if (
-        raw is not None
-        and opt.compressor in ("none", "lz4_block", "zstd")
-        # the adaptive codec owns per-chunk frame decisions — the native
-        # section arms compress at one fixed level and would bypass it
-        and codec is None
-        and not opt.encrypt
-        and not opt.batch_size
-        and not align_needed
-        and native_cdc.pack_section_available()
-    ):
-        section: "object" = _DeferredSectionWriter(out, opt, compress, raw)
-    else:
-        section = _SectionWriter(out, opt, compress)
-    max_chunk = cdc.CDCParams(opt.chunk_size).max_size if opt.chunking == "cdc" else opt.chunk_size
-    digester = (
-        _DeviceDigester(max_chunk)
-        # the device batch kernel is SHA-256; blake3 always digests on the
-        # host blake3 arm (native/pure-Python), whatever the backend
-        if (opt.backend == "jax" or opt.digest_backend == "jax")
-        and opt.digester == "sha256"
-        else _HostDigester(opt.digester)
-    )
-
+def _scan(src_tar, raw: Optional[memoryview], opt: PackOption, queue: _DigestQueue, chunker):
+    """The tar walk -> (metas by path, opaque dirs, the plan [(meta,
+    offset, size)] of the in-memory members' extents, members seen)."""
+    # An in-memory layer (``raw``) takes the zero-copy path: random-access
+    # tar parse, and chunk/digest work deferred to the lane — the plan stays
+    # in tar order, so the blob layout and dedup state are identical to
+    # immediate processing. A member of a file-like source (and a sparse
+    # one) is chunked here as it streams, bounded-memory: that discipline
+    # only matters for sources that may not fit in RAM.
     metas: dict[str, _Meta] = {}
     opaque_dirs: list[str] = []
+    plan: list[tuple[_Meta, int, int]] = []
 
-    # Dedup state (chunk order = tar order; deterministic).
-    own_chunks: dict[bytes, int] = {}
-    uncomp_offsets: list[int] = []
-    uoff = 0
-    dict_hits: dict[bytes, ChunkRecord] = {}
-    dict_blobs_used: list[str] = []
-
-    # One digest batch in flight: (handle, [(meta, data)]) pairs.
-    pending: list[tuple[_Meta, bytes]] = []
-    pending_bytes = 0
-    in_flight: Optional[tuple[object, list[tuple[_Meta, bytes]]]] = None
-
-    def _process(
-        batch: list[tuple[_Meta, bytes]],
-        digests: list[bytes],
-        comp_cache: "Optional[dict[bytes, tuple[bytes, int]]]" = None,
-    ) -> None:
-        nonlocal uoff
-        for (meta, data), digest in zip(batch, digests):
-            ref = _ChunkRef(digest=digest, size=len(data))
-            if chunk_dict is not None and digest not in dict_hits and digest not in own_chunks:
-                hit = chunk_dict.get(digest)
-                if hit is not None:
-                    dict_hits[digest] = hit
-                    bid = chunk_dict.blob_id_for(hit)
-                    if bid not in dict_blobs_used:
-                        dict_blobs_used.append(bid)
-            if digest in dict_hits:
-                ref.dict_hit = dict_hits[digest]
-            else:
-                idx = own_chunks.get(digest)
-                if idx is None:
-                    idx = len(uncomp_offsets)
-                    own_chunks[digest] = idx
-                    uncomp_offsets.append(uoff)
-                    section.add(
-                        idx,
-                        data,
-                        uoff,
-                        # pop: each unique digest reaches here exactly once;
-                        # releasing the entry keeps peak RSS at one chunk,
-                        # not the whole compressed blob.
-                        precomp=comp_cache.pop(digest, None) if comp_cache else None,
-                    )
-                    uoff += len(data)
-                ref.uniq_idx = idx
-            meta.chunks.append(ref)
-
-    def _dispatch() -> None:
-        nonlocal pending, pending_bytes, in_flight
-        if in_flight is not None:
-            handle, batch = in_flight
-            _process(batch, digester.collect(handle))
-            in_flight = None
-        if pending:
-            in_flight = (digester.submit([d for _, d in pending]), pending)
-            pending = []
-            pending_bytes = 0
-
-    def _drain_all() -> None:
-        _dispatch()  # collects old, dispatches remainder
-        _dispatch()  # collects remainder
-
-    def _add_chunk(meta: _Meta, data: bytes, digest: Optional[bytes] = None) -> None:
-        nonlocal pending_bytes
-        if digest is not None:
-            # the fused chunker already digested this chunk (cache-warm,
-            # single native pass); dedup/write it immediately, in order
-            _process([(meta, data)], [digest])
-            return
-        pending.append((meta, data))
-        pending_bytes += len(data)
-        if pending_bytes >= DIGEST_BATCH_BYTES:
-            _dispatch()
-
-    shared_chunker = IncrementalChunker(opt)
-    # In-memory plan: chunk/digest work is deferred during the header walk
-    # so thousands of small files (≤ one chunk each — the node_modules
-    # shape) batch into a single native SHA sweep over the tar buffer
-    # instead of one engine call per file. Entries stay in tar order, so
-    # the blob layout and dedup state are identical to immediate
-    # processing. ("small", meta, off, size) | ("file", meta, off, size)
-    plan: list[tuple[str, _Meta, int, int]] = []
-    params = shared_chunker._engine.params
-    small_max = params.min_size if params is not None else opt.chunk_size
-    defer_small = raw is not None and shared_chunker.fused
-
-    def _walk_member(info, data_off, tf) -> None:
+    def walk(info, data_off, tf) -> None:
         path = fstree.norm_path(info.name)
         special = fstree.classify_special(path)
         if special is not None:
@@ -957,353 +1199,174 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
             # Zero-copy: the member's bytes are a slice of the caller's
             # buffer (sparse members store data compacted, so they take
             # the extractfile path).
-            tag = "small" if defer_small and info.size <= small_max else "file"
-            plan.append((tag, meta, data_off, info.size))
+            plan.append((meta, data_off, info.size))
             return
         f = tf.extractfile(info)
         if f is None:
             raise ConvertError(f"tar member {path!r} has no data stream")
-        chunker = IncrementalChunker(opt, engine=shared_chunker._engine)
+        member = IncrementalChunker(opt, engine=chunker._engine)
         while True:
             seg = f.read(SEGMENT_BYTES)
             if not seg:
                 break
-            for chunk, digest in chunker.feed(seg):
-                _add_chunk(meta, chunk, digest)
-        for chunk, digest in chunker.finish():
-            _add_chunk(meta, chunk, digest)
+            for chunk, digest in member.feed(seg):
+                queue.add(meta, chunk, digest)
+        for chunk, digest in member.finish():
+            queue.add(meta, chunk, digest)
 
-    n_members = 0
     members = _fast_tar_members(raw) if raw is not None else None
     if members is not None:
-        n_members = len(members)
         for info, data_off in members:
-            _walk_member(info, data_off, None)  # tf unused: data via raw
+            walk(info, data_off, None)  # tf unused: data via raw
+        return metas, opaque_dirs, plan, len(members)
+    n_members = 0
+    try:
+        # Random access for in-memory layers (tarfile's stream mode copies
+        # every data byte through its internal block buffers). io.BytesIO
+        # shares a bytes object and copies anything else, so it is built
+        # only here, where the fast walk gave up.
+        with tarfile.open(
+            fileobj=io.BytesIO(src_tar) if raw is not None else src_tar,
+            mode="r:" if raw is not None else "r|",
+        ) as tf:
+            for info in tf:
+                n_members += 1
+                walk(info, info.offset_data if raw is not None else None, tf)
+    except tarfile.TarError as e:
+        raise ConvertError(f"bad layer tar: {e}") from e
+    return metas, opaque_dirs, plan, n_members
+
+
+def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
+    """pack_stream's body: open, scan, lane, assemble, emit; ``stages``
+    (trace.Stages) runs its leaf spans."""
+    opt.validate()
+    # A uint8 array counts as an in-memory layer too (read_layer hands one
+    # over with the device lane's padding behind it, and the lane uploads
+    # that as it is).
+    raw: Optional[memoryview] = None
+    arr = None  # the same bytes as u8[n]
+    if isinstance(src_tar, (bytes, bytearray, np.ndarray)):
+        if isinstance(src_tar, np.ndarray) and not (
+            src_tar.dtype == np.uint8 and src_tar.ndim == 1 and src_tar.flags.c_contiguous
+        ):
+            raise ConvertError("an in-memory layer tar array must be contiguous 1-D uint8")
+        raw = memoryview(src_tar)
+        # the caller's own array where it gave one: the device lane looks
+        # for room behind it (fused_convert.lane_buffer)
+        arr = src_tar if isinstance(src_tar, np.ndarray) else np.frombuffer(raw, dtype=np.uint8)
+
+    if chunk_dict is None and opt.chunk_dict_path:
+        # service://<uds>[#namespace] connects a shared-dict mirror; any
+        # other shape is the file-based dict as before.
+        from nydus_snapshotter_tpu.parallel.dict_service import open_chunk_dict
+
+        stages.next("pack:dict_load")
+        chunk_dict = open_chunk_dict(opt.chunk_dict_path)
+        stages.annotate(
+            dict_chunks=len(chunk_dict), dict_blobs=len(chunk_dict.blob_ids())
+        )
+    # everything up to the lane's own call is the scan: set-up, the member
+    # walk, the plan's extents
+    stages.next("pack:scan")
+    if codec is None:
+        codec = codec_mod.resolve_codec(opt)
+    out = _CountingWriter(dest)
+    compress = _make_compressor(opt.compressor, opt.lz4_acceleration, codec=codec)
+    if raw is not None and _deferred_section(opt, codec is not None, native_cdc):
+        section: "object" = _DeferredSectionWriter(out, opt, compress, raw)
     else:
-        try:
-            # Random access for in-memory layers (tarfile's stream mode
-            # copies every data byte through its internal block buffers).
-            # io.BytesIO shares a bytes object and copies anything else,
-            # so it is built only here, where the fast walk gave up.
-            tf = tarfile.open(
-                fileobj=io.BytesIO(src_tar) if raw is not None else src_tar,
-                mode="r:" if raw is not None else "r|",
-            )
-        except tarfile.TarError as e:
-            raise ConvertError(f"bad layer tar: {e}") from e
-        with tf:
-            try:
-                for info in tf:
-                    n_members += 1
-                    _walk_member(
-                        info,
-                        info.offset_data if raw is not None else None,
-                        tf,
-                    )
-            except tarfile.TarError as e:
-                raise ConvertError(f"bad layer tar: {e}") from e
+        section = _SectionWriter(out, opt, compress)
+    chunker = IncrementalChunker(opt)
+    asm = _Assembler(section, chunk_dict)
+    queue = _DigestQueue(
+        _DeviceDigester(chunker.lookahead)
+        # the device batch kernel is SHA-256; blake3 always digests on the
+        # host blake3 arm (native/pure-Python), whatever the backend
+        if (opt.backend == "jax" or opt.digest_backend == "jax") and opt.digester == "sha256"
+        else _HostDigester(opt.digester),
+        asm,
+    )
+    pack = _Pack(opt, chunker, asm, queue, _pack_threads(), codec, budget, stats)
+    metas, opaque_dirs, plan, n_members = _scan(src_tar, raw, opt, queue, chunker)
     stages.annotate(
         members=n_members,
         files_planned=len(plan),
-        bytes_planned=sum(size for _t, _m, _o, size in plan),
+        bytes_planned=sum(size for _m, _o, size in plan),
     )
-    if plan:
-        from nydus_snapshotter_tpu.ops import native_cdc
-
-        # the caller's own array where it gave one: the fused lane looks
-        # for room behind it (fused_convert.lane_buffer)
-        arr_all = (
-            src_tar if isinstance(src_tar, np.ndarray) else np.frombuffer(raw, dtype=np.uint8)
+    declined: list = []
+    while plan:
+        lane = choose_lane(
+            opt,
+            in_memory=raw is not None,
+            threads=pack.threads,
+            host_fused=chunker.fused,
+            has_dict=chunk_dict is not None,
+            codec_active=codec is not None,
+            seeded=bool(asm.own or asm.uoff or queue.pending or queue.in_flight),
+            declined=declined,
         )
-        n_threads = _pack_threads()
-        # Single-thread fast lane: ONE native call fuses chunk+digest for
-        # EVERY planned file (small and large alike — a <= min_size file
-        # is exactly one CDC chunk, so the unified pass subsumes the
-        # batched small-file digest sweep). Cut points, digests, dedup
-        # and blob bytes are bit-identical to the per-file path.
-        use_multi = (
-            n_threads == 1
-            and shared_chunker.fused
-            and params is not None
-            and opt.chunking == "cdc"
-            and native_cdc.chunk_digest_multi_available()
-        )
-        # Whole-layer fused lane: chunk + digest + first-wins dedup +
-        # compress + assemble + blob hash in ONE native call (the
-        # reference's entire `nydus-image create` hot loop). Applies when
-        # there is no chunk dict (dict probes stay in the Python dedup
-        # lane) and the storage layout is the deferred writer's.
-        if (
-            use_multi
-            and chunk_dict is None
-            and isinstance(section, _DeferredSectionWriter)
-            and native_cdc.pack_files_available()
-            # the walk must not have seeded any chunk state already
-            # (sparse members stream through _process during the walk):
-            # the fused pass owns the WHOLE dedup/storage state or none.
-            and uoff == 0
-            and not own_chunks
-            and not pending
-            and in_flight is None
-            and not section._items
-        ):
-            ext = np.asarray(
-                [(off, size) for _t, _m, off, size in plan], dtype=np.int64
-            )
-            stages.next("pack:fused_pack")
-            fused = native_cdc.pack_files(
-                arr_all, ext, params, section._kind, section._accel, n_threads,
-                digester=opt.digester,
-            )
-            if fused is not None:
-                digs = fused["digests"]
-                sizes_arr = fused["chunk_sizes"]
-                uniq_arr = fused["chunk_uniq"]
-                pos = 0
-                for (_tag, meta, _off, _size), nc in zip(
-                    plan, fused["file_nchunks"]
-                ):
-                    for k in range(int(nc)):
-                        meta.chunks.append(
-                            _ChunkRef(
-                                digest=digs[32 * (pos + k) : 32 * (pos + k + 1)],
-                                size=int(sizes_arr[pos + k]),
-                                uniq_idx=int(uniq_arr[pos + k]),
-                            )
-                        )
-                    pos += int(nc)
-                usz = fused["uniq_sizes"]
-                if len(usz):
-                    uncomp_offsets = (
-                        np.concatenate([[0], np.cumsum(usz[:-1])])
-                        .astype(np.int64)
-                        .tolist()
-                    )
-                    uoff = int(usz.sum())
-                section.finish_fused(
-                    fused["blob"], fused["comp_extents"], fused["blob_digest"]
-                )
-                plan = []
-        if use_multi and plan:
-            ext = np.asarray(
-                [(off, size) for _t, _m, off, size in plan], dtype=np.int64
-            )
-            stages.next("pack:chunk_digest")
-            ncuts_arr, cuts_all, digs_all = native_cdc.chunk_digest_multi(
-                arr_all, ext, params, digester=opt.digester
-            )
-            stages.next("pack:dedup")
-            pos = 0
-            for (tag, meta, off, size), nc in zip(plan, ncuts_arr):
-                nc = int(nc)
-                view = raw[off : off + size]
-                s = 0
-                batch = []
-                dlist = []
-                for k in range(nc):
-                    c = int(cuts_all[pos + k])
-                    batch.append((meta, view[s:c]))
-                    dlist.append(digs_all[32 * (pos + k) : 32 * (pos + k + 1)])
-                    s = c
-                _process(batch, dlist)
-                pos += nc
-            plan = []  # consumed; skip the per-file paths below
-        # Device full-path lane (opt.backend == "fused"): the WHOLE layer's
-        # files as one two-dispatch device batch (ops/fused_convert —
-        # gear+compaction, then gather+digest), host keeping only cut
-        # metadata. Dedup (incl. chunk-dict probes) and compression stay
-        # in the _process lane, byte-identical to the host paths.
-        if (
-            plan
-            and opt.backend == "fused"
-            and params is not None
-            and opt.chunking == "cdc"
-        ):
-            from nydus_snapshotter_tpu.ops import fused_convert
-
-            feng = fused_convert.FusedDeviceEngine(
-                chunk_size=opt.chunk_size, digester=opt.digester
-            )
-            stages.close()  # the lane runs its own stages: pack:lane.*
-            try:
-                # the tar is the lane's buffer, the plan's extents its table
-                fres = feng.process_many(
-                    fused_convert.Extents(
-                        arr_all, [(off, size) for _t, _m, off, size in plan]
-                    )
-                )
-            except fused_convert.FusedOverflow:
-                fres = None  # pathological input: per-file paths below
-                fused_convert.record_host_fallback()
-            if fres is not None:
-                stages.next("pack:dedup")
-                stages.seconds.update(fres.span_seconds or {})  # pack:lane.*, once a pack
-                for (_tag, meta, off, size), fcuts, dlist in zip(
-                    plan, fres.cuts, fres.digests
-                ):
+        try:
+            # closing: an error in the walk ends the lane (its workers) now
+            with closing(lane(pack, plan, arr, stages)) as results:
+                for (meta, off, size), (cuts, digests) in zip(plan, results, strict=True):
                     view = raw[off : off + size]
-                    s = 0
+                    start = 0
                     batch = []
-                    for c in fcuts:
-                        batch.append((meta, view[s : int(c)]))
-                        s = int(c)
-                    if batch:
-                        _process(batch, dlist)
-                plan = []
-        small_items = [
-            (arr_all, off, size) for tag, _m, off, size in plan if tag == "small"
-        ]
-        if plan:
-            # the per-file lanes: chunking (here, or on the pipeline's
-            # workers) interleaves with the ordered dedup walk file by
-            # file, so the loop is ONE span, never one a file
-            stages.next("pack:chunk_digest")
-        if small_items:
-            from nydus_snapshotter_tpu.ops.chunker import host_digests_for
-
-            small_digests = iter(host_digests_for(opt.digester)(small_items))
-
-        # Within-layer parallelism for multi-core hosts (the reference gets
-        # it from the builder's internal thread pool): the stage-parallel
-        # pipeline (parallel/pipeline.py) chunks + digests files on a
-        # worker pool, speculatively compresses each unique chunk as soon
-        # as its digest exists — compression is deterministic, so racing
-        # duplicate digests write identical bytes — and the ordered serial
-        # walk below only dedups + assembles. Queues between stages are
-        # byte-bounded and compressed bytes in flight draw from a
-        # MemoryBudget (shared across layers in batch conversion), so
-        # convert memory stays independent of layer size and count. Blob
-        # bytes are identical to the serial path (pinned by
-        # tests/test_fast_tar.py and tests/test_pipeline_determinism.py).
-        comp_cache: dict[bytes, tuple[bytes, int]] = {}  # serial-path default
-        file_idxs = [i for i, (tag, *_rest) in enumerate(plan) if tag == "file"]
-        # Host arms only: fused/native/numpy chunking is safe to call from
-        # worker threads (GIL-dropping where it matters); the jax lanes
-        # keep their own double-buffered device dispatch discipline.
-        pipe = None
-        if (
-            n_threads > 1
-            and len(file_idxs) > 1
-            and opt.backend in ("hybrid", "numpy")
-            and opt.digest_backend != "jax"
-        ):
-            from nydus_snapshotter_tpu.parallel import pipeline as pipeline_mod
-
-            pcfg = pipeline_mod.resolve_config(n_threads)
-            if pcfg.enabled:
-                digest_fn = None
-                if not shared_chunker.fused:
-                    # Non-fused engines cut without digesting; digest in
-                    # the worker (same bytes → same digests as the batched
-                    # host dispatch) so dedup and speculative compression
-                    # can run ahead of the ordered walk.
-                    from nydus_snapshotter_tpu.ops.chunker import (
-                        host_digests_for as _hdf,
-                    )
-
-                    digest_fn = _hdf(opt.digester)
-
-                def _chunk_one(i: int):
-                    _tag, _meta, off, size = plan[i]
-                    chunks = shared_chunker.chunk_whole(raw[off : off + size])
-                    if digest_fn is not None and chunks:
-                        items = []
-                        s = off
-                        for view, _d in chunks:
-                            items.append((arr_all, s, len(view)))
-                            s += len(view)
-                        digs = digest_fn(items)
-                        chunks = [(v, d) for (v, _), d in zip(chunks, digs)]
-                    return chunks
-
-                compress_fn = None
-                compress_eligible = None
-                if opt.compressor in ("lz4_block", "zstd") and not isinstance(
-                    section, _DeferredSectionWriter
-                ):
-                    # (Deferred sections compress inside the native pass
-                    # with their own thread fan-out — speculating here
-                    # would do the work twice.) Per-thread codec contexts:
-                    # lz4 calls are stateless, zstd contexts are not
-                    # thread-safe; both codecs are deterministic.
-                    from nydus_snapshotter_tpu.converter.convert import (
-                        ThreadSafeCompressor,
-                    )
-
-                    # ThreadSafeCompressor also carries the encode_many
-                    # batch seam: pipeline compress workers drain up to
-                    # [compression] batch_chunks queued chunks into one
-                    # GIL-released native batch-encode call (byte-identical
-                    # frames either way).
-                    compress_fn = ThreadSafeCompressor(
-                        opt.compressor, opt.lz4_acceleration, codec=codec
-                    )
-                    batch_limit = opt.batch_size
-
-                    def compress_eligible(digest, view):
-                        if batch_limit and len(view) < batch_limit:
-                            return False  # batch-packed: compressed jointly
-                        if chunk_dict is not None and chunk_dict.get(digest):
-                            return False  # dict hit: never stored
-                        return True
-
-                pipe = pipeline_mod.ConvertPipeline(
-                    items=[(i, plan[i][3]) for i in file_idxs],
-                    chunk_fn=_chunk_one,
-                    compress_fn=compress_fn,
-                    compress_eligible=compress_eligible,
-                    config=pcfg,
-                    budget=budget,
-                    stats=stats,
-                )
-                # Serial-path equivalence: any walk-time chunks (sparse
-                # members) sit in the pending digest batches and would be
-                # section.add'ed before the plan's chunks — drain them now
-                # so the pipelined immediate _process keeps that order.
-                _drain_all()
-
-        from contextlib import nullcontext
-
-        with pipe if pipe is not None else nullcontext():
-            for i, (tag, meta, off, size) in enumerate(plan):
-                view = raw[off : off + size]
-                if tag == "small":  # ≤ min_size ⇒ exactly one chunk
-                    _process([(meta, view)], [next(small_digests)])
-                    continue
-                chunks = (
-                    pipe.chunks_for(i)
-                    if pipe is not None
-                    else shared_chunker.chunk_whole(view)
-                )
-                if chunks and chunks[0][1] is not None:
-                    _process(
-                        [(meta, c) for c, _ in chunks],
-                        [d for _, d in chunks],
-                        comp_cache=pipe.comp
-                        if pipe is not None and pipe.compress_fn is not None
-                        else comp_cache,
-                    )
-                else:
-                    for chunk, digest in chunks:
-                        _add_chunk(meta, chunk, digest)
+                    for cut in cuts:
+                        cut = int(cut)
+                        batch.append((meta, view[start:cut]))
+                        start = cut
+                    if digests is None:
+                        for _meta, chunk in batch:
+                            queue.add(meta, chunk)
+                    elif batch:
+                        asm.process(batch, digests)
+            break
+        except _LaneDeclined:
+            declined.append(lane)
     if stages.running != "pack:dedup":
         stages.next("pack:dedup")
-    _drain_all()
+    queue.drain()
     stages.annotate(
         chunks=sum(len(m.chunks) for m in metas.values()),
-        unique=len(uncomp_offsets),
-        dict_hits=len(dict_hits),
+        unique=len(asm.uncomp_offsets),
+        dict_hits=len(asm.dict_hits),
     )
-    stages.next("pack:compress_write", uncompressed_bytes=uoff)
+    stages.next("pack:compress_write", uncompressed_bytes=asm.uoff)
     section.finish()
     blob_size = section.coff
     stages.annotate(blob_bytes=blob_size)
     stages.next("pack:bootstrap")
-
-    blob_id = section.hasher.hexdigest() if blob_size else ""
     if blob_size:
         out.write(nydus_tar.make_header(toc.ENTRY_BLOB_DATA, blob_size))
+    bootstrap, boot_bytes, toc_entries = emit_bootstrap(metas, opaque_dirs, asm, opt, out.tell())
+    out.write(boot_bytes)
+    out.write(nydus_tar.make_header(toc.ENTRY_BOOTSTRAP, len(boot_bytes)))
+    toc_bytes = toc.pack_toc(toc_entries)
+    out.write(toc_bytes)
+    out.write(nydus_tar.make_header(toc.ENTRY_BLOB_TOC, len(toc_bytes)))
+    stages.annotate(
+        inodes=len(bootstrap.inodes),
+        chunk_records=len(bootstrap.chunks),
+        bootstrap_bytes=len(boot_bytes),
+    )
+    return PackResult(
+        blob_id=bootstrap.blobs[0].blob_id if blob_size else "",
+        blob_size=blob_size,
+        bootstrap=boot_bytes,
+        referenced_blob_ids=[b.blob_id for b in bootstrap.blobs],
+    )
 
+
+def emit_bootstrap(metas: dict, opaque_dirs: list, asm: _Assembler, opt: PackOption, boot_off: int):
+    """-> (the layer's Bootstrap, its bytes, the blob's TOC entries), from
+    the scan's ``metas`` and ``opaque_dirs`` (completed in place: missing
+    parents, opaque marks) and the assembler's tables after
+    ``section.finish()``; the bootstrap will lie at ``boot_off`` in the blob."""
+    section, chunk_dict = asm.section, asm.chunk_dict
+    blob_size = section.coff
+    blob_id = section.hasher.hexdigest() if blob_size else ""
     # Synthesize root + missing parents (metadata only).
     for p in fstree.missing_parents(metas):
         metas[p] = _Meta(entry=fstree.FileEntry(path=p, mode=stat.S_IFDIR | 0o755))
@@ -1324,14 +1387,14 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
             BlobRecord(
                 blob_id=blob_id,
                 compressed_size=blob_size,
-                uncompressed_size=uoff,
-                chunk_count=len(uncomp_offsets),
+                uncompressed_size=asm.uoff,
+                chunk_count=len(asm.uncomp_offsets),
             )
         )
         cipher_table.append(section.cipher or CipherRecord())
         for coff_b, base_u, usize in section.batches:
             batch_table.append(BatchRecord(0, coff_b, base_u, usize))
-    for bid in dict_blobs_used:
+    for bid in asm.dict_blobs_used:
         new_idx = len(blob_table)
         blob_index_of[bid] = new_idx
         dict_idx, dict_rec = next(
@@ -1385,15 +1448,13 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
                             digest=ref.digest,
                             blob_index=blob_index_of[blob_id],
                             flags=cflag,
-                            uncompressed_offset=uncomp_offsets[ref.uniq_idx],
+                            uncompressed_offset=asm.uncomp_offsets[ref.uniq_idx],
                             compressed_offset=coff_c,
                             uncompressed_size=ref.size,
                             compressed_size=csize,
                         )
                     )
         inodes.append(inode)
-
-    from nydus_snapshotter_tpu.converter.convert import match_prefetch_paths
 
     bootstrap = Bootstrap(
         version=opt.fs_version,
@@ -1408,46 +1469,17 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
         else [],
     )
     boot_bytes = bootstrap.to_bytes()
-
-    toc_entries = []
+    entries = [(toc.ENTRY_BOOTSTRAP, hashlib.sha256(boot_bytes).digest(), boot_off, len(boot_bytes))]
     if blob_size:
-        toc_entries.append(
-            toc.TOCEntry(
-                name=toc.ENTRY_BLOB_DATA,
-                flags=constants.COMPRESSOR_NONE,
-                uncompressed_digest=section.hasher.digest(),
-                compressed_offset=0,
-                compressed_size=blob_size,
-                uncompressed_size=blob_size,
-            )
-        )
-    boot_off = out.tell()
-    out.write(boot_bytes)
-    out.write(nydus_tar.make_header(toc.ENTRY_BOOTSTRAP, len(boot_bytes)))
-    toc_entries.append(
+        entries.insert(0, (toc.ENTRY_BLOB_DATA, section.hasher.digest(), 0, blob_size))
+    return bootstrap, boot_bytes, [
         toc.TOCEntry(
-            name=toc.ENTRY_BOOTSTRAP,
+            name=name,
             flags=constants.COMPRESSOR_NONE,
-            uncompressed_digest=hashlib.sha256(boot_bytes).digest(),
-            compressed_offset=boot_off,
-            compressed_size=len(boot_bytes),
-            uncompressed_size=len(boot_bytes),
+            uncompressed_digest=digest,
+            compressed_offset=offset,
+            compressed_size=size,
+            uncompressed_size=size,
         )
-    )
-    toc_bytes = toc.pack_toc(toc_entries)
-    out.write(toc_bytes)
-    out.write(nydus_tar.make_header(toc.ENTRY_BLOB_TOC, len(toc_bytes)))
-    stages.annotate(
-        inodes=len(inodes),
-        chunk_records=len(chunk_records),
-        bootstrap_bytes=len(boot_bytes),
-    )
-
-    from nydus_snapshotter_tpu.converter.convert import PackResult
-
-    return PackResult(
-        blob_id=blob_id,
-        blob_size=blob_size,
-        bootstrap=boot_bytes,
-        referenced_blob_ids=[b.blob_id for b in blob_table],
-    )
+        for name, digest, offset, size in entries
+    ]

@@ -44,9 +44,9 @@ class PackOption:
     encrypt: bool = False
     # Engine selection (replaces BuilderPath): hybrid = the fused native
     # host arm (SIMD bitmaps + SHA-NI) — the default, like the reference
-    # defaulting to its production builder; jax = force the TPU batch arm
-    # (callers such as bench.py race the arms and pick per measurement);
-    # numpy = host differential path.
+    # defaulting to its production builder; jax = force the TPU batch arm;
+    # fused = the device lane (ops/fused_convert); numpy = host
+    # differential path.
     backend: str = "hybrid"
     chunking: str = "cdc"  # "cdc" | "fixed"
     # "" = engine default for the backend; "jax" routes chunk digests

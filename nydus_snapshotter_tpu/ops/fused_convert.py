@@ -52,8 +52,9 @@ TAIL = gear.GEAR_WINDOW - 1
 
 
 class FusedOverflow(RuntimeError):
-    """Candidate compaction capacity exceeded (pathological input) —
-    callers fall back to the windowed bitmap-download path."""
+    """The batch does not fit the lane (a pathological input exceeds the
+    candidate capacity, or int32 addressing): the converter counts it
+    (record_host_fallback) and redoes the batch on its per-file lane."""
 
 
 def _counters():
@@ -191,8 +192,8 @@ def padded_length(total: int, max_size: int) -> int:
     step = max(WINDOW, _pow2_ceil(npad) // 8)
     npad = -(-npad // step) * step
     # Device ints are 32-bit (no x64): pass-2 chunk offsets must
-    # address the buffer with int32. Callers split larger corpora
-    # into sub-2-GiB batches (bench packs per layer, far below this).
+    # address the buffer with int32. A batch is one layer (far below
+    # this as a rule); a caller with more splits it below 2 GiB.
     if npad >= 1 << 31:
         raise FusedOverflow(
             f"batch of {total} bytes pads to {npad} — beyond int32 "
@@ -487,8 +488,6 @@ class FusedResult:
     cuts: list[np.ndarray]  # per-stream exclusive cut ends
     digests: list[list[bytes]]  # per-stream raw 32-B sha256 digests
     probe: np.ndarray | None  # i32 over all chunks in stream order (0=miss)
-    # seconds per ``pack:lane.*`` span of the batch (None for an empty one)
-    span_seconds: dict[str, float] | None = None
 
 
 class FusedDeviceEngine:
@@ -770,11 +769,15 @@ class FusedDeviceEngine:
         depth: int = 8,
         probe_kernel: str = "auto",
         dict_epoch: int | None = None,
+        stages=None,
     ) -> FusedResult:
         """``streams``: separate byte strings, which layout() copies back to
         back into a fresh buffer, or an Extents: streams that already lie
         in one buffer, which is then the lane's own (lane_buffer). Same
-        lane from the upload on, same result for the same streams."""
+        lane from the upload on, same result for the same streams.
+
+        ``stages``: the caller's running ``trace.Stages`` (a pack's) to
+        drive ``pack:lane.*`` on, the last one closed; its own if None."""
         from nydus_snapshotter_tpu import failpoint, trace
 
         # Device batch boundary: chaos-testable (an injected error
@@ -786,7 +789,8 @@ class FusedDeviceEngine:
         # One span a stage, consecutive (trace.Stages): a span's enter/exit
         # is the only clock read at its boundary, and the stage counters
         # and the caller's stats are fed from the spans' own seconds.
-        with trace.Stages() as lane:
+        with (trace.Stages() if stages is None else stages) as lane:
+            before = dict(lane.seconds)  # it sums by name over all it ran
             lane.next("pack:lane.layout")
             buf, table, n, copied = self._lay(streams)
             if buf is None:
@@ -863,7 +867,7 @@ class FusedDeviceEngine:
             for f_cuts in cuts:
                 out_digests.append(flat_digests[pos : pos + len(f_cuts)])
                 pos += len(f_cuts)
-        took = lane.seconds
+        took = {name: s - before.get(name, 0.0) for name, s in lane.seconds.items()}
         _record_dispatch(
             n,
             {
@@ -877,4 +881,4 @@ class FusedDeviceEngine:
             row_floor_classes=floored_classes,
             copied_bytes=copied,
         )
-        return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np, span_seconds=took)
+        return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np)
