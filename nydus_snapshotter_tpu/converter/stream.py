@@ -787,6 +787,7 @@ class _Pack:
     codec: object  # an active converter.codec.AdaptiveCodec, or None
     budget: object
     stats: Optional[dict]
+    begun: object = None  # the device lane's first half, where the pack began it (_begin_device_lane)
 
 
 # ---------------------------------------------------------------------------
@@ -847,6 +848,29 @@ def _lane_native_multi(pack: _Pack, plan, arr, stages):
         pos += nc
 
 
+def _device_engine(opt: PackOption):
+    from nydus_snapshotter_tpu.ops import fused_convert
+
+    return fused_convert.FusedDeviceEngine(chunk_size=opt.chunk_size, digester=opt.digester)
+
+
+def _begin_device_lane(opt: PackOption, arr, stages):
+    """The device lane's first half (FusedDeviceEngine.begin: the tar's
+    upload and pass 1 enqueued, nothing waited for) as soon as the pack
+    has the layer in memory, so that the device works while the host
+    parses the dictionary and walks the tar; None for a pack that does not
+    lead there, by read_layer's own condition. choose_lane still chooses:
+    the pack closes a begun lane that was never finished."""
+    if not (arr is not None and arr.size and _device_lane_wanted(opt)):
+        return None
+    from nydus_snapshotter_tpu.ops import fused_convert
+
+    try:
+        return _device_engine(opt).begin(arr, stages)
+    except fused_convert.FusedOverflow:
+        return None  # no lane buffer holds it: _lane_device meets that again, and counts it
+
+
 def _lane_device(pack: _Pack, plan, arr, stages):
     """The WHOLE layer's files as one two-dispatch device batch; the engine
     drives ``pack:lane.*`` on ``stages``."""
@@ -855,11 +879,10 @@ def _lane_device(pack: _Pack, plan, arr, stages):
     # extents its table.
     from nydus_snapshotter_tpu.ops import fused_convert
 
-    engine = fused_convert.FusedDeviceEngine(
-        chunk_size=pack.opt.chunk_size, digester=pack.opt.digester
-    )
     try:
-        res = engine.process_many(fused_convert.Extents(arr, _extents(plan)), stages=stages)
+        res = _device_engine(pack.opt).process_many(
+            fused_convert.Extents(arr, _extents(plan)), stages=stages, begun=pack.begun
+        )
     except fused_convert.FusedOverflow as e:  # pathological input
         fused_convert.record_host_fallback()
         raise _LaneDeclined(str(e)) from e
@@ -1112,7 +1135,10 @@ def pack_stream(
     a ``convert.pack`` root (docs/observability.md): ``pack:dict_load``,
     ``pack:scan``, then the lane's (``pack:lane.*`` from the fused device
     engine, or one ``pack:chunk_digest`` / ``pack:fused_pack``),
-    ``pack:dedup``, ``pack:compress_write``, ``pack:bootstrap``.
+    ``pack:dedup``, ``pack:compress_write``, ``pack:bootstrap``. A pack
+    that leads to the device lane begins it first: ``pack:lane.layout``,
+    ``.h2d`` and a first ``.pass1`` (the upload and pass 1 enqueued) come
+    before ``pack:dict_load``, the wait for them after ``pack:scan``.
 
     ``stats``: optional dict that accumulates per-stage wall seconds, the
     sums of those spans' own times (``_STATS_SPANS``): ``scan`` tar walk +
@@ -1256,75 +1282,85 @@ def _pack_stream(dest, src_tar, opt, chunk_dict, stats, budget, codec, stages):
         # for room behind it (fused_convert.lane_buffer)
         arr = src_tar if isinstance(src_tar, np.ndarray) else np.frombuffer(raw, dtype=np.uint8)
 
-    if chunk_dict is None and opt.chunk_dict_path:
-        # service://<uds>[#namespace] connects a shared-dict mirror; any
-        # other shape is the file-based dict as before.
-        from nydus_snapshotter_tpu.parallel.dict_service import open_chunk_dict
+    # a pack that leads to the device lane opens pack:lane.{layout,h2d,pass1}
+    # here, ahead of its own host work; the lane's other leaves follow the scan
+    begun = _begin_device_lane(opt, arr, stages)
+    try:
+        if chunk_dict is None and opt.chunk_dict_path:
+            # service://<uds>[#namespace] connects a shared-dict mirror; any
+            # other shape is the file-based dict as before.
+            from nydus_snapshotter_tpu.parallel.dict_service import open_chunk_dict
 
-        stages.next("pack:dict_load")
-        chunk_dict = open_chunk_dict(opt.chunk_dict_path)
+            stages.next("pack:dict_load")
+            chunk_dict = open_chunk_dict(opt.chunk_dict_path)
+            stages.annotate(
+                dict_chunks=len(chunk_dict), dict_blobs=len(chunk_dict.blob_ids())
+            )
+        # everything up to the lane's own call is the scan: set-up, the member
+        # walk, the plan's extents
+        stages.next("pack:scan")
+        if codec is None:
+            codec = codec_mod.resolve_codec(opt)
+        out = _CountingWriter(dest)
+        compress = _make_compressor(opt.compressor, opt.lz4_acceleration, codec=codec)
+        if raw is not None and _deferred_section(opt, codec is not None, native_cdc):
+            section: "object" = _DeferredSectionWriter(out, opt, compress, raw)
+        else:
+            section = _SectionWriter(out, opt, compress)
+        chunker = IncrementalChunker(opt)
+        asm = _Assembler(section, chunk_dict)
+        queue = _DigestQueue(
+            _DeviceDigester(chunker.lookahead)
+            # the device batch kernel is SHA-256; blake3 always digests on the
+            # host blake3 arm (native/pure-Python), whatever the backend
+            if (opt.backend == "jax" or opt.digest_backend == "jax") and opt.digester == "sha256"
+            else _HostDigester(opt.digester),
+            asm,
+        )
+        pack = _Pack(opt, chunker, asm, queue, _pack_threads(), codec, budget, stats, begun)
+        metas, opaque_dirs, plan, n_members = _scan(src_tar, raw, opt, queue, chunker)
         stages.annotate(
-            dict_chunks=len(chunk_dict), dict_blobs=len(chunk_dict.blob_ids())
+            members=n_members,
+            files_planned=len(plan),
+            bytes_planned=sum(size for _m, _o, size in plan),
         )
-    # everything up to the lane's own call is the scan: set-up, the member
-    # walk, the plan's extents
-    stages.next("pack:scan")
-    if codec is None:
-        codec = codec_mod.resolve_codec(opt)
-    out = _CountingWriter(dest)
-    compress = _make_compressor(opt.compressor, opt.lz4_acceleration, codec=codec)
-    if raw is not None and _deferred_section(opt, codec is not None, native_cdc):
-        section: "object" = _DeferredSectionWriter(out, opt, compress, raw)
-    else:
-        section = _SectionWriter(out, opt, compress)
-    chunker = IncrementalChunker(opt)
-    asm = _Assembler(section, chunk_dict)
-    queue = _DigestQueue(
-        _DeviceDigester(chunker.lookahead)
-        # the device batch kernel is SHA-256; blake3 always digests on the
-        # host blake3 arm (native/pure-Python), whatever the backend
-        if (opt.backend == "jax" or opt.digest_backend == "jax") and opt.digester == "sha256"
-        else _HostDigester(opt.digester),
-        asm,
-    )
-    pack = _Pack(opt, chunker, asm, queue, _pack_threads(), codec, budget, stats)
-    metas, opaque_dirs, plan, n_members = _scan(src_tar, raw, opt, queue, chunker)
-    stages.annotate(
-        members=n_members,
-        files_planned=len(plan),
-        bytes_planned=sum(size for _m, _o, size in plan),
-    )
-    declined: list = []
-    while plan:
-        lane = choose_lane(
-            opt,
-            in_memory=raw is not None,
-            threads=pack.threads,
-            host_fused=chunker.fused,
-            has_dict=chunk_dict is not None,
-            codec_active=codec is not None,
-            seeded=bool(asm.own or asm.uoff or queue.pending or queue.in_flight),
-            declined=declined,
-        )
-        try:
-            # closing: an error in the walk ends the lane (its workers) now
-            with closing(lane(pack, plan, arr, stages)) as results:
-                for (meta, off, size), (cuts, digests) in zip(plan, results, strict=True):
-                    view = raw[off : off + size]
-                    start = 0
-                    batch = []
-                    for cut in cuts:
-                        cut = int(cut)
-                        batch.append((meta, view[start:cut]))
-                        start = cut
-                    if digests is None:
-                        for _meta, chunk in batch:
-                            queue.add(meta, chunk)
-                    elif batch:
-                        asm.process(batch, digests)
-            break
-        except _LaneDeclined:
-            declined.append(lane)
+        declined: list = []
+        while plan:
+            lane = choose_lane(
+                opt,
+                in_memory=raw is not None,
+                threads=pack.threads,
+                host_fused=chunker.fused,
+                has_dict=chunk_dict is not None,
+                codec_active=codec is not None,
+                seeded=bool(asm.own or asm.uoff or queue.pending or queue.in_flight),
+                declined=declined,
+            )
+            try:
+                # closing: an error in the walk ends the lane (its workers) now
+                with closing(lane(pack, plan, arr, stages)) as results:
+                    for (meta, off, size), (cuts, digests) in zip(plan, results, strict=True):
+                        view = raw[off : off + size]
+                        start = 0
+                        batch = []
+                        for cut in cuts:
+                            cut = int(cut)
+                            batch.append((meta, view[start:cut]))
+                            start = cut
+                        if digests is None:
+                            for _meta, chunk in batch:
+                                queue.add(meta, chunk)
+                        elif batch:
+                            asm.process(batch, digests)
+                break
+            except _LaneDeclined:
+                declined.append(lane)
+    finally:
+        if begun is not None:
+            # finished by _lane_device, or never (the scan raised, no file
+            # planned, another lane): nothing waits for what was enqueued,
+            # and it counted nowhere
+            begun.close()
     if stages.running != "pack:dedup":
         stages.next("pack:dedup")
     queue.drain()

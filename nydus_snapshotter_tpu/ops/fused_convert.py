@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
+from time import perf_counter
 
 import jax
 import jax.numpy as jnp
@@ -130,17 +131,34 @@ def _layout_copied_counter():
     )
 
 
+def _early_start_counter():
+    """Batches whose upload and pass 1 a caller had enqueued ahead of its
+    own host work (FusedDeviceEngine.begin) and that process_many then
+    finished; a begun lane that was dropped counts nowhere. Its own
+    accessor for the same reason as _row_floor_counter()."""
+    from nydus_snapshotter_tpu.metrics import registry as _metrics
+
+    return _metrics.default_registry.register(
+        _metrics.Counter(
+            "ntpu_fused_convert_early_starts_total",
+            "Fused batches begun by their caller ahead of its host work and finished",
+        )
+    )
+
+
 def _record_dispatch(
     n_bytes: int,
     stage_seconds: dict[str, float],
     row_floor_classes: int = 0,
     copied_bytes: int = 0,
+    early_start: bool = False,
 ) -> None:
     disp, by_bytes, busy, _ = _counters()
     disp.inc()
     by_bytes.inc(n_bytes)
     _row_floor_counter().inc(row_floor_classes)
     _layout_copied_counter().inc(copied_bytes)
+    _early_start_counter().inc(int(early_start))
     for stage, seconds in stage_seconds.items():
         busy.labels(stage).inc(seconds)
 
@@ -481,6 +499,48 @@ class Extents:
     table: list[tuple[int, int]]
 
 
+def _checked_table(streams: Extents, size: int) -> list[tuple[int, int]]:
+    """An Extents' table as ints, every extent inside its ``size``-byte
+    buffer: the device clamps a gather that leaves the buffer, so one that
+    does is refused here."""
+    table = [(int(off), int(length)) for off, length in streams.table]
+    if any(off < 0 or length < 0 or off + length > size for off, length in table):
+        raise ValueError(f"an extent lies outside its {size}-byte buffer")
+    return table
+
+
+@dataclass
+class Begun:
+    """A batch whose upload and pass 1 are enqueued and whose candidate
+    counts nobody has waited for: FusedDeviceEngine.begin's result and
+    process_many's ``begun``. Whoever began it closes it; a begun batch
+    that no process_many finishes was counted nowhere."""
+
+    data: object  # what it was begun on: process_many's Extents hold this very object
+    table: "list[tuple[int, int]] | None"  # None: a bare buffer, the extents come with process_many
+    n: int  # valid bytes of the buffer
+    copied: int  # bytes layout copied to build it
+    before: dict[str, float]  # the Stages' seconds at begin: the batch's own are what it adds
+    # the host buffer, alive and unwritten until the upload is done (None:
+    # a batch without a byte, nothing enqueued)
+    buf: "np.ndarray | None" = None
+    buffer_dev: "jax.Array | None" = None
+    words: tuple = ()  # _pass1's six outputs, still on the device
+    wcap_s: int = 0
+    wcap_l: int = 0
+    t0: float = 0.0  # perf_counter at the enqueue's start
+
+    def close(self) -> None:
+        """Free the device arrays now, in flight or not (the runtime lets
+        work that reads them finish: 1.6 ms for the call on the v5e, my
+        chip run, PR 32); nothing waits."""
+        if self.buffer_dev is not None:
+            for a in (self.buffer_dev, *self.words):
+                a.delete()
+        self.buf = self.buffer_dev = None
+        self.words = ()
+
+
 @dataclass(frozen=True)
 class FusedResult:
     """Per-stream chunk extents/digests + optional dict-probe hits."""
@@ -613,22 +673,27 @@ class FusedDeviceEngine:
 
     # -- execution -----------------------------------------------------------
 
-    def candidate_words(self, buffer_dev: jax.Array, n: int):
-        """Pass 1 on an already-device-resident buffer, up to its first
-        sync -> (sel_s, got_s, nw_s, sel_l, got_l, nw_l, wcap_s, wcap_l),
-        the word lists still on the device, the counts on the host."""
+    def enqueue_pass1(self, buffer_dev: jax.Array, n: int):
+        """Pass 1 called on an already-device-resident buffer and nothing
+        waited for -> (its six outputs, still on the device; wcap_s;
+        wcap_l). A first call of a new shape compiles here."""
         p = self.params
         wcap_s = _wcap_for(n, p.bits + 2)
         wcap_l = _wcap_for(n, p.bits - 2)
-        sel_s, got_s, nw_s, sel_l, got_l, nw_l = _pass1(
+        words = _pass1(
             buffer_dev, jnp.int32(n), p.mask_small, p.mask_large, wcap_s, wcap_l
         )
-        nw_s, nw_l = int(nw_s), int(nw_l)
+        return words, wcap_s, wcap_l
+
+    @staticmethod
+    def word_counts(words, wcap_s: int, wcap_l: int) -> tuple[int, int]:
+        """Pass 1's first sync: the candidate-word counts on the host."""
+        nw_s, nw_l = int(words[2]), int(words[5])
         if nw_s > wcap_s or nw_l > wcap_l:
             raise FusedOverflow(
                 f"candidate words {nw_s}/{nw_l} exceed caps {wcap_s}/{wcap_l}"
             )
-        return sel_s, got_s, nw_s, sel_l, got_l, nw_l, wcap_s, wcap_l
+        return nw_s, nw_l
 
     @staticmethod
     def candidate_positions(sel, got, nw: int, n: int) -> np.ndarray:
@@ -644,7 +709,9 @@ class FusedDeviceEngine:
 
     def candidates(self, buffer_dev: jax.Array, n: int) -> tuple[np.ndarray, np.ndarray]:
         """Pass 1 + candidate D2H on an already-device-resident buffer."""
-        sel_s, got_s, nw_s, sel_l, got_l, nw_l, _, _ = self.candidate_words(buffer_dev, n)
+        words, wcap_s, wcap_l = self.enqueue_pass1(buffer_dev, n)
+        nw_s, nw_l = self.word_counts(words, wcap_s, wcap_l)
+        sel_s, got_s, _, sel_l, got_l, _ = words
         return (
             self.candidate_positions(sel_s, got_s, nw_s, n),
             self.candidate_positions(sel_l, got_l, nw_l, n),
@@ -737,18 +804,20 @@ class FusedDeviceEngine:
             return blake3_jax.digest_to_bytes(state_row)
         return sha256.digest_to_bytes(state_row)
 
-    def _lay(self, streams: "list[bytes | np.ndarray] | Extents"):
+    def _lay(self, streams):
         """The layout stage -> (u8[padded_length] lane buffer, or None for
-        a batch without a byte; its [(offset, length)] table; the valid
-        bytes; the bytes copied to build it)."""
-        if isinstance(streams, Extents):
-            data = streams.data
+        a batch without a byte; its [(offset, length)] table, or None for
+        a bare buffer, whose extents come later; the valid bytes; the
+        bytes copied to build it)."""
+        if isinstance(streams, (Extents, bytes, bytearray, np.ndarray)):
+            data = streams.data if isinstance(streams, Extents) else streams
             arr = np.frombuffer(data, dtype=np.uint8) if isinstance(data, (bytes, bytearray)) else data
-            table = [(int(off), int(length)) for off, length in streams.table]
-            # the device clamps a gather that leaves the buffer: refuse here
-            if any(off < 0 or length < 0 or off + length > arr.size for off, length in table):
-                raise ValueError(f"an extent lies outside its {arr.size}-byte buffer")
-            if not any(length for _off, length in table):
+            if isinstance(streams, Extents):
+                table = _checked_table(streams, arr.size)
+                empty = not any(length for _off, length in table)
+            else:
+                table, empty = None, not arr.size
+            if empty:
                 return None, table, 0, 0
             buf, copied = lane_buffer(arr, padded_length(arr.size, self.params.max_size))
             return buf, table, arr.size, copied
@@ -762,6 +831,42 @@ class FusedDeviceEngine:
         buf, table = self.layout(arrs)
         return buf, table, n, n
 
+    def begin(self, streams, stages) -> "Begun":
+        """The lane's first half: the buffer made ready, its upload and
+        pass 1 ENQUEUED, nothing waited for. Neither needs a file table,
+        so a caller that has the layer in memory begins here, does the
+        host work that needs nothing from the device (parse a dictionary,
+        walk the tar's members), and hands the result to process_many as
+        ``begun`` with the extents it found meanwhile.
+
+        ``streams``: what process_many takes, or the bare buffer (bytes,
+        bytearray, u8 array) that the Extents will be over. ``stages``: the
+        running ``trace.Stages`` to open ``pack:lane.layout``, ``.h2d`` and
+        the first ``.pass1`` on; the last is left running."""
+        from nydus_snapshotter_tpu import failpoint
+
+        # Device batch boundary: chaos-testable (an injected error
+        # propagates — callers redo a batch on the host lanes only for
+        # FusedOverflow, and count it).
+        failpoint.hit("fused.dispatch")
+        before = dict(stages.seconds)  # it sums by name over all it ran
+        stages.next("pack:lane.layout")
+        buf, table, n, copied = self._lay(streams)
+        data = streams.data if isinstance(streams, Extents) else streams
+        if buf is None:
+            return Begun(data, table, 0, 0, before)
+        stages.annotate(bytes=n, padded_bytes=int(buf.size), copied_bytes=copied)
+        # committed to the default device. From here to word_counts these
+        # two leaves are the host's side of asynchronous device work: the
+        # call that enqueues it, not the work.
+        t0 = stages.next("pack:lane.h2d", bytes=int(buf.size)).t0
+        buffer_dev = jnp.asarray(buf)
+        # a first call of a new buffer length compiles here
+        stages.next("pack:lane.pass1")
+        words, wcap_s, wcap_l = self.enqueue_pass1(buffer_dev, n)
+        stages.annotate(wcap_s=wcap_s, wcap_l=wcap_l)
+        return Begun(data, table, n, copied, before, buf, buffer_dev, words, wcap_s, wcap_l, t0)
+
     def process_many(
         self,
         streams: "list[bytes | np.ndarray] | Extents",
@@ -770,6 +875,7 @@ class FusedDeviceEngine:
         probe_kernel: str = "auto",
         dict_epoch: int | None = None,
         stages=None,
+        begun: "Begun | None" = None,
     ) -> FusedResult:
         """``streams``: separate byte strings, which layout() copies back to
         back into a fresh buffer, or an Extents: streams that already lie
@@ -777,38 +883,49 @@ class FusedDeviceEngine:
         lane from the upload on, same result for the same streams.
 
         ``stages``: the caller's running ``trace.Stages`` (a pack's) to
-        drive ``pack:lane.*`` on, the last one closed; its own if None."""
-        from nydus_snapshotter_tpu import failpoint, trace
+        drive ``pack:lane.*`` on, the last one closed; its own if None.
 
-        # Device batch boundary: chaos-testable (an injected error
-        # propagates — callers redo a batch on the host lanes only for
-        # FusedOverflow, and count it) and timed so the host-arm
-        # scheduling around the two dispatches is visible next to the
-        # pipeline's stage counters.
-        failpoint.hit("fused.dispatch")
+        ``begun``: begin()'s result for the very buffer ``streams`` is an
+        Extents over, on the same ``stages`` (the caller's to close()).
+        Without one the batch is begun here: the same code, in the old
+        order."""
+        from nydus_snapshotter_tpu import trace
+
         # One span a stage, consecutive (trace.Stages): a span's enter/exit
         # is the only clock read at its boundary, and the stage counters
         # and the caller's stats are fed from the spans' own seconds.
         with (trace.Stages() if stages is None else stages) as lane:
-            before = dict(lane.seconds)  # it sums by name over all it ran
-            lane.next("pack:lane.layout")
-            buf, table, n, copied = self._lay(streams)
-            if buf is None:
+            early = begun is not None
+            if not early:
+                begun = self.begin(streams, lane)
+                table = begun.table
+            elif isinstance(streams, Extents) and streams.data is begun.data:
+                table = _checked_table(streams, begun.n)
+            else:
+                raise ValueError("the lane was begun on another buffer than these extents lie in")
+            n, buffer_dev = begun.n, begun.buffer_dev
+            if buffer_dev is None or not any(length for _off, length in table):
                 return FusedResult(
                     cuts=[np.asarray([], dtype=np.int64) for _ in table],
                     digests=[[] for _ in table],
                     probe=np.zeros(0, np.int32) if chunk_dict is not None else None,
                 )
-            lane.annotate(bytes=n, padded_bytes=int(buf.size), copied_bytes=copied)
-            # committed to the default device; blocked on so the upload is
-            # its own stage (pass 1 needs the whole buffer before it starts)
-            lane.next("pack:lane.h2d", bytes=int(buf.size))
-            buffer_dev = jax.block_until_ready(jnp.asarray(buf))
+            # the wait for what begin enqueued. covered_s: what of window_s
+            # (the enqueue's start to the counts on the host) the caller
+            # spent in stages of its own, not waiting in the lane's
             lane.next("pack:lane.pass1")
-            sel_s, got_s, nw_s, sel_l, got_l, nw_l, wcap_s, wcap_l = self.candidate_words(
-                buffer_dev, n
+            nw_s, nw_l = self.word_counts(begun.words, begun.wcap_s, begun.wcap_l)
+            lane.annotate(
+                words_s=nw_s,
+                words_l=nw_l,
+                window_s=perf_counter() - begun.t0,
+                covered_s=sum(
+                    s - begun.before.get(name, 0.0)
+                    for name, s in lane.seconds.items()
+                    if not name.startswith("pack:lane.")
+                ),
             )
-            lane.annotate(wcap_s=wcap_s, wcap_l=wcap_l, words_s=nw_s, words_l=nw_l)
+            sel_s, got_s, _, sel_l, got_l, _ = begun.words
             lane.next("pack:lane.cand_d2h")
             cand_s = self.candidate_positions(sel_s, got_s, nw_s, n)
             cand_l = self.candidate_positions(sel_l, got_l, nw_l, n)
@@ -867,7 +984,7 @@ class FusedDeviceEngine:
             for f_cuts in cuts:
                 out_digests.append(flat_digests[pos : pos + len(f_cuts)])
                 pos += len(f_cuts)
-        took = {name: s - before.get(name, 0.0) for name, s in lane.seconds.items()}
+        took = {name: s - begun.before.get(name, 0.0) for name, s in lane.seconds.items()}
         _record_dispatch(
             n,
             {
@@ -879,6 +996,7 @@ class FusedDeviceEngine:
                 "digest_d2h": took["pack:lane.digest_d2h"],
             },
             row_floor_classes=floored_classes,
-            copied_bytes=copied,
+            copied_bytes=begun.copied,
+            early_start=early,
         )
         return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np)

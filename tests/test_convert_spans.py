@@ -23,13 +23,17 @@ from nydus_snapshotter_tpu.converter.types import MergeOption, PackOption
 from nydus_snapshotter_tpu.ops import fused_convert, native_cdc
 
 CHUNK = 0x10000
-LANE = [f"pack:lane.{s}" for s in ("layout", "h2d", "pass1", "cand_d2h", "resolve", "plan", "pass2", "digest_d2h")]
+# the device lane starts when the layer is read: its first half (the upload
+# and pass 1 enqueued) comes before the dictionary and the scan, the wait
+# for pass 1 and the rest after them
+LANE_BEGIN = [f"pack:lane.{s}" for s in ("layout", "h2d", "pass1")]
+LANE = [f"pack:lane.{s}" for s in ("pass1", "cand_d2h", "resolve", "plan", "pass2", "digest_d2h")]
 TAIL = ["pack:dedup", "pack:compress_write", "pack:bootstrap"]
 WHOLE_LAYER = "pack:fused_pack" if native_cdc.pack_files_available() else "pack:chunk_digest"
 # the leaves of one `pack`, in order, by (backend, with a chunk dict)
 PACK_LEAVES = {
-    ("fused", False): ["pack:read", "pack:open_out", "pack:scan", *LANE, *TAIL],
-    ("fused", True): ["pack:read", "pack:open_out", "pack:dict_load", "pack:scan", *LANE, *TAIL],
+    ("fused", False): ["pack:read", "pack:open_out", *LANE_BEGIN, "pack:scan", *LANE, *TAIL],
+    ("fused", True): ["pack:read", "pack:open_out", *LANE_BEGIN, "pack:dict_load", "pack:scan", *LANE, *TAIL],
     # one thread: the whole-layer native pass without a dictionary (where
     # the native engine is built; the per-file lane where it is not), the
     # chunk+digest sweep and the Python dedup lane with one
@@ -117,7 +121,9 @@ def test_pack_leaf_names_are_the_tables(work, backend, with_dict):
     assert not root.parent_id and root.batch
     assert [s.name for s in leaves] == PACK_LEAVES[backend, with_dict]
     assert len(leaves) + 1 <= 24
-    attrs = {s.name: s.attrs for s in leaves}
+    attrs = {}
+    for s in leaves:  # the two pack:lane.pass1 leaves' attributes side by side
+        attrs.setdefault(s.name, {}).update(s.attrs)
     assert attrs["pack:read"]["bytes"] == os.path.getsize(work / "a.tar")
     assert attrs["pack:scan"]["members"] == 20 and attrs["pack:scan"]["files_planned"] == 20
     assert attrs["pack:dedup"]["chunks"] >= attrs["pack:dedup"]["unique"] > 0
@@ -134,6 +140,18 @@ def test_pack_leaf_names_are_the_tables(work, backend, with_dict):
         assert 0 < plan["blocks_real"] <= plan["blocks_padded"]
         assert sum(rows for _cap, rows, _padded in plan["classes"]) == attrs["pack:lane.digest_d2h"]["chunks"]
         assert attrs["pack:lane.pass2"]["programs_after"] >= attrs["pack:lane.pass2"]["programs_before"]
+        # the first pack:lane.pass1 is the call, the second the wait: window_s runs from the
+        # upload's enqueue to the counts on the host, covered_s is what of it the host spent
+        # in pack:dict_load and pack:scan
+        call, wait = [s for s in leaves if s.name == "pack:lane.pass1"]
+        assert set(call.attrs) == {"wcap_s", "wcap_l"}
+        assert set(wait.attrs) == {"words_s", "words_l", "window_s", "covered_s"}
+        between = [s for s in leaves if call.t0 < s.t0 < wait.t0]
+        assert [s.name for s in between] == ["pack:dict_load", "pack:scan"][not with_dict:]
+        assert wait.attrs["covered_s"] == pytest.approx(sum(s.seconds for s in between), abs=1e-6)
+        h2d = next(s for s in leaves if s.name == "pack:lane.h2d")
+        assert 0 < wait.attrs["covered_s"] <= wait.attrs["window_s"] <= wait.t1 - h2d.t0
+        assert wait.attrs["window_s"] >= wait.t0 - h2d.t0
 
 
 @pytest.mark.parametrize("backend,with_dict", CASES)
@@ -197,11 +215,27 @@ def test_stage_counters_are_the_spans_own_seconds(work, enabled):
     if not enabled:
         assert trace.snapshot_spans() == []
         return
-    took = {s.name.removeprefix("pack:lane."): s.seconds for s in tree("convert.pack")[1]}
+    took = {}
+    for s in tree("convert.pack")[1]:  # pass1 is two leaves, the call and the wait
+        took[s.name.removeprefix("pack:lane.")] = took.get(s.name.removeprefix("pack:lane."), 0.0) + s.seconds
     want = {"layout": took["layout"], "h2d": took["h2d"], "pass1_gear": took["pass1"] + took["cand_d2h"],
             "host_resolve": took["resolve"] + took["plan"], "pass2_digest": took["pass2"],
             "digest_d2h": took["digest_d2h"]}
     assert delta == pytest.approx(want, abs=1e-6)
+
+
+@pytest.mark.parametrize("backend,with_dict", CASES)
+def test_early_start_counter_rises_by_one_a_fused_pack(work, backend, with_dict):
+    """Every served fused pack begins its lane before the dictionary and the
+    scan and finishes it: one early start a dispatch, none on the host lanes."""
+    if with_dict:
+        dict_boot(work)
+    early, dispatches = fused_convert._early_start_counter(), fused_convert._counters()[0]
+    before = early.value(), dispatches.value()
+    pack(work, backend, with_dict=with_dict)
+    pack(work, backend, "many", with_dict)
+    want = 2 if backend == "fused" else 0
+    assert (early.value() - before[0], dispatches.value() - before[1]) == (want, want)
 
 
 def test_plan_span_counts_the_row_floor_and_the_rows_dispatched(work, monkeypatch):

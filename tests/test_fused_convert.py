@@ -257,6 +257,17 @@ def _edge_tar() -> tuple[bytes, list[tuple[int, int]]]:
     return tar[:end], extents
 
 
+def _every_other_chunk(eng, streams):
+    """-> (a chunk dictionary of every other chunk of the batch, its probe
+    depth): hits and misses for process_many(streams, chunk_dict=...)."""
+    flat = [d for digs in eng.process_many(streams).digests for d in digs][::2]
+    words = "<u4" if eng.digester == "blake3" else ">u4"
+    keys, values = _build_host_tables(
+        np.frombuffer(b"".join(flat), dtype=words).astype(np.uint32).reshape(-1, 8), 1
+    )
+    return (keys[0], values[0]), _table_max_depth(keys, values)
+
+
 class TestExtentsEntry:
     """process_many(Extents(tar, extents)) is process_many(the members' slices):
     the tar is the lane's buffer and the plan's extents are its table,
@@ -283,15 +294,7 @@ class TestExtentsEntry:
         tar, extents = _edge_tar()
         eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL, digester=digester)
         streams = [tar[off : off + size] for off, size in extents]
-        chunk_dict, depth = None, 8
-        if with_dict:
-            # a dictionary of every other chunk of the batch: hits and misses
-            flat = [d for digs in eng.process_many(streams).digests for d in digs][::2]
-            words = "<u4" if digester == "blake3" else ">u4"
-            keys, values = _build_host_tables(
-                np.frombuffer(b"".join(flat), dtype=words).astype(np.uint32).reshape(-1, 8), 1
-            )
-            chunk_dict, depth = (keys[0], values[0]), _table_max_depth(keys, values)
+        chunk_dict, depth = _every_other_chunk(eng, streams) if with_dict else (None, 8)
         want = eng.process_many(streams, chunk_dict=chunk_dict, depth=depth)
 
         npad = fused_convert.padded_length(len(tar), eng.params.max_size)
@@ -580,3 +583,123 @@ class TestFusedRandomizedSoak:
         )
         np.testing.assert_array_equal(res_pl.probe, res_xla.probe)
         assert (res_pl.probe[: len(res_pl.digests[0])] > 0).all()
+
+
+class TestEarlyStart:
+    """begin() enqueues the upload and pass 1 on a bare buffer, before any
+    file table exists; process_many(Extents, begun=...) waits for them and
+    runs the rest. Same cuts, digests and probe as process_many(Extents)
+    alone, one entry, one dispatch counted."""
+
+    @staticmethod
+    def _counts() -> tuple[float, float, float]:
+        disp, _bytes, _stages, fallbacks = fused_convert._counters()
+        return disp.value(), fused_convert._early_start_counter().value(), fallbacks.value()
+
+    @pytest.mark.parametrize("room", ["slack", "bytes"])
+    @pytest.mark.parametrize("with_dict", [False, True])
+    @pytest.mark.parametrize("digester", ["sha256", "blake3"])
+    def test_begun_matches_process_many(self, digester, with_dict, room):
+        from nydus_snapshotter_tpu import trace
+
+        tar, extents = _edge_tar()
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL, digester=digester)
+        if room == "bytes":
+            data = tar
+        else:
+            big = fused_convert.zeroed_buffer(fused_convert.padded_length(len(tar), eng.params.max_size))
+            big[: len(tar)] = np.frombuffer(tar, dtype=np.uint8)
+            data = big[: len(tar)]
+        chunk_dict, depth = _every_other_chunk(eng, fused_convert.Extents(data, extents)) if with_dict else (None, 8)
+        want = eng.process_many(fused_convert.Extents(data, extents), chunk_dict=chunk_dict, depth=depth)
+
+        before = self._counts()
+        with trace.Stages() as stages:
+            begun = eng.begin(data, stages)  # no table yet
+            assert begun.table is None and begun.n == len(tar) and stages.running == "pack:lane.pass1"
+            stages.next("pack:scan")  # the caller's own work, under the device's
+            got = eng.process_many(
+                fused_convert.Extents(data, extents), chunk_dict=chunk_dict, depth=depth,
+                stages=stages, begun=begun,
+            )
+            begun.close()
+        assert [b - a for a, b in zip(before, self._counts())] == [1, 1, 0]
+        assert list(stages.seconds) == [f"pack:lane.{s}" for s in ("layout", "h2d", "pass1")] + ["pack:scan"] + [
+            f"pack:lane.{s}" for s in ("cand_d2h", "resolve", "plan", "pass2", "digest_d2h")
+        ]
+        for i, (g, w) in enumerate(zip(got.cuts, want.cuts, strict=True)):
+            np.testing.assert_array_equal(g, w, err_msg=f"file {i}")
+        assert got.digests == want.digests
+        if with_dict:
+            np.testing.assert_array_equal(got.probe, want.probe)
+            assert (got.probe > 0).any() and (got.probe == 0).any()
+        else:
+            assert got.probe is None
+
+    @pytest.mark.parametrize("other", ["an equal copy", "a list of the members", "bytes of the array"])
+    def test_a_lane_begun_on_another_buffer_is_refused(self, other):
+        tar, extents = _edge_tar()
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL)
+        arr = np.frombuffer(tar, dtype=np.uint8)
+        streams = {
+            "an equal copy": fused_convert.Extents(arr.copy(), extents),
+            "a list of the members": [tar[off : off + size] for off, size in extents],
+            "bytes of the array": fused_convert.Extents(tar, extents),
+        }[other]
+        from nydus_snapshotter_tpu import trace
+
+        before = self._counts()
+        with trace.Stages() as stages:
+            begun = eng.begin(arr, stages)
+            with pytest.raises(ValueError, match="begun on another buffer"):
+                eng.process_many(streams, stages=stages, begun=begun)
+            begun.close()
+        assert self._counts() == before
+
+    def test_a_begun_lane_that_is_dropped_counts_nowhere_and_waits_for_nothing(self):
+        from nydus_snapshotter_tpu import trace
+
+        tar, extents = _edge_tar()
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL)
+        before = self._counts()
+        with trace.Stages() as stages:
+            begun = eng.begin(tar, stages)
+            dev, words = begun.buffer_dev, begun.words
+            assert len(words) == 6 and not dev.is_deleted()
+            begun.close()
+            begun.close()  # whoever comes second finds nothing left
+        assert dev.is_deleted() and all(w.is_deleted() for w in words)
+        assert begun.buf is None and begun.buffer_dev is None and begun.words == ()
+        assert self._counts() == before
+        # and the next batch is none the worse for it
+        got = eng.process_many(fused_convert.Extents(tar, extents))
+        assert sum(len(c) for c in got.cuts) > len(extents)
+
+    def test_overflow_is_met_where_the_counts_arrive(self, monkeypatch):
+        from nydus_snapshotter_tpu import trace
+
+        monkeypatch.setattr(fused_convert, "_wcap_for", lambda n, bits, floor=1024: 2)
+        eng = fused_convert.FusedDeviceEngine(chunk_size=CHUNK)
+        data = _corpus(23, [1 << 20])[0]
+        before = self._counts()
+        with trace.Stages() as stages:
+            begun = eng.begin(data, stages)  # enqueues: the counts are not known yet
+            with pytest.raises(fused_convert.FusedOverflow, match="exceed caps 2/2"):
+                eng.process_many(fused_convert.Extents(data, [(0, len(data))]), stages=stages, begun=begun)
+            begun.close()
+        assert self._counts() == before  # the caller counts the fallback, not the engine
+
+    @pytest.mark.parametrize("table", [[], [(0, 0), (0, 0)]])
+    def test_nothing_to_cut_waits_for_nothing(self, table):
+        from nydus_snapshotter_tpu import trace
+
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL)
+        for data in (b"", bytes(10_000)):
+            before = self._counts()
+            with trace.Stages() as stages:
+                begun = eng.begin(data, stages)
+                assert (begun.buffer_dev is None) == (not data)
+                got = eng.process_many(fused_convert.Extents(data, table), stages=stages, begun=begun)
+                begun.close()
+            assert [list(c) for c in got.cuts] == [[] for _ in table] and got.probe is None
+            assert "pack:lane.cand_d2h" not in stages.seconds and self._counts() == before

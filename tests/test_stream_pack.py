@@ -385,3 +385,158 @@ class TestSparseMemberFusedGate:
         with tarfile.open(fileobj=io.BytesIO(back)) as tf:
             got = tf.extractfile("sparse.bin").read()
         assert got == content
+
+
+class TestEarlyDeviceLaneStart:
+    """pack_stream begins the device lane as soon as it has the layer in
+    memory, before the dictionary and the scan; choose_lane still chooses.
+    A begun lane that nothing finishes is closed: the pack is the one the
+    host lanes give (or fails with their error), and nothing was counted."""
+
+    CHUNK = 0x10000
+
+    @staticmethod
+    def _counts() -> dict:
+        from nydus_snapshotter_tpu.ops import fused_convert
+
+        disp, _bytes, _stages, fallbacks = fused_convert._counters()
+        return {"dispatches": disp.value(), "early_starts": fused_convert._early_start_counter().value(),
+                "host_fallbacks": fallbacks.value()}
+
+    def _moved(self, before: dict) -> dict:
+        return {k: v - before[k] for k, v in self._counts().items() if v != before[k]}
+
+    @pytest.fixture
+    def closed(self, monkeypatch):
+        """The lanes that were begun in the test, to ask each whether it was closed."""
+        from nydus_snapshotter_tpu.ops import fused_convert
+
+        begun = []
+        begin = fused_convert.FusedDeviceEngine.begin
+
+        def spy(self, streams, stages):
+            begun.append(begin(self, streams, stages))
+            return begun[-1]
+
+        monkeypatch.setattr(fused_convert.FusedDeviceEngine, "begin", spy)
+        return begun
+
+    def _layer(self, seed=53) -> bytes:
+        """Four files; the first and the last are the same for every seed."""
+        shared, own = np.random.default_rng(53), np.random.default_rng(seed)
+        return build_tar([(f"e/f{i}", (shared if i in (0, 3) else own).integers(0, 256, size, dtype=np.uint8).tobytes())
+                          for i, size in enumerate([300_000, 1_200, 450_000, 70_000])])
+
+    def _pack(self, tar, backend, **kw):
+        return pack_layer(tar, PackOption(chunk_size=self.CHUNK, backend=backend, **kw))
+
+    def test_a_fused_pack_begins_early_and_finishes_once(self, closed):
+        tar = self._layer()
+        want, want_res = self._pack(tar, "hybrid")
+        before = self._counts()
+        got, got_res = self._pack(tar, "fused")
+        assert got == want and got_res.bootstrap == want_res.bootstrap
+        assert self._moved(before) == {"dispatches": 1, "early_starts": 1}
+        (begun,) = closed  # one begin a pack: process_many did not begin again
+        assert begun.table is None and begun.buffer_dev is None
+
+    def test_a_tar_without_a_regular_file_drops_the_begun_lane(self, closed):
+        buf = io.BytesIO()
+        with tarfile.open(fileobj=buf, mode="w") as tf:
+            for name, kind, link in [("d", tarfile.DIRTYPE, ""), ("d/e", tarfile.DIRTYPE, ""),
+                                     ("d/l", tarfile.SYMTYPE, "e"), ("d/empty", tarfile.REGTYPE, "")]:
+                info = tarfile.TarInfo(name)
+                info.type, info.linkname = kind, link
+                tf.addfile(info)
+        tar = buf.getvalue()
+        want, want_res = self._pack(tar, "hybrid")
+        before = self._counts()
+        got, got_res = self._pack(tar, "fused")
+        assert got == want and got_res.bootstrap == want_res.bootstrap
+        assert self._moved(before) == {}
+        (begun,) = closed
+        assert begun.n == len(tar) and begun.buffer_dev is None  # was enqueued, is closed
+
+    def test_a_corrupt_tar_fails_as_on_the_host_lane(self, closed):
+        from nydus_snapshotter_tpu.converter.types import ConvertError
+
+        tar = bytearray(self._layer())
+        tar[:512] = b"\xff" * 512  # the first member's header: no walk gets past it
+        errors = {}
+        before = self._counts()
+        for backend in ("hybrid", "fused"):
+            with pytest.raises(ConvertError) as e:
+                self._pack(bytes(tar), backend)
+            errors[backend] = str(e.value)
+        assert errors["fused"] == errors["hybrid"]
+        assert self._moved(before) == {}
+        (begun,) = closed  # the hybrid pack begins nothing
+        assert begun.n == len(tar) and begun.buffer_dev is None
+
+    def test_another_lane_after_all_drops_the_begun_lane(self, closed, monkeypatch):
+        from nydus_snapshotter_tpu.converter import stream
+
+        tar = self._layer()
+        want, want_res = self._pack(tar, "hybrid")
+        ran = []
+
+        def choose(option, **seen):
+            ran.append(stream._lane_per_file)
+            return stream._lane_per_file
+
+        monkeypatch.setattr(stream, "choose_lane", choose)
+        before = self._counts()
+        got, got_res = self._pack(tar, "fused")
+        assert got == want and got_res.bootstrap == want_res.bootstrap
+        assert ran == [stream._lane_per_file] and self._moved(before) == {}
+        (begun,) = closed
+        assert begun.n == len(tar) and begun.buffer_dev is None
+
+    @pytest.mark.parametrize("where", ["begin", "process_many", "the counts"])
+    def test_a_planted_overflow_counts_one_fallback(self, closed, monkeypatch, where):
+        from nydus_snapshotter_tpu.ops import fused_convert
+
+        tar = self._layer()
+        want, want_res = self._pack(tar, "hybrid")
+        if where == "begin":  # no lane buffer holds the layer: begin and process_many both meet it
+            def refuse(total, max_size):
+                raise fused_convert.FusedOverflow("planted")
+
+            monkeypatch.setattr(fused_convert, "padded_length", refuse)
+        elif where == "process_many":  # benchmark/tests/test_faults.py's plant
+            def overflow(self, streams, *a, **kw):
+                raise fused_convert.FusedOverflow("planted")
+
+            monkeypatch.setattr(fused_convert.FusedDeviceEngine, "process_many", overflow)
+        else:
+            monkeypatch.setattr(fused_convert, "_wcap_for", lambda n, bits, floor=1024: 2)
+        before = self._counts()
+        got, got_res = self._pack(tar, "fused")
+        assert got == want and got_res.bootstrap == want_res.bootstrap
+        assert self._moved(before) == {"host_fallbacks": 1}
+        assert all(b.buffer_dev is None for b in closed) and len(closed) == (0 if where == "begin" else 1)
+
+    @pytest.mark.parametrize("with_dict", [False, True])
+    def test_cli_pack_fused_is_byte_identical_to_hybrid(self, tmp_path, with_dict):
+        from nydus_snapshotter_tpu.cmd import convert as cli
+
+        def run(*argv):
+            assert cli.main(["--jax-platform", "cpu", *argv]) == 0
+
+        (tmp_path / "a.tar").write_bytes(self._layer(53))
+        (tmp_path / "b.tar").write_bytes(self._layer(54))
+        extra = []
+        if with_dict:  # image b shares two files with a
+            run("pack", "--in", str(tmp_path / "b.tar"), "--out", str(tmp_path / "b.nydus"), "--backend", "hybrid",
+                "--chunk-size", hex(self.CHUNK))
+            run("merge", "--out", str(tmp_path / "b.boot"), str(tmp_path / "b.nydus"))
+            extra = ["--chunk-dict", str(tmp_path / "b.boot")]
+        blobs = {}
+        before = self._counts()
+        for backend in ("hybrid", "fused"):
+            out = tmp_path / f"a.{backend}.nydus"
+            run("pack", "--in", str(tmp_path / "a.tar"), "--out", str(out), "--backend", backend,
+                "--chunk-size", hex(self.CHUNK), *extra)
+            blobs[backend] = out.read_bytes()
+        assert blobs["fused"] == blobs["hybrid"]
+        assert self._moved(before) == {"dispatches": 1, "early_starts": 1}
