@@ -21,7 +21,11 @@ on device ONCE and never comes back:
   kernel), SHA-256 padding applied with iota masks on device, the
   measured ``sha256_batch`` scan, and the chunk-dict probe
   (parallel/sharded_dict._probe_local) over every digest. D2H is
-  32 B/chunk of digests + 4 B/chunk of dict hits.
+  32 B/chunk of digests + 4 B/chunk of dict hits. A class is gathered
+  and digested as ONE batch of rows while that batch stays within
+  TILE_BYTES; a wider one runs as **row tiles**: equal power-of-two
+  slices of its rows, one after the other through one compiled loop
+  body, so that pass 2's HBM follows the budget and not the layer.
 
 Why two dispatches and not one: the digest stage's shapes depend on the
 resolved cuts. Keeping resolution on device would make bucket geometry
@@ -117,6 +121,20 @@ def _row_floor_counter():
     )
 
 
+def _row_tiles_counter():
+    """Row tiles beyond the first that pass 2 ran its classes in
+    (class_rows): 0 for a batch whose every class fit TILE_BYTES. Its own
+    accessor for the same reason as _row_floor_counter()."""
+    from nydus_snapshotter_tpu.metrics import registry as _metrics
+
+    return _metrics.default_registry.register(
+        _metrics.Counter(
+            "ntpu_fused_convert_row_tiles_total",
+            "Row tiles beyond one that fused batches' digest classes were split into",
+        )
+    )
+
+
 def _layout_copied_counter():
     """Bytes the layout stage copied on the host to build a lane buffer:
     0 for a batch whose input already was one (lane_buffer). Its own
@@ -152,11 +170,13 @@ def _record_dispatch(
     row_floor_classes: int = 0,
     copied_bytes: int = 0,
     early_start: bool = False,
+    row_tiles: int = 0,
 ) -> None:
     disp, by_bytes, busy, _ = _counters()
     disp.inc()
     by_bytes.inc(n_bytes)
     _row_floor_counter().inc(row_floor_classes)
+    _row_tiles_counter().inc(row_tiles)
     _layout_copied_counter().inc(copied_bytes)
     _early_start_counter().inc(int(early_start))
     for stage, seconds in stage_seconds.items():
@@ -171,6 +191,10 @@ def record_host_fallback() -> None:
 
 def _pow2_ceil(n: int) -> int:
     return 1 << (n - 1).bit_length() if n > 1 else 1
+
+
+def _pow2_floor(n: int) -> int:
+    return 1 << (n.bit_length() - 1) if n >= 1 else 0
 
 
 # The fewest rows a digest class is dispatched with. A digest batch costs
@@ -190,9 +214,49 @@ ROW_FLOOR = 2
 def bucket_rows(live: int) -> int:
     """Rows a digest class holding ``live`` chunks is dispatched with:
     the next power of two, and never fewer than ROW_FLOOR. The one rule
-    for the row axis of every pass-2 batch (plan_buckets here, the
-    per-device rows of ops/mesh_pack.plan_mesh_pack)."""
+    for the row axis of every pass-2 batch: plan_buckets takes it through
+    class_rows, which leaves a batch within TILE_BYTES as this gives it
+    and runs a wider one in tiles; the per-device rows of
+    ops/mesh_pack.plan_mesh_pack are these, untiled (no served verb
+    reaches a mesh)."""
     return max(ROW_FLOOR, _pow2_ceil(live))
+
+
+# The most bytes of gathered blocks (rows x the bytes a row gathers) that
+# pass 2 holds as ONE batch; a wider class runs in row tiles (class_rows).
+# The chip's compiler lays a batch u32[rows, cap_blocks, 16] out eightfold
+# (16 words on 128 lanes), so _pass2's temporaries are ten bytes a byte of
+# its widest batch: for one class of 32,768 blocks in a 1,280 MiB buffer
+# 2,578 / 5,122 / 10,242 MiB at 128 / 256 / 512 rows, and at 1,024 rows,
+# which bucket_rows gives the 576 chunks of 1-2 MiB in the jax / jaxlib /
+# libtpu layer of a training image, RESOURCE_EXHAUSTED: 18.0 of 15.75 GiB
+# (my chip runs, PR 33; tiled or as one batch, the same to the MiB). So
+# 512 MiB, 256 such rows: the largest power of two whose temporaries with
+# that buffer (6.3 GiB) stay under half the HBM, a dictionary's table or a
+# wider buffer beside them, and the smallest that leaves every layer the
+# benchmark had before as it was (node:21 at 64 KiB chunks: 4,096 rows x
+# 2,048 blocks = 512 MiB, one batch). Time does not ask for more: a digest
+# step takes 2.66 / 3.75 / 4.98 us at 128 / 256 / 512 rows, and the
+# gather, 1.9 ms a row of 2 MiB, a padding row like a live one, is most of
+# a tile (0.334 / 0.602 / 1.136 s), so the 576 chunks take 1.66 / 1.80 /
+# 2.27 s in 5 x 128 / 3 x 256 / 2 x 512 rows (PERF.md section 6).
+TILE_BYTES = 512 << 20
+
+
+def class_rows(live: int, row_bytes: int) -> tuple[int, int]:
+    """-> (rows, tile_rows) of a digest class holding ``live`` chunks whose
+    rows gather ``row_bytes`` each. While bucket_rows(live) rows stay
+    within TILE_BYTES the class is one batch of them (tile_rows == rows):
+    the rule the row axis had before there were tiles. A wider class is
+    run in tiles of the largest power-of-two row count within the budget,
+    as many as its chunks need and no more: ceil(live / tile_rows) of
+    them, not the next power of two of its rows."""
+    rows = bucket_rows(live)
+    if rows * row_bytes <= TILE_BYTES:
+        return rows, rows
+    # never under the floor, though one row alone be over the budget
+    tile = max(ROW_FLOOR, _pow2_floor(TILE_BYTES // row_bytes))
+    return -(-live // tile) * tile, tile
 
 
 def padded_length(total: int, max_size: int) -> int:
@@ -210,8 +274,11 @@ def padded_length(total: int, max_size: int) -> int:
     step = max(WINDOW, _pow2_ceil(npad) // 8)
     npad = -(-npad // step) * step
     # Device ints are 32-bit (no x64): pass-2 chunk offsets must
-    # address the buffer with int32. A batch is one layer (far below
-    # this as a rule); a caller with more splits it below 2 GiB.
+    # address the buffer with int32. A batch is one layer: 640 MiB for
+    # half of node:21, 1,280 MiB for the jax/jaxlib/libtpu layer of a
+    # training image (benchmark/configs/mlimage-1m.json), and a layer
+    # with one file of a GiB pads past this; a caller with more splits
+    # it below 2 GiB.
     if npad >= 1 << 31:
         raise FusedOverflow(
             f"batch of {total} bytes pads to {npad} — beyond int32 "
@@ -351,10 +418,15 @@ def _wcap_for(n: int, density_bits: int, floor: int = 1024) -> int:
 class Bucket:
     """One power-of-two block-capacity class of the pass-2 plan.
 
-    offsets/sizes are padded to ``bucket_rows(count)`` (padding rows have
-    size 0 and offset 0 and are discarded on assembly); ``count`` is the
-    live prefix and ``blocks`` the digest blocks its chunks really hold
-    (of the ``M * cap_blocks`` the class computes).
+    offsets/sizes are padded to the rows ``class_rows`` gives the class
+    (padding rows have size 0 and offset 0 and are discarded on
+    assembly); ``count`` is the live prefix and ``blocks`` the digest
+    blocks its chunks really hold (of the ``M * cap_blocks`` the class
+    computes). ``tile_rows``: the rows pass 2 gathers and digests at a
+    time. A class within TILE_BYTES is one batch (``tile_rows == M``,
+    M = ``bucket_rows(count)``); a wider one is ``M // tile_rows`` tiles,
+    rows ``[t * tile_rows, (t + 1) * tile_rows)`` being tile ``t``, so
+    only the last tile holds padding rows. 0 stands for ``M``.
     """
 
     cap_blocks: int
@@ -362,6 +434,11 @@ class Bucket:
     sizes: np.ndarray  # i32[M]
     count: int
     blocks: int = 0
+    tile_rows: int = 0
+
+    @property
+    def tiles(self) -> int:
+        return len(self.offsets) // self.tile_rows if self.tile_rows else 1
 
 
 def _gather_pack_sha(buffer: jax.Array, offs: jax.Array, sizes: jax.Array, cap_blocks: int):
@@ -438,26 +515,38 @@ def _pass2(
 ):
     """-> (tuple of u32[M_i, 8] digest states, i32[sum M_i] probe or None).
 
+    A class's offsets and sizes are i32[M_i], one batch, or i32[T, R]
+    with T * R == M_i: T row tiles of R rows, gathered and digested one
+    after the other by one loop body (its temporaries are one tile's),
+    the states in row order.
+
     Digest states are u32 words in the digester's natural order (big-
     endian words for sha256, little-endian for blake3); chunk-dict keys
     must be built with the same convention.
     """
     unroll = jax.default_backend() != "cpu"
-    states = []
-    for offs, sizes, cap in zip(bucket_offs, bucket_sizes, caps):
+
+    def digest_rows(cap, offs, sizes):
         if digester == "blake3":
             from nydus_snapshotter_tpu.ops import blake3_jax
 
             with jax.named_scope(f"gather_c{cap}"):
                 blocks = _gather_pack_b3(buffer, offs, sizes, cap)
             with jax.named_scope(f"blake3_c{cap}"):
-                states.append(blake3_jax._blake3_batch_jit(blocks, sizes, unroll))
+                return blake3_jax._blake3_batch_jit(blocks, sizes, unroll)
+        with jax.named_scope(f"gather_c{cap}"):
+            blocks = _gather_pack_sha(buffer, offs, sizes, cap)
+        with jax.named_scope(f"sha256_c{cap}"):
+            counts = (sizes + 8) // 64 + 1
+            return sha256._sha256_batch_jit(blocks, counts, unroll)
+
+    states = []
+    for offs, sizes, cap in zip(bucket_offs, bucket_sizes, caps):
+        if offs.ndim == 1:
+            states.append(digest_rows(cap, offs, sizes))
         else:
-            with jax.named_scope(f"gather_c{cap}"):
-                blocks = _gather_pack_sha(buffer, offs, sizes, cap)
-            with jax.named_scope(f"sha256_c{cap}"):
-                counts = (sizes + 8) // 64 + 1
-                states.append(sha256._sha256_batch_jit(blocks, counts, unroll))
+            tiled = jax.lax.map(lambda tile: digest_rows(cap, *tile), (offs, sizes))
+            states.append(tiled.reshape(-1, tiled.shape[-1]))
     probe = None
     if table_keys is not None:
         allq = jnp.concatenate(states, axis=0)
@@ -583,11 +672,16 @@ class FusedDeviceEngine:
         the guard padded_length() leaves for, and the shard halo
         ops/mesh_pack must append to every per-device slab so a chunk
         cut at a shard boundary still gathers without clamping."""
+        return self._blocks_of(self.params.max_size) * self._unit_bytes()
+
+    def _unit_bytes(self) -> int:
+        """Bytes a row gathers per unit of its class's capacity: a SHA
+        block's or a blake3 leaf's."""
         if self.digester == "blake3":
             from nydus_snapshotter_tpu.ops import blake3_jax
 
-            return self._blocks_of(self.params.max_size) * blake3_jax.LEAF_BYTES
-        return self._blocks_of(self.params.max_size) * 64
+            return blake3_jax.LEAF_BYTES
+        return 64
 
     # -- planning ------------------------------------------------------------
 
@@ -661,14 +755,15 @@ class FusedDeviceEngine:
                 rows.append((f_off + prev, size))
                 prev = int(cut)
         buckets = []
+        unit_bytes = self._unit_bytes()
         for cap in sorted(per_class):
             rows = per_class[cap]
-            m = bucket_rows(len(rows))
+            m, tile = class_rows(len(rows), cap * unit_bytes)
             offs = np.zeros(m, dtype=np.int32)
             sizes = np.zeros(m, dtype=np.int32)
             offs[: len(rows)] = [r[0] for r in rows]
             sizes[: len(rows)] = [r[1] for r in rows]
-            buckets.append(Bucket(cap, offs, sizes, len(rows), real_blocks[cap]))
+            buckets.append(Bucket(cap, offs, sizes, len(rows), real_blocks[cap], tile))
         return buckets, order
 
     # -- execution -----------------------------------------------------------
@@ -737,8 +832,10 @@ class FusedDeviceEngine:
         arrays IN PLACE, so the staged-table cache must key on the epoch
         — identity alone would keep serving the pre-insert device copy.
         """
-        offs = tuple(jnp.asarray(b.offsets) for b in buckets)
-        sizes = tuple(jnp.asarray(b.sizes) for b in buckets)
+        # a tiled class goes as [tiles, tile_rows]: the shape tells _pass2
+        shapes = [(b.tiles, -1) if b.tiles > 1 else (-1,) for b in buckets]
+        offs = tuple(jnp.asarray(b.offsets.reshape(s)) for b, s in zip(buckets, shapes))
+        sizes = tuple(jnp.asarray(b.sizes.reshape(s)) for b, s in zip(buckets, shapes))
         caps = tuple(b.cap_blocks for b in buckets)
         tk = tv = None
         table_cap = 0
@@ -942,14 +1039,20 @@ class FusedDeviceEngine:
             lane.next("pack:lane.plan")
             buckets, order = self.plan_buckets(table, cuts)
             # rows the floor added to each class, beyond its power of two
-            floored = [len(b.offsets) - _pow2_ceil(b.count) for b in buckets]
+            floored = [max(0, len(b.offsets) - _pow2_ceil(b.count)) for b in buckets]
             floored_classes = sum(1 for r in floored if r)
+            row_tiles = sum(b.tiles - 1 for b in buckets)
             lane.annotate(
                 classes=[[b.cap_blocks, b.count, len(b.offsets)] for b in buckets],
                 blocks_real=sum(b.blocks for b in buckets),
                 blocks_padded=sum(len(b.offsets) * b.cap_blocks for b in buckets),
                 row_floor_classes=floored_classes,
                 row_floor_rows=sum(floored),
+                blocks_tiled=sum(len(b.offsets) * b.cap_blocks for b in buckets if b.tiles > 1),
+                row_tiles=row_tiles,
+                batch_mib_max=max(len(b.offsets) // b.tiles * b.cap_blocks for b in buckets)
+                * self._unit_bytes()
+                / 2**20,
             )
             # a first call of a new plan compiles here: programs_after tells
             lane.next("pack:lane.pass2", programs_before=_pass2._cache_size())
@@ -998,5 +1101,6 @@ class FusedDeviceEngine:
             row_floor_classes=floored_classes,
             copied_bytes=begun.copied,
             early_start=early,
+            row_tiles=row_tiles,
         )
         return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np)

@@ -126,6 +126,27 @@ def test_pass2_gather_digest(shape, monkeypatch, chunk_size, digester, caps_of, 
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
+def test_pass2_row_tiles_hold_hbm_to_the_budget(shape, monkeypatch):
+    """The widest class of benchmark/configs/mlimage-1m.json's layer: 576
+    chunks of 1-2 MiB (32,768 blocks) in a 1,280 MiB buffer. As one batch
+    of bucket_rows(576) = 1,024 rows it is 2 GiB of blocks, which the
+    chip's compiler lays out eightfold (a last dimension of 16 words on
+    128 lanes) and refuses: what the plan before class_rows did on the
+    chip. In the row tiles the plan gives it, the temporaries are one
+    tile's, and with the buffer they stay under half the chip's HBM."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cap, live, buffer = 32768, 576, shape((1280 * MIB,), jnp.uint8)
+    rows, tile_rows = fused_convert.class_rows(live, cap * 64)
+    assert (rows, tile_rows) == (768, 256) and rows < fused_convert.bucket_rows(live) == 1024
+    whole = (shape((fused_convert.bucket_rows(live),), jnp.int32),)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        fused_convert._pass2.lower(buffer, whole, whole, (cap,)).compile()
+    tiled = (shape((rows // tile_rows, tile_rows), jnp.int32),)
+    compiled = fused_convert._pass2.lower(buffer, tiled, tiled, (cap,)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 11 * fused_convert.TILE_BYTES
+    assert _device_bytes(compiled) < V5E_HBM_BYTES // 2
+
+
 def test_pass2_with_pallas_probe(shape):
     cap, depth = 1 << 16, 8
     cp = probe_pallas.padded_slots(cap, depth)
