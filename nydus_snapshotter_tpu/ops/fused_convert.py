@@ -16,10 +16,14 @@ on device ONCE and never comes back:
   Shipping cuts through the host costs two dispatch floors but buys
   EXACT static shapes for pass 2 — an on-device resolver would force
   worst-case (~16x padded) digest compute, which loses at any batch size.
-- **Pass 2 (one jit dispatch).** Per bucket: ``lax.scan`` of
-  ``dynamic_slice`` gathers (byte-exact chunk starts, so no realignment
-  kernel), SHA-256 padding applied with iota masks on device, the
-  measured ``sha256_batch`` scan, and the chunk-dict probe
+- **Pass 2 (one jit dispatch).** It reads the buffer as 32-bit words
+  (``lane_words``: the same host memory, uploaded a second time under
+  pass 1). Per bucket: ``lax.scan`` of ``dynamic_slice`` gathers of words,
+  funnel-shifted to the chunk's byte-exact start (``_chunk_words``: no
+  realignment kernel, nothing touched as a byte), SHA-256 padding applied
+  with masks on a word iota, the rows transposed once so that the digest
+  scan reads a ``[16, rows]`` block a step (``sha256._sha256_lanes``), and
+  the chunk-dict probe
   (parallel/sharded_dict._probe_local) over every digest. D2H is
   32 B/chunk of digests + 4 B/chunk of dict hits. A class is gathered
   and digested as ONE batch of rows while that batch stays within
@@ -205,9 +209,13 @@ def _pow2_floor(n: int) -> int:
 # 65,536 blocks took 3.97 s as one row and 0.33 / 0.33 / 0.35 s padded
 # to 2 / 4 / 8 (PERF.md section 5; my chip runs, PR 27). So 2, the
 # smallest batch past the cliff: a padding row is gathered and digested
-# like any other, which is nearly free on the chip up to ~128 rows
-# (2.5-2.7 us a step from 16 to 128) but costs the CPU backend, whose
-# scan is bound by throughput, a whole row's time.
+# like any other, which is nearly free on the chip up to ~128 rows but
+# costs the CPU backend, whose scan is bound by throughput, a whole row's
+# time. In the lane-dense loop (sha256._sha256_lanes) a step takes 5.6 us
+# at 2 rows, 2.7 at 16 and 2.9 at 128, and the gather 40 us a row of 2
+# MiB: the one-chunk class takes 0.368 s at 2 rows and 0.176 at 16 (my
+# chip run, PR 34), so a floor of 16 is the chip's optimum and the CPU
+# backend's cost; the value is the plan's, and stays.
 ROW_FLOOR = 2
 
 
@@ -224,22 +232,25 @@ def bucket_rows(live: int) -> int:
 
 # The most bytes of gathered blocks (rows x the bytes a row gathers) that
 # pass 2 holds as ONE batch; a wider class runs in row tiles (class_rows).
-# The chip's compiler lays a batch u32[rows, cap_blocks, 16] out eightfold
-# (16 words on 128 lanes), so _pass2's temporaries are ten bytes a byte of
-# its widest batch: for one class of 32,768 blocks in a 1,280 MiB buffer
-# 2,578 / 5,122 / 10,242 MiB at 128 / 256 / 512 rows, and at 1,024 rows,
-# which bucket_rows gives the 576 chunks of 1-2 MiB in the jax / jaxlib /
-# libtpu layer of a training image, RESOURCE_EXHAUSTED: 18.0 of 15.75 GiB
-# (my chip runs, PR 33; tiled or as one batch, the same to the MiB). So
-# 512 MiB, 256 such rows: the largest power of two whose temporaries with
-# that buffer (6.3 GiB) stay under half the HBM, a dictionary's table or a
-# wider buffer beside them, and the smallest that leaves every layer the
-# benchmark had before as it was (node:21 at 64 KiB chunks: 4,096 rows x
-# 2,048 blocks = 512 MiB, one batch). Time does not ask for more: a digest
-# step takes 2.66 / 3.75 / 4.98 us at 128 / 256 / 512 rows, and the
-# gather, 1.9 ms a row of 2 MiB, a padding row like a live one, is most of
-# a tile (0.334 / 0.602 / 1.136 s), so the 576 chunks take 1.66 / 1.80 /
-# 2.27 s in 5 x 128 / 3 x 256 / 2 x 512 rows (PERF.md section 6).
+# _pass2's temporaries are three bytes a byte of its widest batch (the
+# gathered rows, their transpose, the digest's blocks): for one class of
+# 32,768 blocks in a 1,280 MiB buffer 1,538 MiB at 256 rows, tiled or as
+# one batch, and 6,146 MiB at the 1,024 rows that bucket_rows gives the 576
+# chunks of 1-2 MiB in the jax / jaxlib / libtpu layer of a training image
+# (my chip run, PR 34, and tests/test_chip_compile.py). The value dates
+# from the byte gather, whose batch u32[rows, cap_blocks, 16] the chip's
+# compiler laid out eightfold (16 words on 128 lanes: ten bytes a byte,
+# 5,122 MiB at 256 rows, RESOURCE_EXHAUSTED at 1,024; PR 33): 512 MiB, 256
+# such rows, was the largest power of two whose temporaries stayed under
+# half the HBM beside that buffer, and is still the smallest that leaves
+# every layer the benchmark had before as it was (node:21 at 64 KiB chunks:
+# 4,096 rows x 2,048 blocks = 512 MiB, one batch), which is why it stays:
+# a plan change is judged on that cell. Time asks for neither more nor
+# less: a tile of 256 such rows takes 0.149 s, 0.140 of it the digest's
+# 32,768 serial steps (4.3 us a step; 5.0 at 512 rows, PR 33) and 0.01 the
+# gather, 40 us a row of 2 MiB, a padding row like a live one (it was 1.9
+# ms); the 576 chunks take 0.445 s in 3 x 256 rows, where the byte gather
+# took 1.80 (my chip runs, PR 34; PERF.md section 6).
 TILE_BYTES = 512 << 20
 
 
@@ -266,7 +277,9 @@ def padded_length(total: int, max_size: int) -> int:
     into a buffer of this size)."""
     # a window multiple + one max-chunk guard so pass-2 dynamic_slice
     # never clamps a start (clamping would shift the slice and corrupt
-    # in-range bytes)
+    # in-range bytes). The gather reads whole words, one past its class's
+    # capacity: max_size + 64 is the widest class's, and a chunk of that
+    # class is longer than the word, so the guard covers it
     guard = max_size + 64
     npad = -(-max(1, total + guard) // WINDOW) * WINDOW
     # quantize to 1/8-pow2 steps: bounded compile count without the
@@ -327,6 +340,26 @@ def lane_buffer(data: np.ndarray, npad: int) -> tuple[np.ndarray, int]:
     buf = zeroed_buffer(npad)
     buf[: data.size] = data
     return buf, data.size
+
+
+def lane_words(buf: np.ndarray) -> np.ndarray:
+    """u8[..., L] -> u32[..., ceil(L / 4)]: a lane buffer as the words pass 2
+    gathers from (_chunk_words), word i holding bytes 4i..4i+3 with the
+    first in its low bits. ``"<u4"``, so on a little-endian host (every one
+    this runs on) it is a view: the same memory, nothing copied. Every
+    padded_length is whole words (a WINDOW multiple); a buffer that is not
+    (a mesh slab of any length, ops/mesh_pack) is first padded with zeros.
+
+    The words are made here, on the host, because the chip's compiler
+    cannot make them from the u8 operand: ``bitcast_convert_type`` of
+    ``buffer.reshape(-1, 4)`` is refused (a last dimension of 4 on 128
+    lanes: an allocation of 160 GiB for a 1,280 MiB buffer) and four strided
+    slices ``buffer[k::4]`` compile to 3.5 GiB of temporaries and 1.4 TB of
+    bytes accessed (compiled for a described v5e, ISSUE 34)."""
+    pad = -buf.shape[-1] % 4
+    if pad:
+        buf = np.pad(buf, [(0, 0)] * (buf.ndim - 1) + [(0, pad)])
+    return np.ascontiguousarray(buf).view("<u4")
 
 
 # ---------------------------------------------------------------------------
@@ -441,57 +474,87 @@ class Bucket:
         return len(self.offsets) // self.tile_rows if self.tile_rows else 1
 
 
-def _gather_pack_sha(buffer: jax.Array, offs: jax.Array, sizes: jax.Array, cap_blocks: int):
-    """Gather chunks at byte-exact offsets and emit SHA-padded blocks.
+def _chunk_words(words: jax.Array, offs: jax.Array, sizes: jax.Array, n_words: int):
+    """-> u32[M, n_words]: row i holds the bytes of the chunk at byte
+    offs[i] of the lane buffer as little-endian words, zero from byte
+    sizes[i] on. ``words`` is the buffer as lane_words gives it.
 
-    One scan step per chunk: dynamic_slice (a contiguous DMA-shaped copy,
-    not an element gather), zero/0x80 padding + big-endian word build +
-    64-bit length words, all via iota masks. -> u32[M, cap_blocks, 16].
-    """
-    capb = cap_blocks * 64
-    byte_iota = jnp.arange(capb, dtype=jnp.int32)
-    word_iota = jnp.arange(capb // 4, dtype=jnp.int32)
+    One scan step per chunk, nothing touched as a byte: n_words words from
+    the word holding the chunk's first byte and n_words from the next (two
+    dynamic_slices: contiguous DMA-shaped copies, not element gathers),
+    joined by a funnel shift of 8 * (off & 3) bits, then the tail masked by
+    a word iota. The slices read one word past the chunk's capacity, which
+    the guard of padded_length (and max_read_span, for a mesh slab) leaves
+    room for: a clamped start would shift the whole slice."""
+    word_iota = jnp.arange(n_words, dtype=jnp.int32)
 
     def step(carry, xs):
         off, size = xs
-        raw = jax.lax.dynamic_slice(buffer, (off,), (capb,))
-        padded = jnp.where(byte_iota < size, raw, jnp.uint8(0))
-        padded = jnp.where(byte_iota == size, jnp.uint8(0x80), padded)
-        w = padded.reshape(-1, 4).astype(jnp.uint32)
-        words = (w[:, 0] << 24) | (w[:, 1] << 16) | (w[:, 2] << 8) | w[:, 3]
-        nb = (size + 8) // 64 + 1  # n_padded_blocks
-        hi = (size >> 29).astype(jnp.uint32)
-        lo = size.astype(jnp.uint32) << 3
-        words = jnp.where(word_iota == (nb - 1) * 16 + 14, hi, words)
-        words = jnp.where(word_iota == (nb - 1) * 16 + 15, lo, words)
-        return carry, words.reshape(cap_blocks, 16)
+        first = off >> 2
+        lo = jax.lax.dynamic_slice(words, (first,), (n_words,))
+        hi = jax.lax.dynamic_slice(words, (first + 1,), (n_words,))
+        shift = (8 * (off & 3)).astype(jnp.uint32)
+        # (hi << 1) << (31 - shift): hi's low bytes on top of lo's high
+        # ones, and nothing of hi where the chunk starts on a word (no
+        # shift by 32, whose result XLA leaves to the backend)
+        got = (lo >> shift) | ((hi << 1) << (31 - shift))
+        last = size >> 2  # the word holding byte `size`: its bytes below it stay
+        keep = (jnp.uint32(1) << (8 * (size & 3)).astype(jnp.uint32)) - 1
+        got = jnp.where(word_iota < last, got, jnp.where(word_iota == last, got & keep, 0))
+        return carry, got
 
-    _, blocks = jax.lax.scan(step, 0, (offs, sizes))
-    return blocks
+    _, rows = jax.lax.scan(step, 0, (offs, sizes))
+    return rows
 
 
-def _gather_pack_b3(buffer: jax.Array, offs: jax.Array, sizes: jax.Array, cap_leaves: int):
+def _gather_pack_sha(words: jax.Array, offs: jax.Array, sizes: jax.Array, cap_blocks: int):
+    """Gather chunks at byte-exact offsets and emit SHA-padded blocks in
+    the form the digest loop reads (sha256._sha256_lanes): big-endian words
+    u32[cap_blocks, 16, M], the chunks on the last axis.
+
+    The 0x80 byte, the byte swap and the two length words are masks and
+    shifts on whole u32 words; the rows are transposed once, so that no
+    array of the batch's size has a block's 16 words as its last dimension
+    (the chip's compiler lays such a one out eightfold, 16 words on 128
+    lanes, which was ten bytes of temporaries a byte of the batch: for 256
+    rows of 32,768 blocks 5,122 MiB, now 1,538)."""
+    n_words = cap_blocks * 16
+    word_iota = jnp.arange(n_words, dtype=jnp.int32)
+    size = sizes[:, None]
+    le = _chunk_words(words, offs, sizes, n_words)
+    le = le | jnp.where(
+        word_iota == size >> 2, jnp.uint32(0x80) << (8 * (size & 3)).astype(jnp.uint32), 0
+    )
+    be = (le << 24) | ((le & 0xFF00) << 8) | ((le >> 8) & 0xFF00) | (le >> 24)
+    length_at = (size + 8) // 64 * 16 + 14  # the last two words of the last padded block
+    be = jnp.where(word_iota == length_at, (size >> 29).astype(jnp.uint32), be)
+    be = jnp.where(word_iota == length_at + 1, size.astype(jnp.uint32) << 3, be)
+    return be.T.reshape(cap_blocks, 16, -1)
+
+
+def _gather_pack_b3(words: jax.Array, offs: jax.Array, sizes: jax.Array, cap_leaves: int):
     """Gather chunks into the blake3 batch layout u32[M, C, 16, 16].
 
-    Simpler than the SHA pack: zero beyond the message and build
-    LITTLE-endian words (blake3's byte order); lengths drive the in-kernel
-    flag/tail handling, so no padding bytes or length words are embedded.
+    The same gather as the SHA pack and nothing more: blake3's words are
+    little-endian, and lengths drive the in-kernel flag/tail handling, so no
+    padding bytes or length words are embedded. The hand-over keeps
+    blake3_jax's form: its leaves and tree are vmapped over rows, and a
+    lane-dense one is more than a reshape there.
     """
     from nydus_snapshotter_tpu.ops import blake3_jax
 
-    capb = cap_leaves * blake3_jax.LEAF_BYTES
-    byte_iota = jnp.arange(capb, dtype=jnp.int32)
+    le = _chunk_words(words, offs, sizes, cap_leaves * (blake3_jax.LEAF_BYTES // 4))
+    return le.reshape(-1, cap_leaves, 16, 16)
 
-    def step(carry, xs):
-        off, size = xs
-        raw = jax.lax.dynamic_slice(buffer, (off,), (capb,))
-        b = jnp.where(byte_iota < size, raw, jnp.uint8(0))
-        w = b.reshape(-1, 4).astype(jnp.uint32)
-        words = w[:, 0] | (w[:, 1] << 8) | (w[:, 2] << 16) | (w[:, 3] << 24)
-        return carry, words.reshape(cap_leaves, 16, 16)
 
-    _, blocks = jax.lax.scan(step, 0, (offs, sizes))
-    return blocks
+def _gather_digest_sha(words: jax.Array, offs, sizes, cap_blocks: int, unroll: bool):
+    """One sha256 class of pass 2, gather and digest as the pair they are:
+    -> u32[M, 8] states. _pass2's, and the per-device step of
+    __graft_entry__.sharded_convert_step's."""
+    with jax.named_scope(f"gather_c{cap_blocks}"):
+        blocks = _gather_pack_sha(words, offs, sizes, cap_blocks)
+    with jax.named_scope(f"sha256_c{cap_blocks}"):
+        return sha256._sha256_lanes(blocks, (sizes + 8) // 64 + 1, unroll)
 
 
 @functools.partial(
@@ -501,7 +564,7 @@ def _gather_pack_b3(buffer: jax.Array, offs: jax.Array, sizes: jax.Array, cap_le
     ),
 )
 def _pass2(
-    buffer: jax.Array,
+    words: jax.Array,  # u32[NP / 4]: the lane buffer as lane_words gives it
     bucket_offs: tuple[jax.Array, ...],
     bucket_sizes: tuple[jax.Array, ...],
     caps: tuple[int, ...],
@@ -531,14 +594,10 @@ def _pass2(
             from nydus_snapshotter_tpu.ops import blake3_jax
 
             with jax.named_scope(f"gather_c{cap}"):
-                blocks = _gather_pack_b3(buffer, offs, sizes, cap)
+                blocks = _gather_pack_b3(words, offs, sizes, cap)
             with jax.named_scope(f"blake3_c{cap}"):
                 return blake3_jax._blake3_batch_jit(blocks, sizes, unroll)
-        with jax.named_scope(f"gather_c{cap}"):
-            blocks = _gather_pack_sha(buffer, offs, sizes, cap)
-        with jax.named_scope(f"sha256_c{cap}"):
-            counts = (sizes + 8) // 64 + 1
-            return sha256._sha256_batch_jit(blocks, counts, unroll)
+        return _gather_digest_sha(words, offs, sizes, cap, unroll)
 
     states = []
     for offs, sizes, cap in zip(bucket_offs, bucket_sizes, caps):
@@ -613,20 +672,29 @@ class Begun:
     # the host buffer, alive and unwritten until the upload is done (None:
     # a batch without a byte, nothing enqueued)
     buf: "np.ndarray | None" = None
-    buffer_dev: "jax.Array | None" = None
+    buffer_dev: "jax.Array | None" = None  # u8[NP], pass 1's operand: None once its candidates are on the host
+    words_dev: "jax.Array | None" = None  # u32[NP / 4], the same bytes as pass 2 gathers them (lane_words)
     words: tuple = ()  # _pass1's six outputs, still on the device
     wcap_s: int = 0
     wcap_l: int = 0
     t0: float = 0.0  # perf_counter at the enqueue's start
 
+    def drop_bytes(self) -> None:
+        """Free pass 1's u8 operand: pass 2 reads the words, and the two
+        are a buffer's length of HBM each."""
+        if self.buffer_dev is not None:
+            self.buffer_dev.delete()
+        self.buffer_dev = None
+
     def close(self) -> None:
         """Free the device arrays now, in flight or not (the runtime lets
         work that reads them finish: 1.6 ms for the call on the v5e, my
         chip run, PR 32); nothing waits."""
-        if self.buffer_dev is not None:
-            for a in (self.buffer_dev, *self.words):
+        self.drop_bytes()
+        if self.words_dev is not None:
+            for a in (self.words_dev, *self.words):
                 a.delete()
-        self.buf = self.buffer_dev = None
+        self.buf = self.words_dev = None
         self.words = ()
 
 
@@ -672,7 +740,9 @@ class FusedDeviceEngine:
         the guard padded_length() leaves for, and the shard halo
         ops/mesh_pack must append to every per-device slab so a chunk
         cut at a shard boundary still gathers without clamping."""
-        return self._blocks_of(self.params.max_size) * self._unit_bytes()
+        # + a word: a gather reads whole words, from the one that holds the
+        # chunk's first byte to one past its capacity (_chunk_words)
+        return self._blocks_of(self.params.max_size) * self._unit_bytes() + 4
 
     def _unit_bytes(self) -> int:
         """Bytes a row gathers per unit of its class's capacity: a SHA
@@ -814,7 +884,7 @@ class FusedDeviceEngine:
 
     def digest_probe(
         self,
-        buffer_dev: jax.Array,
+        words_dev: jax.Array,  # u32: the buffer as lane_words gives it
         buckets: list[Bucket],
         chunk_dict: tuple[np.ndarray, np.ndarray] | None = None,
         depth: int = 8,
@@ -859,7 +929,7 @@ class FusedDeviceEngine:
             # the branch really taken (what "auto" resolved to)
             self.probe_kernel_used = "pallas" if use_pallas else "xla"
         states, probe = _pass2(
-            buffer_dev, offs, sizes, caps, tk, tv, table_cap, depth,
+            words_dev, offs, sizes, caps, tk, tv, table_cap, depth,
             digester=self.digester, pallas_probe=use_pallas,
             probe_interpret=probe_interpret,
         )
@@ -962,7 +1032,13 @@ class FusedDeviceEngine:
         stages.next("pack:lane.pass1")
         words, wcap_s, wcap_l = self.enqueue_pass1(buffer_dev, n)
         stages.annotate(wcap_s=wcap_s, wcap_l=wcap_l)
-        return Begun(data, table, n, copied, before, buf, buffer_dev, words, wcap_s, wcap_l, t0)
+        # the same bytes once more, as the words pass 2 gathers from: enqueued
+        # behind the bytes and the call, so the upload runs under _pass1 and
+        # nothing waits for it before pass 2 is dispatched
+        words_dev = jnp.asarray(lane_words(buf))
+        return Begun(
+            data, table, n, copied, before, buf, buffer_dev, words_dev, words, wcap_s, wcap_l, t0
+        )
 
     def process_many(
         self,
@@ -1000,8 +1076,8 @@ class FusedDeviceEngine:
                 table = _checked_table(streams, begun.n)
             else:
                 raise ValueError("the lane was begun on another buffer than these extents lie in")
-            n, buffer_dev = begun.n, begun.buffer_dev
-            if buffer_dev is None or not any(length for _off, length in table):
+            n = begun.n
+            if begun.words_dev is None or not any(length for _off, length in table):
                 return FusedResult(
                     cuts=[np.asarray([], dtype=np.int64) for _ in table],
                     digests=[[] for _ in table],
@@ -1027,6 +1103,7 @@ class FusedDeviceEngine:
             cand_s = self.candidate_positions(sel_s, got_s, nw_s, n)
             cand_l = self.candidate_positions(sel_l, got_l, nw_l, n)
             lane.annotate(candidates_s=len(cand_s), candidates_l=len(cand_l))
+            begun.drop_bytes()
             lane.next("pack:lane.resolve", files=len(table))
             cuts = self.resolve(cand_s, cand_l, table)
             # a file no longer than min_size is one chunk, no candidate judged
@@ -1057,7 +1134,7 @@ class FusedDeviceEngine:
             # a first call of a new plan compiles here: programs_after tells
             lane.next("pack:lane.pass2", programs_before=_pass2._cache_size())
             states, probe = self.digest_probe(
-                buffer_dev, buckets, chunk_dict, depth, probe_kernel, dict_epoch
+                begun.words_dev, buckets, chunk_dict, depth, probe_kernel, dict_epoch
             )
             jax.block_until_ready(states)
             lane.annotate(programs_after=_pass2._cache_size())

@@ -12,12 +12,14 @@ replication:
   ``shard_bytes = ceil(total / n)`` bytes; a chunk belongs to the device
   that owns its first byte.
 - Each device's packed buffer is its shard plus a **halo**: pass-2
-  gathers read ``cap_blocks * block_bytes`` bytes from each chunk start
-  (the ``dynamic_slice`` span, not the chunk size), so a chunk cut right
-  before a shard boundary reads into the next shard. The halo is the
-  engine's maximum read span, which also guarantees no slice ever clamps
-  (a clamped ``dynamic_slice`` shifts its start and corrupts in-range
-  bytes — the same guard rule ops/fused_convert.layout applies).
+  gathers read ``cap_blocks * block_bytes`` bytes and one word more from
+  the word that holds each chunk's start (the ``dynamic_slice`` span, not
+  the chunk size), so a chunk cut right before a shard boundary reads into
+  the next shard. The halo is the engine's maximum read span, rounded up
+  so that a slab is whole words (it goes to the devices as
+  ``fused_convert.lane_words``), which also guarantees no slice ever
+  clamps (a clamped ``dynamic_slice`` shifts its start and corrupts
+  in-range bytes — the same guard rule ops/fused_convert.layout applies).
 - Every pass-2 bucket is re-partitioned so each device's rows sit in one
   contiguous block of the leading axis (``shard_map``'s layout), padded
   per device to a uniform ``rows_per_device``. Offsets are rebased to
@@ -43,7 +45,8 @@ import numpy as np
 
 from nydus_snapshotter_tpu.ops.fused_convert import bucket_rows
 
-BLOCK_BYTES = 64  # SHA-256 block: pass-2 read span = cap_blocks * 64
+BLOCK_BYTES = 64  # SHA-256 block: pass-2 read span = cap_blocks * 64 + WORD_BYTES
+WORD_BYTES = 4  # pass 2 gathers u32 words (fused_convert._chunk_words)
 
 
 @dataclass(frozen=True)
@@ -87,14 +90,6 @@ class MeshPackPlan:
         return min(offset // self.shard_bytes, self.n_devices - 1)
 
 
-def max_read_span(params, block_bytes: int = BLOCK_BYTES) -> int:
-    """Largest pass-2 gather span for a CDC parameterization: the padded
-    block count of a max-size chunk times the digest block width."""
-    from nydus_snapshotter_tpu.ops import sha256
-
-    return sha256.n_padded_blocks(params.max_size) * block_bytes
-
-
 def plan_mesh_pack(
     buckets,
     order,
@@ -116,10 +111,11 @@ def plan_mesh_pack(
         raise ValueError(f"n_devices must be >= 1, got {n_devices}")
     total = max(0, int(total))
     shard = max(1, -(-total // n_devices)) if total else 1
-    max_span = max(
+    max_span = WORD_BYTES + max(
         (b.cap_blocks * block_bytes for b in buckets), default=block_bytes
     )
     halo = max_span if halo_bytes is None else max(int(halo_bytes), max_span)
+    halo += -(shard + halo) % WORD_BYTES  # a slab is whole words
     pack_len = shard + halo
 
     sharded: list[ShardedBucket] = []
@@ -146,10 +142,11 @@ def plan_mesh_pack(
         rows = dev * m_dev + idx_in_dev
         local = offs - dev * shard
         if live:
-            if local.min() < 0 or (local + b.cap_blocks * block_bytes).max() > pack_len:
+            span = b.cap_blocks * block_bytes + WORD_BYTES
+            if local.min() < 0 or (local + span).max() > pack_len:
                 raise AssertionError(
                     "extent plan would clamp a gather: local offset span "
-                    f"[{local.min()}, {(local + b.cap_blocks * block_bytes).max()}] "
+                    f"[{local.min()}, {(local + span).max()}] "
                     f"outside pack_len {pack_len}"
                 )
             loc[rows] = local
@@ -187,7 +184,7 @@ def plan_mesh_pack(
 
 def pack_buffers(buf: np.ndarray, plan: MeshPackPlan) -> np.ndarray:
     """``u8[n_devices, pack_len]``: each row is that device's byte shard
-    plus halo, zero-padded past the corpus tail."""
+    plus halo, zero-padded past the corpus tail; whole words a row."""
     buf = np.asarray(buf, dtype=np.uint8).reshape(-1)
     out = np.zeros((plan.n_devices, plan.pack_len), dtype=np.uint8)
     for d in range(plan.n_devices):

@@ -374,6 +374,113 @@ class TestExtentsEntry:
             )
 
 
+# -- pass 2's word gather alone -------------------------------------------------
+
+GATHER_CAP = 4  # SHA blocks a row: 256 bytes, 64 words
+GATHER_SHA_SIZES = [0, 1, 3, 4, 55, 56, 63, 64, 65, 119, 120, GATHER_CAP * 64 - 9]
+GATHER_B3_SIZES = [0, 1, 3, 4, 63, 64, 65, 1023, 1024, 1025, 2048]  # two leaves a row
+
+
+def _chunk_and_padding_row(off: int, size: int):
+    """-> (offs, sizes) of a batch of two: the chunk, and a padding row."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(np.array([off, 0], np.int32)), jnp.asarray(np.array([size, 0], np.int32))
+
+
+class TestWordGather:
+    """_gather_pack_sha / _gather_pack_b3 against the host packings, byte
+    for byte: the chunk's bytes reach the digest as words funnel-shifted
+    out of the buffer's words, for every residue of the chunk's offset, and
+    its padding is made by masks on words. Row 1 of every batch is a
+    padding row (size 0, offset 0), as class_rows pads a class."""
+
+    @pytest.fixture(scope="class")
+    def lane(self):
+        import jax
+        import jax.numpy as jnp
+
+        buf = np.random.default_rng(34).integers(0, 256, 1 << 14, dtype=np.uint8)
+        words = fused_convert.lane_words(buf)
+        assert np.shares_memory(words, buf) and words.dtype == np.uint32 and words.size == buf.size // 4
+        sha = jax.jit(fused_convert._gather_pack_sha, static_argnums=3)
+        b3 = jax.jit(fused_convert._gather_pack_b3, static_argnums=3)
+        digest = jax.jit(fused_convert._gather_digest_sha, static_argnums=(3, 4))
+        return buf, jnp.asarray(words), sha, b3, digest, _chunk_and_padding_row
+
+    @staticmethod
+    def _sha_blocks(chunk, cap):
+        from nydus_snapshotter_tpu.ops import sha256
+
+        want = np.zeros((cap, 16), np.uint32)  # beyond its padded blocks a row is zeros
+        padded = sha256.pad_message_np(chunk)
+        want[: len(padded)] = padded
+        return want
+
+    @pytest.mark.parametrize("size", GATHER_SHA_SIZES)
+    @pytest.mark.parametrize("residue", [0, 1, 2, 3])
+    def test_sha_blocks_are_pad_message_nps(self, lane, residue, size):
+        from nydus_snapshotter_tpu.ops import sha256
+
+        buf, words, sha, _b3, digest, rows = lane
+        off = 1000 + residue
+        blocks = np.asarray(sha(words, *rows(off, size), GATHER_CAP))
+        assert blocks.shape == (GATHER_CAP, 16, 2)  # the rows on the last axis
+        np.testing.assert_array_equal(blocks[:, :, 0], self._sha_blocks(buf[off : off + size], GATHER_CAP))
+        np.testing.assert_array_equal(blocks[:, :, 1], self._sha_blocks(b"", GATHER_CAP))
+        states = np.asarray(digest(words, *rows(off, size), GATHER_CAP, False))
+        assert sha256.digest_to_bytes(states[0]) == hashlib.sha256(bytes(buf[off : off + size])).digest()
+        assert sha256.digest_to_bytes(states[1]) == hashlib.sha256(b"").digest()
+
+    @pytest.mark.parametrize("size", GATHER_B3_SIZES)
+    @pytest.mark.parametrize("residue", [0, 1, 2, 3])
+    def test_blake3_blocks_are_pack_messages_nps(self, lane, residue, size):
+        from nydus_snapshotter_tpu.ops import blake3_jax
+
+        buf, words, _sha, b3, _digest, rows = lane
+        off = 2000 + residue
+        blocks = np.asarray(b3(words, *rows(off, size), 2))
+        want, _lengths = blake3_jax.pack_messages_np([buf[off : off + size], b""], leaf_capacity=2)
+        np.testing.assert_array_equal(blocks, want)
+
+    @pytest.mark.parametrize("digester", ["sha256", "blake3"])
+    @pytest.mark.parametrize("residue", [0, 1, 2, 3])
+    def test_a_max_size_chunk_that_ends_at_the_last_valid_byte(self, digester, residue):
+        """The gather reads whole words, one past the chunk's capacity: the
+        guard of padded_length (max_size + 64, here without its rounding up
+        to a window) is room for it, so no dynamic_slice clamps its start."""
+        import jax.numpy as jnp
+        from nydus_snapshotter_tpu.ops import blake3_jax, sha256
+
+        eng = fused_convert.FusedDeviceEngine(chunk_size=SMALL, digester=digester)
+        size = eng.params.max_size
+        total = 3 * size + residue
+        buf = np.zeros(total + size + 64, np.uint8)
+        buf[:total] = np.random.default_rng(residue).integers(0, 256, total, dtype=np.uint8)
+        off, cap = total - size, eng._blocks_of(size)
+        assert off & 3 == residue and off + eng.max_read_span() <= buf.size
+        words = jnp.asarray(fused_convert.lane_words(buf))
+        offs, sizes = _chunk_and_padding_row(off, size)
+        if digester == "blake3":
+            got = np.asarray(fused_convert._gather_pack_b3(words, offs, sizes, cap))[0]
+            want = blake3_jax.pack_messages_np([buf[off:total]], leaf_capacity=cap)[0][0]
+        else:
+            got = np.asarray(fused_convert._gather_pack_sha(words, offs, sizes, cap))[:, :, 0]
+            want = self._sha_blocks(buf[off:total], cap)
+            assert len(sha256.pad_message_np(buf[off:total])) == cap  # the widest row of the widest class
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("length", [8, 9, 10, 11])
+    def test_lane_words_of_a_buffer_that_is_not_whole_words(self, length):
+        buf = np.arange(2 * length, dtype=np.uint8).reshape(2, length)
+        words = fused_convert.lane_words(buf)
+        assert words.shape == (2, 3 if length > 8 else 2)
+        got = words.view(np.uint8).reshape(2, -1)
+        np.testing.assert_array_equal(got[:, :length], buf)
+        assert not got[:, length:].any()
+        assert int(words[0, 0]) == 0x03020100  # the first byte in the low bits
+
+
 class TestFusedPackLane:
     def test_pack_layer_byte_identity_vs_hybrid(self):
         """PackOption(backend="fused") must produce byte-identical layer
@@ -622,6 +729,8 @@ class TestEarlyStart:
                 fused_convert.Extents(data, extents), chunk_dict=chunk_dict, depth=depth,
                 stages=stages, begun=begun,
             )
+            # pass 1's operand went when its candidates were on the host; pass 2 read the words
+            assert begun.buffer_dev is None and not begun.words_dev.is_deleted()
             begun.close()
         assert [b - a for a, b in zip(before, self._counts())] == [1, 1, 0]
         assert list(stages.seconds) == [f"pack:lane.{s}" for s in ("layout", "h2d", "pass1")] + ["pack:scan"] + [
@@ -664,12 +773,13 @@ class TestEarlyStart:
         before = self._counts()
         with trace.Stages() as stages:
             begun = eng.begin(tar, stages)
-            dev, words = begun.buffer_dev, begun.words
+            dev, words_dev, words = begun.buffer_dev, begun.words_dev, begun.words
             assert len(words) == 6 and not dev.is_deleted()
+            assert words_dev.dtype == np.uint32 and words_dev.size * 4 == dev.size  # the same bytes, twice
             begun.close()
             begun.close()  # whoever comes second finds nothing left
-        assert dev.is_deleted() and all(w.is_deleted() for w in words)
-        assert begun.buf is None and begun.buffer_dev is None and begun.words == ()
+        assert dev.is_deleted() and words_dev.is_deleted() and all(w.is_deleted() for w in words)
+        assert begun.buf is None and begun.buffer_dev is None and begun.words_dev is None and begun.words == ()
         assert self._counts() == before
         # and the next batch is none the worse for it
         got = eng.process_many(fused_convert.Extents(tar, extents))
