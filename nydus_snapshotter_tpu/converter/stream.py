@@ -800,7 +800,7 @@ class _Pack:
 # opens its own leaf spans. It yields, per planned file and in plan order,
 # (cuts, digests): the file's exclusive chunk ends and its chunks' digests,
 # or None for digests the lane leaves to the digest queue. Before its first
-# result it may raise _LaneDeclined (the batch overflows the device lane's
+# result it may raise _LaneDeclined (one file overflows the device lane's
 # buffer, a native arm lacks its codec library).
 
 
@@ -859,31 +859,38 @@ def _begin_device_lane(opt: PackOption, arr, stages):
     upload and pass 1 enqueued, nothing waited for) as soon as the pack
     has the layer in memory, so that the device works while the host
     parses the dictionary and walks the tar; None for a pack that does not
-    lead there, by read_layer's own condition. choose_lane still chooses:
-    the pack closes a begun lane that was never finished."""
+    lead there, by read_layer's own condition, and for a layer that no one
+    lane buffer holds: its file table decides its batches, so _lane_device
+    begins them after the scan. choose_lane still chooses: the pack closes
+    a begun lane that was never finished."""
     if not (arr is not None and arr.size and _device_lane_wanted(opt)):
         return None
     from nydus_snapshotter_tpu.ops import fused_convert
 
-    try:
-        return _device_engine(opt).begin(arr, stages)
-    except fused_convert.FusedOverflow:
-        return None  # no lane buffer holds it: _lane_device meets that again, and counts it
+    engine = _device_engine(opt)
+    if not fused_convert.lane_fits(arr.size, engine.params.max_size):
+        return None
+    return engine.begin(arr, stages)
 
 
 def _lane_device(pack: _Pack, plan, arr, stages):
-    """The WHOLE layer's files as one two-dispatch device batch; the engine
-    drives ``pack:lane.*`` on ``stages``."""
+    """Every planned file through the device engine, which drives
+    ``pack:lane.*`` on ``stages``: the WHOLE layer as one two-dispatch
+    batch where one lane buffer holds it (the pack began it when it read
+    the layer), else as batches of whole files, each two dispatches, the
+    next one's upload and pass 1 under this one's host work
+    (FusedDeviceEngine.process_batches). Yields once all are in: a batch
+    is not a stretch of the plan's order."""
     # ops/fused_convert — gear+compaction, then gather+digest, the host
     # keeping only cut metadata: the tar is the lane's buffer, the plan's
     # extents its table.
     from nydus_snapshotter_tpu.ops import fused_convert
 
     try:
-        res = _device_engine(pack.opt).process_many(
+        res = _device_engine(pack.opt).process_batches(
             fused_convert.Extents(arr, _extents(plan)), stages=stages, begun=pack.begun
         )
-    except fused_convert.FusedOverflow as e:  # pathological input
+    except fused_convert.FusedOverflow as e:  # one file past the limit, pathological input
         fused_convert.record_host_fallback()
         raise _LaneDeclined(str(e)) from e
     stages.next("pack:dedup")
@@ -1077,8 +1084,10 @@ def read_layer(f: BinaryIO, opt: PackOption):
     ``opt`` leads to the device lane it is read into the head of a zeroed
     buffer of the lane's padded length, so that the lane uploads the
     buffer as it stands (fused_convert.lane_buffer; the untouched tail
-    costs no page), and a view of the tar's own bytes is returned. Any
-    other pack gets the plain ``bytes``."""
+    costs no page), and a view of the tar's own bytes is returned; a
+    layer that no one lane buffer holds into a page-aligned buffer of its
+    own length, runs of which the lane uploads as its batches. Any other
+    pack gets the plain ``bytes``."""
     size = os.fstat(f.fileno()).st_size  # 0 for a pipe
     if not (size and not opt.oci_ref and _device_lane_wanted(opt)):
         return f.read()
@@ -1088,7 +1097,7 @@ def read_layer(f: BinaryIO, opt: PackOption):
     try:
         npad = fused_convert.padded_length(size, cdc.CDCParams(opt.chunk_size).max_size)
     except fused_convert.FusedOverflow:
-        return f.read()  # no lane buffer holds it: the lane will say so
+        npad = size  # it goes up in batches, each padded on the device
     buf = fused_convert.zeroed_buffer(npad)
     view = memoryview(buf)[:size]
     got = 0
@@ -1138,7 +1147,9 @@ def pack_stream(
     ``pack:dedup``, ``pack:compress_write``, ``pack:bootstrap``. A pack
     that leads to the device lane begins it first: ``pack:lane.layout``,
     ``.h2d`` and a first ``.pass1`` (the upload and pass 1 enqueued) come
-    before ``pack:dict_load``, the wait for them after ``pack:scan``.
+    before ``pack:dict_load``, the wait for them after ``pack:scan``; a
+    layer that no one lane buffer holds brings all of ``pack:lane.*`` once
+    a batch, after ``pack:scan``.
 
     ``stats``: optional dict that accumulates per-stage wall seconds, the
     sums of those spans' own times (``_STATS_SPANS``): ``scan`` tar walk +

@@ -31,6 +31,13 @@ on device ONCE and never comes back:
   slices of its rows, one after the other through one compiled loop
   body, so that pass 2's HBM follows the budget and not the layer.
 
+A lane buffer is addressed with int32, so its padded length stays under
+ADDRESS_LIMIT (2 GiB). A layer that pads past it is packed as **batches of
+whole files** (plan_batches, FusedDeviceEngine.process_batches): each batch
+is the two dispatches above on a buffer joined on the device from runs of
+the one host buffer, the next batch's upload and pass 1 enqueued before
+this one's candidates are waited for.
+
 Why two dispatches and not one: the digest stage's shapes depend on the
 resolved cuts. Keeping resolution on device would make bucket geometry
 dynamic, forcing every chunk slot to the 4 MiB max class. Two dispatch
@@ -46,7 +53,9 @@ must produce byte-identical cuts and digests (tests/test_fused_convert.py).
 
 from __future__ import annotations
 
+import bisect
 import functools
+import itertools
 from dataclasses import dataclass
 from time import perf_counter
 
@@ -61,9 +70,12 @@ TAIL = gear.GEAR_WINDOW - 1
 
 
 class FusedOverflow(RuntimeError):
-    """The batch does not fit the lane (a pathological input exceeds the
-    candidate capacity, or int32 addressing): the converter counts it
-    (record_host_fallback) and redoes the batch on its per-file lane."""
+    """The batch does not fit the lane: a pathological input exceeds the
+    candidate capacity, or its buffer pads past int32 addressing. A layer
+    past that limit is packed as batches of whole files (plan_batches,
+    process_batches), so what still raises on the served path is ONE file
+    whose own bytes pad past it; the converter counts it
+    (record_host_fallback) and redoes the layer on its per-file lane."""
 
 
 def _counters():
@@ -156,7 +168,9 @@ def _layout_copied_counter():
 def _early_start_counter():
     """Batches whose upload and pass 1 a caller had enqueued ahead of its
     own host work (FusedDeviceEngine.begin) and that process_many then
-    finished; a begun lane that was dropped counts nowhere. Its own
+    finished; a begun lane that was dropped counts nowhere, and neither
+    do the batches of a split layer, which process_batches begins itself
+    once the caller's scan has given their files (Begun.early). Its own
     accessor for the same reason as _row_floor_counter()."""
     from nydus_snapshotter_tpu.metrics import registry as _metrics
 
@@ -164,6 +178,21 @@ def _early_start_counter():
         _metrics.Counter(
             "ntpu_fused_convert_early_starts_total",
             "Fused batches begun by their caller ahead of its host work and finished",
+        )
+    )
+
+
+def _split_packs_counter():
+    """Layers that no one lane buffer held and that process_batches packed
+    as several batches of whole files; each batch is a dispatch of its own
+    in _counters(). Its own accessor for the same reason as
+    _row_floor_counter()."""
+    from nydus_snapshotter_tpu.metrics import registry as _metrics
+
+    return _metrics.default_registry.register(
+        _metrics.Counter(
+            "ntpu_fused_convert_split_packs_total",
+            "Layers past one lane buffer that were packed as several device batches of whole files",
         )
     )
 
@@ -270,11 +299,20 @@ def class_rows(live: int, row_bytes: int) -> tuple[int, int]:
     return -(-live // tile) * tile, tile
 
 
+# Device ints are 32-bit (no x64): pass 2's chunk offsets address a lane
+# buffer with int32, so a buffer's padded length stays under this, and a
+# layer that pads past it is packed as several batches (plan_batches). A
+# constant of the device, no option: a test reaches the split at a few
+# MiB by patching it.
+ADDRESS_LIMIT = 1 << 31
+
+
 def padded_length(total: int, max_size: int) -> int:
     """Bytes of the lane's device buffer for ``total`` bytes of input cut
     into chunks of at most ``max_size``: the one padding rule of the
-    lane (layout, lane_buffer, and a caller that reads a layer straight
-    into a buffer of this size)."""
+    lane (layout, lane_buffer, a batch of a split layer, and a caller that
+    reads a layer straight into a buffer of this size). Raises
+    FusedOverflow for a ``total`` that pads to ADDRESS_LIMIT or past it."""
     # a window multiple + one max-chunk guard so pass-2 dynamic_slice
     # never clamps a start (clamping would shift the slice and corrupt
     # in-range bytes). The gather reads whole words, one past its class's
@@ -286,18 +324,28 @@ def padded_length(total: int, max_size: int) -> int:
     # full pow2 doubling (which would push a 1.1 GiB batch to 2 GiB)
     step = max(WINDOW, _pow2_ceil(npad) // 8)
     npad = -(-npad // step) * step
-    # Device ints are 32-bit (no x64): pass-2 chunk offsets must
-    # address the buffer with int32. A batch is one layer: 640 MiB for
-    # half of node:21, 1,280 MiB for the jax/jaxlib/libtpu layer of a
-    # training image (benchmark/configs/mlimage-1m.json), and a layer
-    # with one file of a GiB pads past this; a caller with more splits
-    # it below 2 GiB.
-    if npad >= 1 << 31:
+    # A batch is one layer where the layer fits: 640 MiB for half of
+    # node:21, 1,280 MiB for the jax/jaxlib/libtpu layer of a training
+    # image (benchmark/configs/mlimage-1m.json). The pip install tensorflow
+    # layer (2,047 MiB of tar, one file of 1,046 MiB:
+    # benchmark/configs/tfimage-1m.json) would pad to 2,560 MiB:
+    # process_batches splits it at whole files into batches that pass here.
+    if npad >= ADDRESS_LIMIT:
         raise FusedOverflow(
             f"batch of {total} bytes pads to {npad} — beyond int32 "
             "device addressing; split the batch"
         )
     return npad
+
+
+def lane_fits(total: int, max_size: int) -> bool:
+    """Whether ONE lane buffer holds ``total`` bytes: padded_length's limit
+    as a question, for whoever has another way to go (batches)."""
+    try:
+        padded_length(total, max_size)
+    except FusedOverflow:
+        return False
+    return True
 
 
 def zeroed_buffer(npad: int) -> np.ndarray:
@@ -641,10 +689,137 @@ class Extents:
     the buffer as the lane's and the table as it is, and builds no second
     buffer: what lies between two files (a tar header, padding) is hashed
     by pass 1 like any byte and its candidates fall to no file in
-    resolve(), whose seam argument does not ask what precedes a file."""
+    resolve(), whose seam argument does not ask what precedes a file.
+
+    ``runs``: the batch is part of a layer that no one lane buffer holds
+    (plan_batches). Its lane buffer is then these (start, length)
+    stretches of ``data``, ascending, disjoint and whole words, back to
+    back: uploaded as views and joined on the device, no host copy. Every
+    extent of ``table`` (still in ``data``'s offsets) lies inside one of
+    them. ``part``: (which batch of its layer this is, from 1; of how
+    many), for the ``pack:lane.layout`` span."""
 
     data: "bytes | bytearray | np.ndarray"  # 1-D uint8 where an array
     table: list[tuple[int, int]]
+    runs: "tuple[tuple[int, int], ...] | None" = None
+    part: tuple[int, int] = (1, 1)
+
+
+@dataclass(frozen=True)
+class Batch:
+    """One device batch of a layer that no one lane buffer holds: the
+    ``files`` (indices into the layer's extent table, ascending) and the
+    ``runs`` of the layer's buffer that hold them, as Extents.runs."""
+
+    files: tuple[int, ...]
+    runs: tuple[tuple[int, int], ...]
+
+    @property
+    def size(self) -> int:
+        return sum(length for _start, length in self.runs)
+
+
+def plan_batches(table: list[tuple[int, int]], size: int, max_size: int) -> list[Batch]:
+    """A ``size``-byte buffer whose files are ``table`` (ascending,
+    disjoint), as batches of WHOLE files that each pad below ADDRESS_LIMIT,
+    so that resolve(), the cut state and a file's CDC are what they are in
+    one batch and no chunk straddles two.
+
+    The batches partition the buffer: file i brings the stretch from its
+    first byte to the next file's (the first from 0, the last to ``size``),
+    so every tar header, padding and end-of-archive block is uploaded in
+    exactly one batch, and a seam lies where a file starts: a judged
+    candidate sits >= 31 bytes inside its file (resolve), whatever
+    precedes it in the batch's buffer.
+
+    Which files share a batch does not follow their order in the buffer
+    more than it must: a file of at least a quarter of ADDRESS_LIMIT is a
+    batch of its own, and all other files fill batches in the buffer's
+    order up to the limit, those batches first. _pass2 is keyed by each
+    class's row count, and the benchmark's seeds (any two builds of one
+    image) shuffle the members: a greedy split by position gives each
+    order its own pair of programs, this rule gives the pip install
+    tensorflow layer {libtensorflow_cc.so.2} and {the other ~30k files}
+    whatever the order. The many-file batches go first because their
+    host resolve then runs under the next batch's pass 1
+    (process_batches); a one-file batch has nothing to hide.
+
+    Raises FusedOverflow for a file whose own stretch pads past the limit
+    (the cut state would have to cross batches) and for a seam off a word
+    boundary (pass 2 reads words; no tar has one: members start at
+    multiples of 512)."""
+    bounds = [0, *(off for off, _length in table[1:]), size]
+    own_from = ADDRESS_LIMIT // 4
+    filled: list[Batch] = []
+    own: list[Batch] = []
+    files: list[int] = []
+    runs: list[tuple[int, int]] = []
+    total = 0
+    for i, (_off, length) in enumerate(table):
+        start, stretch = bounds[i], bounds[i + 1] - bounds[i]
+        if length >= own_from:
+            own.append(Batch((i,), ((start, stretch),)))
+            continue
+        if files and not lane_fits(total + stretch, max_size):
+            filled.append(Batch(tuple(files), tuple(runs)))
+            files, runs, total = [], [], 0
+        if runs and sum(runs[-1]) == start:
+            runs[-1] = (runs[-1][0], runs[-1][1] + stretch)
+        else:
+            runs.append((start, stretch))
+        files.append(i)
+        total += stretch
+    if files:
+        filled.append(Batch(tuple(files), tuple(runs)))
+    batches = filled + own
+    for batch in batches:
+        if not lane_fits(batch.size, max_size):
+            largest = max(table[i][1] for i in batch.files)
+            raise FusedOverflow(
+                f"one file of {largest} bytes (a stretch of {batch.size}) pads past int32 "
+                "device addressing on its own; a batch is whole files"
+            )
+        if any(start % 4 or length % 4 for start, length in batch.runs):
+            raise FusedOverflow(f"a batch's runs {batch.runs} do not start and end on a word")
+    return batches
+
+
+@functools.partial(jax.jit, static_argnames=("length",))
+def _join(pieces: tuple[jax.Array, ...], length: int) -> jax.Array:
+    """The lane buffer of a batch of runs, made on the device: the pieces
+    back to back, zeros up to ``length``. One program per set of run
+    lengths, and the runs' lengths follow the members' order in the tar,
+    so it has to compile in no time: as slices written into zeros the
+    chip's compiler takes 0.07-0.09 s for two runs of 0.5 GiB, as one
+    concatenate of u8 1.1-1.4 s, which is also past the 1 s from which JAX
+    writes a program to its persistent cache and counts a miss (compiled
+    for a described v5e, PR 36)."""
+    out, at = jnp.zeros(length, pieces[0].dtype), 0
+    for piece in pieces:
+        out = jax.lax.dynamic_update_slice(out, piece, (at,))
+        at += piece.shape[0]
+    return out
+
+
+def _upload(pieces: list[np.ndarray], length: int) -> tuple[jax.Array, int]:
+    """Host arrays -> (the device array of ``length`` they make back to
+    back, nothing waited for; the _join programs that compiled, 0 or 1).
+    A lane buffer that is one host array goes up as it stands; a batch of
+    runs as views of the layer's buffer, joined and zero-padded on the
+    device (_join: one small program per set of run lengths, compiled on
+    first use and kept by the process: no compile counter sees it, so
+    the caller's span says so; by the jit cache's size before and after,
+    so of two packs at once either may count the other's)."""
+    if len(pieces) == 1 and pieces[0].shape[0] == length:
+        return jnp.asarray(pieces[0]), 0
+    had = _join._cache_size()
+    joined = _join(tuple(jnp.asarray(p) for p in pieces), length)
+    return joined, _join._cache_size() - had
+
+
+def _u8(data) -> np.ndarray:
+    """bytes, bytearray or a u8 array, as the u8 array (a view, no copy)."""
+    return np.frombuffer(data, dtype=np.uint8) if isinstance(data, (bytes, bytearray)) else data
 
 
 def _checked_table(streams: Extents, size: int) -> list[tuple[int, int]]:
@@ -669,15 +844,22 @@ class Begun:
     n: int  # valid bytes of the buffer
     copied: int  # bytes layout copied to build it
     before: dict[str, float]  # the Stages' seconds at begin: the batch's own are what it adds
-    # the host buffer, alive and unwritten until the upload is done (None:
-    # a batch without a byte, nothing enqueued)
-    buf: "np.ndarray | None" = None
+    # the host arrays that went up (the lane buffer, or a batch's runs of
+    # it), alive and unwritten until the upload is done (None: a batch
+    # without a byte, nothing enqueued)
+    buf: "list[np.ndarray] | None" = None
     buffer_dev: "jax.Array | None" = None  # u8[NP], pass 1's operand: None once its candidates are on the host
     words_dev: "jax.Array | None" = None  # u32[NP / 4], the same bytes as pass 2 gathers them (lane_words)
     words: tuple = ()  # _pass1's six outputs, still on the device
     wcap_s: int = 0
     wcap_l: int = 0
     t0: float = 0.0  # perf_counter at the enqueue's start
+    # seconds since t0 that the host spent on other batches of the same
+    # layer (process_batches): cover for this one's upload and pass 1
+    sibling_s: float = 0.0
+    # begun by the caller ahead of host work of its own (False: by
+    # process_batches itself, after the caller's scan: no early start)
+    early: bool = True
 
     def drop_bytes(self) -> None:
         """Free pass 1's u8 operand: pass 2 reads the words, and the two
@@ -972,31 +1154,54 @@ class FusedDeviceEngine:
         return sha256.digest_to_bytes(state_row)
 
     def _lay(self, streams):
-        """The layout stage -> (u8[padded_length] lane buffer, or None for
-        a batch without a byte; its [(offset, length)] table, or None for
-        a bare buffer, whose extents come later; the valid bytes; the
-        bytes copied to build it)."""
+        """The layout stage -> (the host arrays that make the lane buffer
+        back to back, or None for a batch without a byte; its
+        padded_length; its [(offset, length)] table in the buffer's own
+        offsets, or None for a bare buffer, whose extents come later; the
+        valid bytes; the bytes copied to build it)."""
+        if isinstance(streams, Extents) and streams.runs is not None:
+            return self._lay_runs(streams)
         if isinstance(streams, (Extents, bytes, bytearray, np.ndarray)):
-            data = streams.data if isinstance(streams, Extents) else streams
-            arr = np.frombuffer(data, dtype=np.uint8) if isinstance(data, (bytes, bytearray)) else data
+            arr = _u8(streams.data if isinstance(streams, Extents) else streams)
             if isinstance(streams, Extents):
                 table = _checked_table(streams, arr.size)
                 empty = not any(length for _off, length in table)
             else:
                 table, empty = None, not arr.size
             if empty:
-                return None, table, 0, 0
+                return None, 0, table, 0, 0
             buf, copied = lane_buffer(arr, padded_length(arr.size, self.params.max_size))
-            return buf, table, arr.size, copied
-        arrs = [
-            np.frombuffer(s, dtype=np.uint8) if isinstance(s, (bytes, bytearray)) else s
-            for s in streams
-        ]
+            return [buf], buf.size, table, arr.size, copied
+        arrs = [_u8(s) for s in streams]
         n = sum(a.size for a in arrs)
         if n == 0:
-            return None, [(0, 0)] * len(arrs), 0, 0
+            return None, 0, [(0, 0)] * len(arrs), 0, 0
         buf, table = self.layout(arrs)
-        return buf, table, n, n
+        return [buf], buf.size, table, n, n
+
+    def _lay_runs(self, streams: Extents):
+        """_lay for a batch of runs: the runs as views of the layer's
+        buffer, the table moved from the layer's offsets to the batch's."""
+        arr = _u8(streams.data)
+        runs = [(int(start), int(length)) for start, length in streams.runs]
+        ends = [start + length for start, length in runs]
+        if any(
+            start < 0 or length < 0 or start % 4 or length % 4 for start, length in runs
+        ) or any(end > nxt for end, (nxt, _l) in zip(ends, runs[1:] + [(arr.size, 0)])):
+            raise ValueError(f"runs {runs} are not ascending disjoint whole words of a {arr.size}-byte buffer")
+        starts = [start for start, _length in runs]
+        bases = [0, *itertools.accumulate(length for _start, length in runs)]
+        table = []
+        for off, length in _checked_table(streams, arr.size):
+            j = max(bisect.bisect_right(starts, off) - 1, 0)
+            if not starts[j] <= off <= off + length <= ends[j]:
+                raise ValueError(f"the extent ({off}, {length}) lies in no run of its batch")
+            table.append((off - starts[j] + bases[j], length))
+        n = bases[-1]
+        if not any(length for _off, length in table):
+            return None, 0, table, 0, 0
+        pieces = [arr[start:end] for start, end in zip(starts, ends) if end > start]
+        return pieces, padded_length(n, self.params.max_size), table, n, 0
 
     def begin(self, streams, stages) -> "Begun":
         """The lane's first half: the buffer made ready, its upload and
@@ -1016,28 +1221,35 @@ class FusedDeviceEngine:
         # propagates — callers redo a batch on the host lanes only for
         # FusedOverflow, and count it).
         failpoint.hit("fused.dispatch")
-        before = dict(stages.seconds)  # it sums by name over all it ran
         stages.next("pack:lane.layout")
-        buf, table, n, copied = self._lay(streams)
+        # it sums by name over all it ran; taken with the caller's stage closed
+        # (a batch begun after the scan: the scan is no cover for it)
+        before = dict(stages.seconds)
+        pieces, npad, table, n, copied = self._lay(streams)
         data = streams.data if isinstance(streams, Extents) else streams
-        if buf is None:
+        if pieces is None:
             return Begun(data, table, 0, 0, before)
-        stages.annotate(bytes=n, padded_bytes=int(buf.size), copied_bytes=copied)
+        batch, batches = streams.part if isinstance(streams, Extents) else (1, 1)
+        stages.annotate(
+            bytes=n, padded_bytes=npad, copied_bytes=copied,
+            batch=batch, batches=batches, runs=len(pieces),
+        )
         # committed to the default device. From here to word_counts these
         # two leaves are the host's side of asynchronous device work: the
         # call that enqueues it, not the work.
-        t0 = stages.next("pack:lane.h2d", bytes=int(buf.size)).t0
-        buffer_dev = jnp.asarray(buf)
+        t0 = stages.next("pack:lane.h2d", bytes=sum(p.size for p in pieces)).t0
+        buffer_dev, joins = _upload(pieces, npad)
+        stages.annotate(join_compiles=joins)
         # a first call of a new buffer length compiles here
         stages.next("pack:lane.pass1")
         words, wcap_s, wcap_l = self.enqueue_pass1(buffer_dev, n)
-        stages.annotate(wcap_s=wcap_s, wcap_l=wcap_l)
         # the same bytes once more, as the words pass 2 gathers from: enqueued
         # behind the bytes and the call, so the upload runs under _pass1 and
         # nothing waits for it before pass 2 is dispatched
-        words_dev = jnp.asarray(lane_words(buf))
+        words_dev, joins = _upload([lane_words(p) for p in pieces], npad // 4)
+        stages.annotate(wcap_s=wcap_s, wcap_l=wcap_l, join_compiles=joins)
         return Begun(
-            data, table, n, copied, before, buf, buffer_dev, words_dev, words, wcap_s, wcap_l, t0
+            data, table, n, copied, before, pieces, buffer_dev, words_dev, words, wcap_s, wcap_l, t0
         )
 
     def process_many(
@@ -1068,14 +1280,14 @@ class FusedDeviceEngine:
         # is the only clock read at its boundary, and the stage counters
         # and the caller's stats are fed from the spans' own seconds.
         with (trace.Stages() if stages is None else stages) as lane:
-            early = begun is not None
-            if not early:
+            early = begun is not None and begun.early
+            if begun is None:
                 begun = self.begin(streams, lane)
-                table = begun.table
-            elif isinstance(streams, Extents) and streams.data is begun.data:
-                table = _checked_table(streams, begun.n)
-            else:
+            elif not (isinstance(streams, Extents) and streams.data is begun.data):
                 raise ValueError("the lane was begun on another buffer than these extents lie in")
+            # begun on the bare buffer, the extents come now; begun on
+            # Extents (a batch of runs), the table is the batch's already
+            table = begun.table if begun.table is not None else _checked_table(streams, begun.n)
             n = begun.n
             if begun.words_dev is None or not any(length for _off, length in table):
                 return FusedResult(
@@ -1085,14 +1297,16 @@ class FusedDeviceEngine:
                 )
             # the wait for what begin enqueued. covered_s: what of window_s
             # (the enqueue's start to the counts on the host) the caller
-            # spent in stages of its own, not waiting in the lane's
+            # spent in stages of its own, not waiting in the lane's, or on
+            # another batch of the same layer (sibling_s)
             lane.next("pack:lane.pass1")
             nw_s, nw_l = self.word_counts(begun.words, begun.wcap_s, begun.wcap_l)
             lane.annotate(
                 words_s=nw_s,
                 words_l=nw_l,
                 window_s=perf_counter() - begun.t0,
-                covered_s=sum(
+                covered_s=begun.sibling_s
+                + sum(
                     s - begun.before.get(name, 0.0)
                     for name, s in lane.seconds.items()
                     if not name.startswith("pack:lane.")
@@ -1181,3 +1395,80 @@ class FusedDeviceEngine:
             row_tiles=row_tiles,
         )
         return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np)
+
+    def process_batches(
+        self,
+        streams: Extents,
+        chunk_dict: tuple[np.ndarray, np.ndarray] | None = None,
+        depth: int = 8,
+        probe_kernel: str = "auto",
+        dict_epoch: int | None = None,
+        stages=None,
+        begun: "Begun | None" = None,
+    ) -> FusedResult:
+        """process_many for a layer of any size: where no one lane buffer
+        holds ``streams.data``, its files go as batches of whole files
+        (plan_batches), each through begin and process_many on runs of the
+        same host buffer, and the result is one, in the table's order. A
+        layer that fits is process_many's own one batch, ``begun`` by the
+        caller or not (what was begun whole fits).
+
+        Batch k+1 is begun (its upload and pass 1 enqueued) before batch
+        k's candidates are waited for, so the device works on it while the
+        host resolves and plans k; at most two batches are on the device.
+        Each batch is a dispatch of the counters, fed with the leaves that
+        ran since the one before it, and the layer counts once in
+        ntpu_fused_convert_split_packs_total."""
+        from nydus_snapshotter_tpu import trace
+
+        data, max_size = streams.data, self.params.max_size
+        size = _u8(data).size
+        if begun is not None or lane_fits(size, max_size):
+            return self.process_many(
+                streams, chunk_dict, depth, probe_kernel, dict_epoch, stages, begun
+            )
+        table = _checked_table(streams, size)
+        plan = plan_batches(table, size, max_size)
+        parts = [
+            Extents(data, [table[i] for i in batch.files], batch.runs, (k + 1, len(plan)))
+            for k, batch in enumerate(plan)
+        ]
+        started: list[Begun] = []
+        results: list[FusedResult] = []
+        with (trace.Stages() if stages is None else stages) as lane:
+            try:
+                for k, part in enumerate(parts):
+                    for nxt in parts[len(started) : k + 2]:
+                        started.append(self.begin(nxt, lane))
+                        started[-1].early = False
+                    t0 = perf_counter()
+                    results.append(
+                        self.process_many(
+                            part, chunk_dict, depth, probe_kernel, dict_epoch, lane, started[k]
+                        )
+                    )
+                    started[k].close()
+                    if k + 1 < len(parts):
+                        # what the next batch adds to the counters starts here, and
+                        # this batch's lane leaves were cover for its upload and pass 1
+                        started[k + 1].before = dict(lane.seconds)
+                        started[k + 1].sibling_s = perf_counter() - t0
+            finally:
+                for b in started:
+                    b.close()
+        if parts:
+            _split_packs_counter().inc()
+        cuts: list = [None] * len(table)
+        digests: list = [None] * len(table)
+        probes: list = [None] * len(table)
+        for batch, res in zip(plan, results):
+            pos = 0
+            for i, f_cuts, f_digests in zip(batch.files, res.cuts, res.digests):
+                cuts[i], digests[i] = f_cuts, f_digests
+                if res.probe is not None:
+                    probes[i] = res.probe[pos : pos + len(f_cuts)]
+                    pos += len(f_cuts)
+        probe = None
+        if chunk_dict is not None:
+            probe = np.concatenate([np.zeros(0, np.int32), *probes]).astype(np.int32)
+        return FusedResult(cuts=cuts, digests=digests, probe=probe)

@@ -144,7 +144,7 @@ def test_pack_leaf_names_are_the_tables(work, backend, with_dict):
         # upload's enqueue to the counts on the host, covered_s is what of it the host spent
         # in pack:dict_load and pack:scan
         call, wait = [s for s in leaves if s.name == "pack:lane.pass1"]
-        assert set(call.attrs) == {"wcap_s", "wcap_l"}
+        assert set(call.attrs) == {"wcap_s", "wcap_l", "join_compiles"} and call.attrs["join_compiles"] == 0
         assert set(wait.attrs) == {"words_s", "words_l", "window_s", "covered_s"}
         between = [s for s in leaves if call.t0 < s.t0 < wait.t0]
         assert [s.name for s in between] == ["pack:dict_load", "pack:scan"][not with_dict:]

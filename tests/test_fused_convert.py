@@ -368,9 +368,11 @@ class TestExtentsEntry:
         assert fused_convert.padded_length(last_ok, max_size) == (1 << 31) - step
         with pytest.raises(fused_convert.FusedOverflow):
             fused_convert.padded_length(last_ok + 1, max_size)
-        with pytest.raises(fused_convert.FusedOverflow):
-            fused_convert.FusedDeviceEngine(chunk_size=chunk_size).process_many(
-                fused_convert.Extents(np.zeros(last_ok + 1, dtype=np.uint8), [(0, 1)])
+        # a layer past it goes as batches of whole files (tests/test_lane_batches.py);
+        # what still declines is ONE file whose own bytes pad past it, and says its size
+        with pytest.raises(fused_convert.FusedOverflow, match=f"one file of {last_ok + 1} bytes"):
+            fused_convert.FusedDeviceEngine(chunk_size=chunk_size).process_batches(
+                fused_convert.Extents(np.zeros(last_ok + 1, dtype=np.uint8), [(0, last_ok + 1)])
             )
 
 

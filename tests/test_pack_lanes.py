@@ -67,6 +67,11 @@ LANE_TABLE = [
      dict(threads=1, host_fused=False), "_lane_device"),
     ("--backend fused with threads and a dictionary", opt("fused"), dict(threads=8, host_fused=False, has_dict=True),
      "_lane_device"),
+    # the lane is not picked by the layer's size: one that no lane buffer holds is still the device
+    # lane's, which packs it as batches of whole files (tests/test_lane_batches.py); choose_lane sees
+    # its size nowhere, and _begin_device_lane having begun nothing for it changes nothing here
+    ("--backend fused and a layer past one lane buffer: the device lane, in batches", opt("fused"),
+     dict(threads=13, host_fused=False, seeded=False), "_lane_device"),
     ("the device lane cuts CDC only", opt("fused", chunking="fixed"), dict(threads=1, host_fused=False),
      "_lane_per_file"),
     ("the device lane declined (FusedOverflow)", opt("fused"),
