@@ -62,7 +62,7 @@ def cmd_pack(args) -> int:
     # pack_stream's stages follow pack:read under it (docs/observability.md).
     # Spans leave through the trace ring, never through the result line.
     with trace.batch_span("convert.pack"):
-        with trace.span("pack:read") as sp, open(args.input, "rb") as f:
+        with trace.leaf("pack:read") as sp, open(args.input, "rb") as f:
             src = read_layer(f, opt)
             sp.annotate(bytes=len(src))
         if args.oci_ref:
@@ -77,7 +77,7 @@ def cmd_pack(args) -> int:
                               "chunks": len(bootstrap.chunks)}))
             return 0
         # replacing a blob of the same name frees its pages here: a stage
-        with trace.span("pack:open_out") as sp:
+        with trace.leaf("pack:open_out") as sp:
             if os.path.exists(args.out):
                 sp.annotate(replaced_bytes=os.path.getsize(args.out))
             out = open(args.out, "wb")
@@ -98,7 +98,7 @@ def cmd_merge(args) -> int:
 
     with trace.batch_span("convert.merge"):
         layers = []
-        with trace.span("merge:read", layers=len(args.layers)) as sp:
+        with trace.leaf("merge:read", layers=len(args.layers)) as sp:
             for path in args.layers:
                 with open(path, "rb") as f:
                     layers.append(f.read())
@@ -113,7 +113,7 @@ def cmd_merge(args) -> int:
                 digester=getattr(args, "digester", "sha256"),
             ),
         )
-        with trace.span("merge:emit", bytes=len(res.bootstrap)), open(args.out, "wb") as f:
+        with trace.leaf("merge:emit", bytes=len(res.bootstrap)), open(args.out, "wb") as f:
             f.write(res.bootstrap)
     print(json.dumps({"blob_digests": res.blob_digests}))
     return 0

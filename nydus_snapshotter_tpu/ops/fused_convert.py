@@ -123,55 +123,14 @@ def _counters():
     )
 
 
-def _row_floor_counter():
-    """Digest classes the bucket plan lifted to ROW_FLOOR rows
-    (bucket_rows). Beside _counters(), whose four the benchmark and
-    chip_smoke.py unpack by position."""
-    from nydus_snapshotter_tpu.metrics import registry as _metrics
-
-    return _metrics.default_registry.register(
-        _metrics.Counter(
-            "ntpu_fused_convert_row_floor_classes_total",
-            "Digest classes of fused batches padded up to the row floor",
-        )
-    )
-
-
-def _row_tiles_counter():
-    """Row tiles beyond the first that pass 2 ran its classes in
-    (class_rows): 0 for a batch whose every class fit TILE_BYTES. Its own
-    accessor for the same reason as _row_floor_counter()."""
-    from nydus_snapshotter_tpu.metrics import registry as _metrics
-
-    return _metrics.default_registry.register(
-        _metrics.Counter(
-            "ntpu_fused_convert_row_tiles_total",
-            "Row tiles beyond one that fused batches' digest classes were split into",
-        )
-    )
-
-
-def _layout_copied_counter():
-    """Bytes the layout stage copied on the host to build a lane buffer:
-    0 for a batch whose input already was one (lane_buffer). Its own
-    accessor for the same reason as _row_floor_counter()."""
-    from nydus_snapshotter_tpu.metrics import registry as _metrics
-
-    return _metrics.default_registry.register(
-        _metrics.Counter(
-            "ntpu_fused_convert_layout_copied_bytes_total",
-            "Bytes copied on the host into fused batches' padded buffers",
-        )
-    )
-
-
 def _early_start_counter():
     """Batches whose upload and pass 1 a caller had enqueued ahead of its
     own host work (FusedDeviceEngine.begin) and that process_many then
     finished; a begun lane that was dropped counts nowhere, and neither
     do the batches of a split layer, which process_batches begins itself
-    once the caller's scan has given their files (Begun.early). Its own
-    accessor for the same reason as _row_floor_counter()."""
+    once the caller's scan has given their files (Begun.early). Beside
+    _counters(), whose four the benchmark and chip_smoke.py unpack by
+    position."""
     from nydus_snapshotter_tpu.metrics import registry as _metrics
 
     return _metrics.default_registry.register(
@@ -186,7 +145,7 @@ def _split_packs_counter():
     """Layers that no one lane buffer held and that process_batches packed
     as several batches of whole files; each batch is a dispatch of its own
     in _counters(). Its own accessor for the same reason as
-    _row_floor_counter()."""
+    _early_start_counter()."""
     from nydus_snapshotter_tpu.metrics import registry as _metrics
 
     return _metrics.default_registry.register(
@@ -197,20 +156,10 @@ def _split_packs_counter():
     )
 
 
-def _record_dispatch(
-    n_bytes: int,
-    stage_seconds: dict[str, float],
-    row_floor_classes: int = 0,
-    copied_bytes: int = 0,
-    early_start: bool = False,
-    row_tiles: int = 0,
-) -> None:
+def _record_dispatch(n_bytes: int, stage_seconds: dict[str, float], early_start: bool = False) -> None:
     disp, by_bytes, busy, _ = _counters()
     disp.inc()
     by_bytes.inc(n_bytes)
-    _row_floor_counter().inc(row_floor_classes)
-    _row_tiles_counter().inc(row_tiles)
-    _layout_copied_counter().inc(copied_bytes)
     _early_start_counter().inc(int(early_start))
     for stage, seconds in stage_seconds.items():
         busy.labels(stage).inc(seconds)
@@ -842,7 +791,6 @@ class Begun:
     data: object  # what it was begun on: process_many's Extents hold this very object
     table: "list[tuple[int, int]] | None"  # None: a bare buffer, the extents come with process_many
     n: int  # valid bytes of the buffer
-    copied: int  # bytes layout copied to build it
     before: dict[str, float]  # the Stages' seconds at begin: the batch's own are what it adds
     # the host arrays that went up (the lane buffer, or a batch's runs of
     # it), alive and unwritten until the upload is done (None: a batch
@@ -1228,7 +1176,7 @@ class FusedDeviceEngine:
         pieces, npad, table, n, copied = self._lay(streams)
         data = streams.data if isinstance(streams, Extents) else streams
         if pieces is None:
-            return Begun(data, table, 0, 0, before)
+            return Begun(data, table, 0, before)
         batch, batches = streams.part if isinstance(streams, Extents) else (1, 1)
         stages.annotate(
             bytes=n, padded_bytes=npad, copied_bytes=copied,
@@ -1249,7 +1197,7 @@ class FusedDeviceEngine:
         words_dev, joins = _upload([lane_words(p) for p in pieces], npad // 4)
         stages.annotate(wcap_s=wcap_s, wcap_l=wcap_l, join_compiles=joins)
         return Begun(
-            data, table, n, copied, before, pieces, buffer_dev, words_dev, words, wcap_s, wcap_l, t0
+            data, table, n, before, pieces, buffer_dev, words_dev, words, wcap_s, wcap_l, t0
         )
 
     def process_many(
@@ -1331,16 +1279,14 @@ class FusedDeviceEngine:
             buckets, order = self.plan_buckets(table, cuts)
             # rows the floor added to each class, beyond its power of two
             floored = [max(0, len(b.offsets) - _pow2_ceil(b.count)) for b in buckets]
-            floored_classes = sum(1 for r in floored if r)
-            row_tiles = sum(b.tiles - 1 for b in buckets)
             lane.annotate(
                 classes=[[b.cap_blocks, b.count, len(b.offsets)] for b in buckets],
                 blocks_real=sum(b.blocks for b in buckets),
                 blocks_padded=sum(len(b.offsets) * b.cap_blocks for b in buckets),
-                row_floor_classes=floored_classes,
+                row_floor_classes=sum(1 for r in floored if r),
                 row_floor_rows=sum(floored),
                 blocks_tiled=sum(len(b.offsets) * b.cap_blocks for b in buckets if b.tiles > 1),
-                row_tiles=row_tiles,
+                row_tiles=sum(b.tiles - 1 for b in buckets),
                 batch_mib_max=max(len(b.offsets) // b.tiles * b.cap_blocks for b in buckets)
                 * self._unit_bytes()
                 / 2**20,
@@ -1389,10 +1335,7 @@ class FusedDeviceEngine:
                 "pass2_digest": took["pack:lane.pass2"],
                 "digest_d2h": took["pack:lane.digest_d2h"],
             },
-            row_floor_classes=floored_classes,
-            copied_bytes=begun.copied,
             early_start=early,
-            row_tiles=row_tiles,
         )
         return FusedResult(cuts=cuts, digests=out_digests, probe=probe_np)
 

@@ -32,7 +32,10 @@ flat partition of consecutive leaf spans (:class:`Stages`) under a
 :func:`batch_span` root, feed their stage counters from the leaves' own
 seconds (:func:`stage`), and — once a process that has JAX loaded calls
 :func:`install_profiler_bridge` — show on the JAX profiler's host plane
-(docs/observability.md). This module itself never imports JAX.
+(docs/observability.md). A recorded leaf also says what its thread did
+in its wall: CPU seconds, blocks, preemptions and collector seconds,
+read once a boundary like the clock. This module itself never imports
+JAX.
 
 Zero-overhead contract (gated by ``tools/trace_profile.py``): with
 tracing disabled, :func:`span` is one global load, one branch and a
@@ -47,6 +50,7 @@ the env is also how the section reaches spawned daemon processes.
 from __future__ import annotations
 
 import functools
+import gc
 import itertools
 import json
 import os
@@ -58,6 +62,11 @@ from contextvars import ContextVar
 from dataclasses import dataclass
 from time import perf_counter
 from typing import Iterator, Optional
+
+try:
+    import resource
+except ImportError:  # not a Unix
+    resource = None
 
 from nydus_snapshotter_tpu.metrics import registry as _metrics
 from nydus_snapshotter_tpu.trace.export import (
@@ -86,6 +95,7 @@ __all__ = [
     "enabled",
     "exemplars",
     "install_profiler_bridge",
+    "leaf",
     "remote_context",
     "reset",
     "resolve_trace_config",
@@ -451,15 +461,22 @@ def _retire_tracer_locked() -> None:
     if _tracer is not None:
         _spans_base += _tracer.ring.pushes()
         _drops_base += _tracer.ring.dropped()
+    _watch_collector(False)
+
+
+def _install_locked(cfg: TraceRuntimeConfig) -> None:
+    """The tracer ``cfg`` asks for, and the collector's clock where it
+    can record a span. Caller holds ``_init_lock``."""
+    global _tracer, _initialized
+    _tracer = Tracer(cfg) if cfg.enabled else None
+    _initialized = True
+    _watch_collector(_tracer is not None and cfg.sample_ratio > 0)
 
 
 def _init() -> Optional[Tracer]:
-    global _tracer, _initialized
     with _init_lock:
         if not _initialized:
-            cfg = resolve_trace_config()
-            _tracer = Tracer(cfg) if cfg.enabled else None
-            _initialized = True
+            _install_locked(resolve_trace_config())
         return _tracer
 
 
@@ -509,23 +526,71 @@ def stage(name: str, /, **attrs):
     return s if isinstance(s, Span) else _Stopwatch(name)
 
 
+# What a recorded leaf's thread did inside it, read once a boundary:
+# getrusage(RUSAGE_THREAD) (Linux) and the seconds of the collections that
+# ran on the thread, which _collector_clock adds up while a tracer that
+# samples is installed.
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+_gc_tls = threading.local()
+
+
+def _collector_clock(phase: str, info: dict, _clock=perf_counter, _tls=_gc_tls) -> None:
+    """The ``gc.callbacks`` entry: a collection's seconds onto the total
+    of the thread it ran on. Its names are bound at definition, so a
+    collection late in interpreter shutdown finds them too."""
+    now = _clock()
+    if phase == "start":
+        _tls.t0 = now
+    else:
+        _tls.seconds = getattr(_tls, "seconds", 0.0) + now - getattr(_tls, "t0", now)
+
+
+def _watch_collector(on: bool) -> None:
+    """One ``gc.callbacks`` entry of ours while ``on``, none otherwise."""
+    if on and _collector_clock not in gc.callbacks:
+        gc.callbacks.append(_collector_clock)
+    elif not on:
+        while _collector_clock in gc.callbacks:
+            gc.callbacks.remove(_collector_clock)
+
+
+def _thread_usage() -> tuple:
+    """(CPU seconds, voluntary and involuntary context switches, collector
+    seconds, thread id) of the calling thread, so far."""
+    r = resource.getrusage(_RUSAGE_THREAD)
+    return (r.ru_utime + r.ru_stime, r.ru_nvcsw, r.ru_nivcsw, getattr(_gc_tls, "seconds", 0.0),
+            threading.get_ident())
+
+
 class Stages:
     """A flat partition of one operation's wall into consecutive leaf
     spans: ``next(name)`` closes the running stage and opens the next, so
     nothing lies between two stages; ``seconds`` holds the sum per name.
     Use as a context manager: leaving it (an error too) closes the
-    running stage."""
+    running stage.
 
-    __slots__ = ("seconds", "_cur")
+    A leaf that is a recorded span also gets, on close, what its thread
+    did inside it: ``cpu_s`` (user + system seconds), ``waits`` (times it
+    blocked: the interpreter lock, a futex, I/O, a device wait),
+    ``preempts`` (times the kernel took its core) and ``gc_s`` (seconds of
+    collections that ran on it). One usage reading a boundary serves the
+    leaf that closes and the one that opens; a leaf closed on another
+    thread than it opened on, or on a platform without ``RUSAGE_THREAD``,
+    carries none of them. A stopwatch leaf reads nothing."""
+
+    __slots__ = ("seconds", "_cur", "_usage")
 
     def __init__(self):
         self.seconds: dict[str, float] = {}
         self._cur = None
+        self._usage = None  # the thread's usage when the running leaf opened
 
     def next(self, name: str, /, **attrs):
-        self.close()
+        usage = self._close()
         self._cur = cur = stage(name, **attrs)
         cur.__enter__()
+        if type(cur) is Span and _RUSAGE_THREAD is not None:
+            self._usage = usage or _thread_usage()
         return cur
 
     @property
@@ -538,12 +603,31 @@ class Stages:
             self._cur.annotate(**attrs)
 
     def close(self) -> None:
+        self._close()
+
+    def _close(self) -> Optional[tuple]:
+        """Close the running stage -> the usage read at this boundary, or
+        None where the stage read none."""
         cur = self._cur
         if cur is None:
-            return
+            return None
         self._cur = None
+        start, self._usage = self._usage, None
+        usage = None
+        if start is not None:
+            usage = _thread_usage()
+            if usage[4] == start[4]:
+                cur.attrs.update(
+                    cpu_s=usage[0] - start[0],
+                    waits=usage[1] - start[1],
+                    preempts=usage[2] - start[2],
+                    gc_s=usage[3] - start[3],
+                )
+            else:
+                usage = None
         cur.end()
         self.seconds[cur.name] = self.seconds.get(cur.name, 0.0) + cur.seconds
+        return usage
 
     def __enter__(self) -> "Stages":
         return self
@@ -551,6 +635,14 @@ class Stages:
     def __exit__(self, *exc) -> bool:
         self.close()
         return False
+
+
+@contextmanager
+def leaf(name: str, /, **attrs) -> Iterator:
+    """One leaf on its own, where no :class:`Stages` runs (a verb's steps
+    around the library call): timed and read like a stage."""
+    with Stages() as one:
+        yield one.next(name, **attrs)
 
 
 def install_profiler_bridge(annotation) -> None:
@@ -632,7 +724,6 @@ def configure(
     sample_ratio: float = 1.0,
 ) -> Optional[Tracer]:
     """Install a tracer explicitly (tests, tools); bypasses env/config."""
-    global _tracer, _initialized
     cfg = TraceRuntimeConfig(
         enabled=enabled,
         ring_capacity=max(1, ring_capacity),
@@ -641,8 +732,7 @@ def configure(
     )
     with _init_lock:
         _retire_tracer_locked()
-        _tracer = Tracer(cfg) if enabled else None
-        _initialized = True
+        _install_locked(cfg)
         return _tracer
 
 

@@ -13,6 +13,7 @@ import tarfile
 import numpy as np
 import pytest
 
+from nydus_snapshotter_tpu import trace
 from nydus_snapshotter_tpu.ops import cdc, fused_convert, mesh_pack
 from nydus_snapshotter_tpu.ops.chunker import ChunkDigestEngine
 from nydus_snapshotter_tpu.parallel.sharded_dict import (
@@ -306,12 +307,15 @@ class TestExtentsEntry:
                 big[:] = np.random.default_rng(93).integers(0, 256, npad, dtype=np.uint8)
             big[: len(tar)] = np.frombuffer(tar, dtype=np.uint8)
             data, copied_want = big[: len(tar)], 0
-        copied = fused_convert._layout_copied_counter()
-        before = copied.value()
-        got = eng.process_many(
-            fused_convert.Extents(data, extents), chunk_dict=chunk_dict, depth=depth
-        )
-        assert copied.value() - before == copied_want
+        trace.configure(enabled=True)
+        try:
+            got = eng.process_many(
+                fused_convert.Extents(data, extents), chunk_dict=chunk_dict, depth=depth
+            )
+            (layout,) = [s.attrs for s in trace.snapshot_spans() if s.name == "pack:lane.layout"]
+        finally:
+            trace.reset()
+        assert layout["copied_bytes"] == copied_want
 
         assert len(got.cuts) == len(extents)
         for i, (g, w) in enumerate(zip(got.cuts, want.cuts)):

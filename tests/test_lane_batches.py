@@ -76,7 +76,9 @@ def counters() -> dict:
         "dispatches": disp.value(), "bytes": by_bytes.value(), "host_fallbacks": fallbacks.value(),
         "split_packs": fused_convert._split_packs_counter().value(),
         "early_starts": fused_convert._early_start_counter().value(),
-        "copied": fused_convert._layout_copied_counter().value(),
+        # what the layout stage copied, by the spans of the test's own ring
+        "copied": sum(s.attrs.get("copied_bytes", 0) for s in trace.snapshot_spans()
+                      if s.name == "pack:lane.layout"),
         "stage_seconds": sum(stages.value(s) for s in
                              ("layout", "h2d", "pass1_gear", "host_resolve", "pass2_digest", "digest_d2h")),
     }

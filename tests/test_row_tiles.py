@@ -55,9 +55,12 @@ def test_tiled_classes_give_the_one_batchs_cuts_digests_and_probe(monkeypatch, d
     whole = eng.process_many(streams, chunk_dict=table, depth=depth)
 
     monkeypatch.setattr(fused_convert, "TILE_BYTES", fit_rows * eng.max_read_span())
-    counter = fused_convert._row_tiles_counter()
-    before = counter.value()
-    tiled = eng.process_many(streams, chunk_dict=table, depth=depth)
+    trace.configure(enabled=True)
+    try:
+        tiled = eng.process_many(streams, chunk_dict=table, depth=depth)
+        (plan,) = [s.attrs for s in trace.snapshot_spans() if s.name == "pack:lane.plan"]
+    finally:
+        trace.reset()
     for i, (got, want) in enumerate(zip(tiled.cuts, whole.cuts)):
         np.testing.assert_array_equal(got, want, err_msg=f"stream {i}")
     assert tiled.digests == whole.digests
@@ -76,7 +79,7 @@ def test_tiled_classes_give_the_one_batchs_cuts_digests_and_probe(monkeypatch, d
     assert top.tile_rows == (fit_rows if tiles > 1 else fused_convert.bucket_rows(top_rows))
     assert not top.sizes[top.count :].any()  # padding rows only behind the last live one
     assert all(row < b.count for b in buckets for cap, row in order if cap == b.cap_blocks)
-    assert counter.value() - before == sum(b.tiles - 1 for b in buckets)
+    assert plan["row_tiles"] == sum(b.tiles - 1 for b in buckets)
     if case == "a_tiled_class_beside_one_that_is_not":
         assert {b.tiles > 1 for b in buckets} == {True, False}
 
@@ -141,7 +144,7 @@ def served(tmp_path_factory):
     """A tar of three files that hold nearly all its bytes and forty small
     ones, packed on the device lane with the budget at 2 MiB and on the host
     lane -> (files, fused artifact, hybrid artifact, the fused pack's
-    `pack:lane.plan`, the row-tile counter's step)."""
+    `pack:lane.plan`)."""
     d = tmp_path_factory.mktemp("row_tiles")
     members = [image.Member(f"huge/f{i}.so", size, kind) for i, (size, kind) in enumerate(HUGE)]
     members += [image.Member(f"small/f{i}.py", 300 + 97 * i, "text") for i in range(40)]
@@ -149,13 +152,11 @@ def served(tmp_path_factory):
     image.write_tar(str(d / "layer.tar"), members, datas)
     args = ["--chunking", "cdc", "--fs-version", "v6", "--compressor", "lz4_block", "--digester", "sha256",
             "--chunk-size", hex(CHUNK)]
-    counter = fused_convert._row_tiles_counter()
     out = {}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(fused_convert, "TILE_BYTES", SERVED_TILE_BYTES)
         trace.configure(enabled=True)
         try:
-            before = counter.value()
             for backend in ("fused", "hybrid"):
                 path = str(d / f"layer.{backend}.nydus")
                 line = run_cli("pack", "--in", str(d / "layer.tar"), "--out", path, "--backend", backend, *args)
@@ -165,8 +166,7 @@ def served(tmp_path_factory):
             plan = [dict(s.attrs) for s in spans if s.name == "pack:lane.plan"]
         finally:
             trace.reset()
-    return {"files": [(m.name, data) for m, data in zip(members, datas)], **out, "plan": plan,
-            "row_tiles": counter.value() - before}
+    return {"files": [(m.name, data) for m, data in zip(members, datas)], **out, "plan": plan}
 
 
 def test_the_served_pack_of_a_few_huge_files_equals_the_plain_reference(served):
@@ -186,9 +186,11 @@ def test_the_plan_span_says_what_was_tiled(served):
     assert tiled and len(tiled) < len(plan["classes"])
     assert plan["blocks_tiled"] == sum(cap * rows for cap, _live, rows in tiled) > 0
     assert plan["blocks_tiled"] < plan["blocks_padded"]
-    assert plan["row_tiles"] == served["row_tiles"] >= len(tiled)
     assert 0 < plan["batch_mib_max"] <= SERVED_TILE_BYTES / MIB
     # every tiled class is whole tiles of the budget's rows, and no more of them than its chunks need
+    tiles = []
     for cap, live, rows in tiled:
         tile = fused_convert._pow2_floor(SERVED_TILE_BYTES // (cap * 64))
         assert rows == -(-live // tile) * tile
+        tiles.append(rows // tile)
+    assert plan["row_tiles"] == sum(t - 1 for t in tiles) >= len(tiled)

@@ -25,7 +25,8 @@ Also reports span throughput (spans/sec into the ring) and ring drops,
 and the convert verbs' tracing budget: spans a ``pack`` / ``merge`` (the
 count must not follow the file count) and their cost a verb, priced at
 the measured cost of one ``trace.Stages`` boundary with a profiler bridge
-installed (gated at ``--max-verb-us``, default 1000).
+installed and of the collector clock a collection (gated at
+``--max-verb-us``, default 1000).
 Doubles as the CI smoke driver (``trace-smoke`` job, PYTHONDEVMODE=1) and
 feeds ``bench.py``'s ``detail.trace``.
 
@@ -94,8 +95,11 @@ def convert_verbs(n: int = 20000) -> dict:
     """Spans a served ``pack`` (fused lane, XLA on the CPU here) and a
     ``merge`` record, for a 16-file and a 400-file tar, and what they
     cost: spans x the measured cost of one stage boundary (span exit +
-    next span enter + a no-op profiler annotation)."""
+    the thread's usage read + next span enter + a no-op profiler
+    annotation), plus the collections a verb ran x the collector clock's
+    cost a collection (its two callback calls)."""
     import contextlib
+    import gc
     import io
     import tarfile
 
@@ -121,8 +125,12 @@ def convert_verbs(n: int = 20000) -> dict:
                 tf.addfile(info, io.BytesIO(data))
         return buf.getvalue()
 
+    def collections() -> int:
+        return sum(s["collections"] for s in gc.get_stats())
+
     work = tempfile.mkdtemp(prefix="ntpu_trace_convert.")
     counts = {}
+    collected = {}
     try:
         for files in (16, 400):
             tar, blob = os.path.join(work, f"{files}.tar"), os.path.join(work, f"{files}.nydus")
@@ -133,11 +141,13 @@ def convert_verbs(n: int = 20000) -> dict:
                 ("merge", ["merge", "--out", blob + ".boot", blob]),
             ):
                 trace.configure(enabled=True, slow_op_threshold_ms=0)
+                before = collections()
                 with contextlib.redirect_stdout(io.StringIO()):  # the verb's result line
                     rc = cli.main(["--jax-platform", "cpu", *argv])
                 if rc != 0:
                     raise RuntimeError(f"cmd.convert {verb} exited {rc}")
                 counts.setdefault(verb, []).append(len(trace.snapshot_spans()))
+                collected.setdefault(verb, []).append(collections() - before)
     finally:
         shutil.rmtree(work, ignore_errors=True)
     trace.configure(enabled=True, ring_capacity=2048, slow_op_threshold_ms=0)
@@ -151,13 +161,24 @@ def convert_verbs(n: int = 20000) -> dict:
     finally:
         trace.install_profiler_bridge(None)
     ns = dt / n * 1e9
+    t0 = perf_counter()
+    for _ in range(n):
+        trace._collector_clock("start", {})
+        trace._collector_clock("stop", {})
+    ns_gc = (perf_counter() - t0) / n * 1e9
+
+    def us(verb: str) -> float:
+        return round((max(counts[verb]) * ns + max(collected[verb]) * ns_gc) / 1e3, 1)
+
     return {
         "spans_per_pack": counts["pack"],
         "spans_per_merge": counts["merge"],
         "count_follows_files": len(set(counts["pack"])) != 1 or len(set(counts["merge"])) != 1,
         "ns_per_stage": round(ns),
-        "us_per_pack": round(max(counts["pack"]) * ns / 1e3, 1),
-        "us_per_merge": round(max(counts["merge"]) * ns / 1e3, 1),
+        "collections_per_pack": collected["pack"],
+        "ns_per_collection": round(ns_gc),
+        "us_per_pack": us("pack"),
+        "us_per_merge": us("merge"),
     }
 
 
@@ -405,7 +426,8 @@ def main() -> int:
         print(f"disabled: {report['disabled']['ns_per_call']} ns/call")
         cv = report["convert"]
         print(f"convert: {cv['spans_per_pack']} spans/pack {cv['spans_per_merge']} "
-              f"spans/merge (16 and 400 files), {cv['ns_per_stage']} ns/stage = "
+              f"spans/merge (16 and 400 files), {cv['ns_per_stage']} ns/stage, "
+              f"{cv['collections_per_pack']} collections/pack at {cv['ns_per_collection']} ns = "
               f"{cv['us_per_pack']} us/pack, {cv['us_per_merge']} us/merge")
         tr = report["tree"]
         print(f"tree: {tr['spans']} spans single_tree={tr['single_tree']} "
