@@ -226,8 +226,28 @@ def find_cell(workload: str) -> tuple[dict, dict, dict, dict]:
             load(HERE, "configs", f"{entry['config']}.json"))
 
 
+def metric_file(name: str) -> str:
+    """``<name>.json`` under metrics/, or for a quantity split by cells
+    (``lane_host_s_per_gib.fanout``) that has no file of its own, its base
+    name's: the same reader with the same parameters."""
+    if os.path.exists(os.path.join(HERE, "metrics", f"{name}.json")) or "." not in name:
+        return f"{name}.json"
+    return f"{name.rsplit('.', 1)[0]}.json"
+
+
+def reported(bench: dict, workload: str) -> tuple[list, list]:
+    """-> (the end-to-end metrics, the per-layer metrics) that ``workload``
+    reports: those whose ``workloads`` lists it or that have no such list, and
+    of the per-layer ones only those that move an end-to-end metric it reports."""
+    ours = lambda m: workload in m.get("workloads", [workload])  # noqa: E731
+    e2e = [m for m in bench["end_to_end"] if ours(m)]
+    moved = {m["name"] for m in e2e}
+    return e2e, [m for m in bench["per_layer"] if ours(m) and m["moves"] in moved]
+
+
 def _run(args, require_tpu: bool, log, out) -> int:
     bench, entry, cell, config = find_cell(args.workload)
+    e2e_metrics, layer_metrics = reported(bench, args.workload)
 
     import jax
 
@@ -293,10 +313,8 @@ def _run(args, require_tpu: bool, log, out) -> int:
                 **{k: trace.get(k) for k in ("structure", "spans", "busy_s", "window_s")})
             ctx = {"records": records, "counters": in_window, "trace": trace, "setup": setup, "peaks": peaks,
                    "loop": loop}
-            for m in bench["per_layer"]:
-                if "workloads" in m and args.workload not in m["workloads"]:
-                    continue
-                spec = load(HERE, "metrics", f"{m['name']}.json")
+            for m in layer_metrics:
+                spec = load(HERE, "metrics", metric_file(m["name"]))
                 reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
                 value = reader.read(ctx, **spec.get("params", {}))
                 if value is not None:
@@ -305,9 +323,9 @@ def _run(args, require_tpu: bool, log, out) -> int:
                 device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
                 breakdown = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
         else:
-            units = {m["name"]: m["unit"] for m in bench["end_to_end"]}
-            for name, value in end_to_end(records, setup_s).items():
-                metrics[name] = {"value": value, "unit": units[name]}
+            values = end_to_end(records, setup_s)
+            for m in e2e_metrics:
+                metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
         log("measured", programs_compiled_in_window=programs_after - setup["lane_programs"],
             counters=counters, window_counters=in_window, **end_to_end(records, setup_s))
 

@@ -91,7 +91,7 @@ def test_dedup_is_judged_by_the_plain_reference(tiny, capfd, monkeypatch):  # no
     assert checks["artifacts_differ"]["value"] == 0 and checks["dict_hits_differ"]["value"] == 0
 
 
-@pytest.mark.parametrize("cell", ["node21-64k.fresh", "node21-64k.fanout"])  # verb after verb; an image's packs at once
+@pytest.mark.parametrize("cell", ["node21-64k.dict", "node21-64k.fanout"])  # verb after verb; an image's packs at once
 @pytest.mark.parametrize("fault,fails", [
     (_alter_digest, {"artifacts_differ", "plain_files_differ"}),
     (_alter_cut, {"artifacts_differ", "plain_files_differ"}),

@@ -2,11 +2,22 @@
 window itself wrote. Every number has its limit beside it; all are exact.
 
 The decider is ``benchmark/reference.py`` (imports nothing of the program,
-takes nothing it has made):
+takes nothing it has made), on the arms the configuration's ``pack_args``
+state (``arms``; where a flag is absent, the CLI's default):
 
-* it cuts and digests a seed-drawn sample of the tars' files, the largest
-  among them: the chunk records of the kept artifacts must say the same;
-* it decodes a sample of stored chunks: the stored bytes must be the file's;
+* ``--chunking`` ``cdc`` | ``fixed`` and ``--chunk-size`` choose the cut rule,
+  ``--digester`` ``sha256`` | ``blake3`` the digest: it cuts and digests a
+  seed-drawn sample of the tars' files, the largest among them, and the chunk
+  records of the kept artifacts must say the same (``plain_files_differ``);
+* ``--compressor`` ``lz4_block`` | ``zstd`` | ``none`` chooses how a sample of
+  stored chunks is decoded: a chunk flagged with the configuration's
+  compressor is decoded (lz4 block; zstd as ONE standalone frame that needs
+  no dictionary), a chunk flagged raw is compared as it is, and one flagged
+  with any other compressor differs; the stored bytes must be the file's
+  (``stored_chunks_differ``), and where the configuration states a
+  compressor, at least one sampled chunk must carry its flag
+  (``stored_compressed_compared``: a pack that stored every chunk raw is no
+  compressed pack);
 * it digests every file of the dictionary image: a sampled chunk whose digest
   is in that set has to be referenced in a blob that is not the image's own,
   and every other one in the image's own, whose id is the sha256 of the blob
@@ -23,6 +34,7 @@ the host adds none), whatever the number of batches a pack makes of its layer.
 
 from __future__ import annotations
 
+import argparse
 import filecmp
 import hashlib
 import os
@@ -33,7 +45,26 @@ import numpy as np
 
 from benchmark import program, reference
 
-COMPRESSOR_MASK, LZ4_BLOCK, FLAG_BATCH = 0xF, 0x4, 0x200  # RAFS chunk flags
+COMPRESSOR_MASK, FLAG_BATCH = 0xF, 0x200  # RAFS chunk flags
+COMPRESSOR_NONE, COMPRESSOR_ZSTD, LZ4_BLOCK = 0x1, 0x2, 0x4  # the values of the mask; 0 is raw too
+COMPRESSOR_FLAGS = {"none": COMPRESSOR_NONE, "zstd": COMPRESSOR_ZSTD, "lz4_block": LZ4_BLOCK}
+DECODERS = {COMPRESSOR_ZSTD: reference.zstd_frame_decode, LZ4_BLOCK: reference.lz4_block_decode}
+READ_GROUP_BYTES = 64 << 20  # sampled files read, cut and digested together
+
+
+def arms(config: dict) -> tuple[dict, str]:
+    """The pack arguments the reference follows, read from the configuration's
+    ``pack_args`` as ``cmd/convert.py`` reads them (its defaults where a flag
+    is absent, the last of a repeated one) -> (the cut and digest arms as
+    ``reference.plain_chunks_many`` takes them, the compressor). A value the
+    reference has no arm for is refused (``SystemExit``)."""
+    ap = argparse.ArgumentParser(prog="pack_args", add_help=False, allow_abbrev=False)
+    ap.add_argument("--chunking", default="cdc", choices=("cdc", "fixed"))
+    ap.add_argument("--digester", default="sha256", choices=tuple(reference.DIGESTERS))
+    ap.add_argument("--compressor", default="lz4_block", choices=tuple(COMPRESSOR_FLAGS))
+    ap.add_argument("--chunk-size", type=lambda v: int(v, 0), default=0x100000)
+    got, _others = ap.parse_known_args(config["pack_args"])
+    return {"avg": got.chunk_size, "chunking": got.chunking, "digester": got.digester}, got.compressor
 
 
 def check(name: str, value, limit, rule: str = "<=") -> dict:
@@ -97,9 +128,9 @@ def dict_hits(directory: str, loop) -> int:
 def dictionary_digests(loop, log) -> set:
     """The digest of every chunk of every file of the dictionary image, by
     the plain reference: what a pack with that dictionary may not store again."""
-    t0, avg, held = time.perf_counter(), loop.config["chunk_size"], set()
-    for data in loop.dict_files:
-        held.update(digest for _size, digest in reference.plain_chunks(data, avg))
+    t0, held = time.perf_counter(), set()
+    for chunks in reference.plain_chunks_many(loop.dict_files, **arms(loop.config)[0]):
+        held.update(digest for _size, digest in chunks)
     if loop.dict_files:
         log("plain_dictionary", files=len(loop.dict_files), digests=len(held), wall_s=time.perf_counter() - t0)
     return held
@@ -108,10 +139,11 @@ def dictionary_digests(loop, log) -> set:
 def plain_checks(loop, directory: str, own_ids: set, log) -> list[dict]:
     """A sample of files, drawn from the seed, against the plain reference."""
     t0 = time.perf_counter()
-    avg = loop.config["chunk_size"]
+    cut, compressor = arms(loop.config)
+    want_flag = COMPRESSOR_FLAGS[compressor]
     rng = np.random.default_rng([int(loop.seed), 0xC0])
     budget = loop.cell["plain_sample_mib"] << 20
-    n_files = n_chunks = files_differ = n_stored = stored_differ = dedup_differ = hits_expected = 0
+    n_files = n_chunks = files_differ = n_stored = n_compressed = stored_differ = dedup_differ = hits_expected = 0
     held = dictionary_digests(loop, log)
     for li, members in enumerate(loop.members):
         order = sorted(range(len(members)), key=lambda i: -members[i].size)[:1]  # the largest
@@ -130,11 +162,8 @@ def plain_checks(loop, directory: str, own_ids: set, log) -> list[dict]:
         own = [i for i, b in enumerate(bs.blobs) if b.blob_id in own_ids]
         stored_left = loop.cell["stored_sample_chunks"] // len(loop.members)
         with tarfile.open(loop.tars[li]) as tf:
-            infos = {m.name: m for m in tf.getmembers()}
-            for i in picked:
-                data = np.frombuffer(tf.extractfile(infos[members[i].name]).read(), np.uint8)
-                want = reference.plain_chunks(data, avg)
-                ino = by_path.get("/" + members[i].name)
+            for m, data, want in plain_files(tf, [members[i] for i in picked], cut):
+                ino = by_path.get("/" + m.name)
                 recs = bs.chunks[ino.chunk_index:ino.chunk_index + ino.chunk_count] if ino else []
                 got = [(c.uncompressed_size, c.digest) for c in recs]
                 n_files += 1
@@ -150,19 +179,40 @@ def plain_checks(loop, directory: str, own_ids: set, log) -> list[dict]:
                         stored_left -= 1
                         of_file += 1
                         n_stored += 1
+                        flag = c.flags & COMPRESSOR_MASK
+                        n_compressed += flag == want_flag
                         raw = blob[c.compressed_offset:c.compressed_offset + c.compressed_size]
                         try:
-                            if c.flags & COMPRESSOR_MASK == LZ4_BLOCK:
-                                raw = reference.lz4_block_decode(raw, c.uncompressed_size)
+                            if flag == want_flag and flag in DECODERS:
+                                raw = DECODERS[flag](raw, c.uncompressed_size)
+                            elif flag not in (0, COMPRESSOR_NONE):
+                                raise ValueError(f"stored with compressor flag {flag:#x}, not {compressor}'s")
                             stored_differ += raw != data[pos:pos + c.uncompressed_size].tobytes()
                         except (ValueError, IndexError):
                             stored_differ += 1
                     pos += c.uncompressed_size
     log("plain_reference", files=n_files, chunks=n_chunks, stored_chunks=n_stored, dictionary_hits=hits_expected,
-        wall_s=time.perf_counter() - t0)
+        arms={**cut, "compressor": compressor}, wall_s=time.perf_counter() - t0)
     return [check("plain_files_differ", files_differ, 0),
             check("plain_chunks_compared", n_chunks, 1, ">="),
             check("stored_chunks_differ", stored_differ, 0),
             check("stored_chunks_compared", n_stored, 1, ">="),
+            check("stored_compressed_compared", n_compressed, 0 if compressor == "none" else 1, ">="),
             check("dedup_differ", dedup_differ, 0),
             check("dictionary_hits_expected", hits_expected, 1 if loop.dict_files else 0, ">=")]
+
+
+def plain_files(tf, members: list, cut: dict):
+    """(member, its bytes, the plain reference's chunks) for each member of
+    the tar, in order; files are read, cut and digested together, about
+    ``READ_GROUP_BYTES`` at a time (a larger file alone)."""
+    infos = {m.name: m for m in tf.getmembers()}
+    start = 0
+    while start < len(members):
+        end, held = start + 1, members[start].size
+        while end < len(members) and held + members[end].size <= READ_GROUP_BYTES:
+            held += members[end].size
+            end += 1
+        datas = [np.frombuffer(tf.extractfile(infos[m.name]).read(), np.uint8) for m in members[start:end]]
+        yield from zip(members[start:end], datas, reference.plain_chunks_many(datas, **cut))
+        start = end
